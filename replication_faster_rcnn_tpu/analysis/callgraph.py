@@ -45,10 +45,7 @@ _STATIC_PARAM_NAMES = {"self", "cls", "train", "training", "deterministic", "cfg
 _STATIC_ANNOTATION_HEADS = {"bool", "int", "str", "float", "Sequence", "Tuple", "tuple", "List", "list", "Dict", "dict"}
 
 _JIT_NAMES = {"jax.jit"}
-_SHARD_MAP_NAMES = {
-    "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
-}
+_SHARD_MAP_NAMES = {"jax.shard_map"}
 _REMAT_NAMES = {"flax.linen.remat", "nn.remat", "jax.checkpoint", "jax.remat"}
 
 

@@ -111,7 +111,6 @@ _TRACER_CALL_PREFIXES = (
 # external callables that just map over their arguments (taint passes through)
 _PASSTHROUGH_CALLS = {
     "jax.tree_util.tree_map",
-    "jax.tree_map",
     "jax.tree.map",
     "optax.apply_updates",
     "jax.checkpoint",

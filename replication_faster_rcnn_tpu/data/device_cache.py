@@ -1,11 +1,10 @@
 """Device-resident dataset cache: the TPU-native answer to a feed-bound
 trainer.
 
-Why: the measured loader-fed trainer at 600x600 b16 runs at ~11 img/s on
-the remote v5e while the same step on device-resident tensors runs at
-~215 img/s (`benchmarks/loader_throughput.json`, `mfu_experiments.json`)
-— the host->device image transfer (69 MB/step f32, 17 MB u8) dwarfs the
-74 ms step. The reference has no answer to this: its torch DataLoader
+Why: a loader-fed trainer ships every image host->device every step
+(69 MB/step f32, 17 MB u8 at 600x600 b16), and where that link is the
+bottleneck the step waits on it (ROADMAP S2 is to measure how much, on
+the chip). The reference has no answer to this: its torch DataLoader
 re-decodes and re-ships every image every epoch (`frcnn.py:19-23`,
 `utils/data_loader.py:42-48`).
 

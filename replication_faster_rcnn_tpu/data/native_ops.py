@@ -4,15 +4,23 @@ with exact-equivalent numpy fallbacks.
 The native library replaces, in the framework's own code, the compiled host
 kernels the reference borrows from skimage/torchvision (SURVEY.md §2.3):
 fused bilinear-resize+normalize for the data pipeline and greedy NMS for
-CPU-side post-processing. If the ``.so`` is absent, a best-effort ``make``
-builds it; failing that, the numpy fallbacks keep everything working (the
-fallbacks ARE the behavioral spec — parity is tested both ways).
+CPU-side post-processing. The ``.so`` is a build product (``native/build/``
+is gitignored), so it is only ever loaded when THIS host built it from the
+source as it stands: a stamp beside it records the source, the host and
+the object's own hash, and anything else — no object, an object from
+another checkout or CPU (the build uses ``-march=native``), a hand-run
+``make`` — is rebuilt first. If the build fails, the numpy fallbacks keep
+everything working (the fallbacks ARE the behavioral spec — parity is
+tested both ways).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import json
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -22,52 +30,82 @@ import numpy as np
 _REPO = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
-_SO_PATH = os.path.join(_REPO, "native", "build", "libfrcnn_native.so")
+_NATIVE_DIR = os.path.join(_REPO, "native")
+_SO_NAME = "libfrcnn_native.so"
 
 _lib: Optional[ctypes.CDLL] = None
 _lib_checked = False
 _lib_lock = threading.Lock()  # loader threads race here on first batch
 
 
-def _try_build(rebuild: bool = False) -> bool:
+def _so_path() -> str:
+    return os.path.join(_NATIVE_DIR, "build", _SO_NAME)
+
+
+def _sha256(path: str) -> str:
+    with open(path, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _host_signature() -> str:
+    """This host, as far as a ``-march=native`` object cares: the node
+    name plus the CPU model and feature flags."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            cpu = sorted(
+                {ln for ln in f if ln.startswith(("model name", "flags", "Features"))}
+            )
+    except OSError:
+        cpu = [platform.processor() or platform.machine()]
+    return platform.node() + "\n" + "".join(cpu)
+
+
+def _build_key() -> str:
+    """What a loadable object must have been built from and on."""
+    h = hashlib.sha256(_host_signature().encode())
+    for name in ("frcnn_native.cpp", "Makefile"):
+        h.update(_sha256(os.path.join(_NATIVE_DIR, name)).encode())
+    return h.hexdigest()
+
+
+def _stamp_matches() -> bool:
+    """True iff the object on disk is byte-for-byte what :func:`_try_build`
+    last produced on this host from the current source."""
+    so = _so_path()
+    try:
+        with open(so + ".stamp") as f:
+            stamp = json.load(f)
+        return stamp == {"key": _build_key(), "so_sha256": _sha256(so)}
+    except (OSError, ValueError):
+        return False
+
+
+def _try_build() -> bool:
     """Best-effort make, degrading through host capabilities: full build,
     then without -march=native (older gcc), then without libjpeg (missing
-    jpeglib.h — the JPEG entry points are simply absent), then both."""
-    flag_sets = [[], ["MARCH="], ["JPEG=0"], ["MARCH=", "JPEG=0"]]
-    base = ["make", "-C", os.path.join(_REPO, "native")]
-    if rebuild:
-        base.insert(1, "-B")
-    for flags in flag_sets:
+    jpeglib.h — the JPEG entry points are simply absent), then both.
+
+    Builds under a scratch name and moves the object, then its stamp,
+    into place — a concurrent process never maps a half-written file, and
+    a stamp never describes an object it was not written for."""
+    so = _so_path()
+    tmp = f"{_SO_NAME}.{os.getpid()}.tmp"
+    for flags in ([], ["MARCH="], ["JPEG=0"], ["MARCH=", "JPEG=0"]):
         try:
             subprocess.run(
-                base + flags, check=True, capture_output=True, timeout=120
+                ["make", "-B", "-C", _NATIVE_DIR, f"OUT=build/{tmp}", *flags],
+                check=True, capture_output=True, timeout=120,
             )
-            return True
-        except Exception:
+        except (subprocess.SubprocessError, OSError):
             continue
+        built = os.path.join(_NATIVE_DIR, "build", tmp)
+        stamp = {"key": _build_key(), "so_sha256": _sha256(built)}
+        os.replace(built, so)
+        with open(built + ".stamp", "w") as f:
+            json.dump(stamp, f)
+        os.replace(built + ".stamp", so + ".stamp")
+        return True
     return False
-
-
-def _rebuild_and_reload() -> Optional[ctypes.CDLL]:
-    """Rebuild the .so and dlopen it under a fresh unique pathname (glibc
-    caches dlopen by path, so reloading _SO_PATH would return the old
-    handle). Returns None if the rebuild or reload fails, or if the
-    rebuilt library still lacks the JPEG entry points (JPEG=0 fallback
-    build) — callers then keep whatever library they already have."""
-    import shutil
-    import tempfile
-
-    if not _try_build(rebuild=True):
-        return None
-    try:
-        fd, tmp = tempfile.mkstemp(suffix=".so", prefix="frcnn_native_")
-        os.close(fd)
-        shutil.copy2(_SO_PATH, tmp)
-        lib = ctypes.CDLL(tmp)
-        os.unlink(tmp)  # the mapping survives the unlink
-    except Exception:
-        return None
-    return lib if hasattr(lib, "decode_jpeg_resize_normalize") else None
 
 
 def _load_lib() -> Optional[ctypes.CDLL]:
@@ -83,20 +121,12 @@ def _load_lib_locked() -> Optional[ctypes.CDLL]:
     if _lib_checked:
         return _lib
     _lib_checked = True
-    if not os.path.exists(_SO_PATH):
-        if not _try_build():
-            return None  # numpy fallbacks cover everything
+    if not _stamp_matches() and not _try_build():
+        return None  # numpy fallbacks cover everything
     try:
-        lib = ctypes.CDLL(_SO_PATH)
+        lib = ctypes.CDLL(_so_path())
     except OSError:
         return None
-    if not hasattr(lib, "decode_jpeg_resize_normalize"):
-        # stale .so from before the JPEG kernels. Rebuild, then load the
-        # fresh file through a unique temp copy: re-dlopening the same
-        # pathname would return the cached stale handle (ctypes never
-        # dlcloses). On any failure keep the stale-but-working library —
-        # resize/NMS/scale_boxes don't need libjpeg.
-        lib = _rebuild_and_reload() or lib
     f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
     u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
     i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")

@@ -132,9 +132,7 @@ def multilevel_roi_align(
     [N, sum(Hl*Wl), C] buffer and every roi does a single 4-corner
     bilinear gather with level-offset flat indices (index = level_offset +
     r * Wl + c, computed from the roi's assigned level). One gather pass
-    and one backward scatter for the whole pyramid — measured 3.4x the
-    blend path on v5e (50.3 -> 14.6 ms at b8, 128 rois; see
-    benchmarks/bench_v5e_round2.json).
+    and one backward scatter for the whole pyramid.
 
     ``method="blend"``: the original formulation — every roi is aligned on
     EVERY level (gather roi_align per level) and the results combined with

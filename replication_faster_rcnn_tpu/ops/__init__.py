@@ -17,9 +17,11 @@ Resolution order, highest first:
 3. the ``config.ops.backend`` value the caller passes down
 4. ``"xla"``
 
-`want_pallas(op)` is the single question dispatch sites ask; it folds in
-availability (import failure of the kernel package warns once per op and
-falls back to XLA rather than erroring — e.g. a jax build without pallas).
+`want_pallas(op)` is the single question dispatch sites ask. Choosing the
+pallas backend and not getting it is an error, never a quiet XLA program
+under a pallas name: a kernel package that fails to import raises, and on
+a TPU backend a kernel compiles or the compiler's error propagates — it
+never interprets there (`interpret_mode`).
 """
 
 import os
@@ -119,29 +121,31 @@ def resolve_backend(config=None) -> str:
     return "xla"
 
 
-def pallas_available(op: str = "") -> bool:
-    """Can the pallas kernels be used here? (warns once per op if not)"""
+def require_pallas(op: str):
+    """The kernel package, for dispatch site ``op`` — or an error saying
+    why the pallas backend that was chosen cannot be had."""
     try:
-        from replication_faster_rcnn_tpu.ops import pallas  # noqa: F401
-
-        return True
-    except Exception as e:  # pragma: no cover - env without pallas support
-        _warn_once(
-            f"unavailable:{op}",
-            f"ops.backend=pallas requested but the kernel package failed "
-            f"to import ({type(e).__name__}: {e}); falling back to the XLA "
-            + (f"implementation for {op!r}" if op else "implementations"),
-        )
-        return False
+        from replication_faster_rcnn_tpu.ops import pallas
+    except Exception as e:
+        raise RuntimeError(
+            f"ops.backend=pallas was selected for {op!r} but the kernel "
+            f"package failed to import ({type(e).__name__}: {e})"
+        ) from e
+    return pallas
 
 
 def want_pallas(op: str, config=None) -> bool:
-    """True iff dispatch site ``op`` should take the pallas path."""
-    return resolve_backend(config) == "pallas" and pallas_available(op)
+    """True iff dispatch site ``op`` should take the pallas path (raises
+    when pallas is chosen and unavailable — see :func:`require_pallas`)."""
+    if resolve_backend(config) != "pallas":
+        return False
+    require_pallas(op)
+    return True
 
 
 def interpret_mode() -> bool:
-    """Pallas interpret mode: everywhere except a real TPU backend."""
+    """Pallas interpret mode: everywhere except a real TPU backend, where
+    a kernel compiles or raises and never interprets."""
     import jax
 
     return jax.default_backend() != "tpu"

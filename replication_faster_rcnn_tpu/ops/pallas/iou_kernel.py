@@ -9,13 +9,17 @@ HBM; here it is tiled over the anchor axis with the matching reductions fused
 in VMEM, one grid step per anchor tile.
 
 Exactness: the in-kernel IoU replicates `ops/boxes.py::iou` op-for-op
-(elementwise IEEE arithmetic — bitwise equal), row argmax/max use the same
-``jnp.argmax`` / ``jnp.max(jnp.maximum(x, 0.0))`` ops on the same values, and
-the column argmax streams across tiles with a strictly-greater update, which
-reproduces ``jnp.argmax(axis=0)`` first-occurrence tie-breaking exactly
-(padded anchor rows are forced to -1 and sit after all real rows, so they can
-tie but never win). Tier-1 pins all four outputs bitwise
-(tests/test_pallas_iou.py).
+(elementwise IEEE arithmetic — bitwise equal); row max is the same
+``jnp.max(jnp.maximum(x, 0.0))`` on the same values; each argmax is the
+lowest index holding the maximum, which is ``jnp.argmax``'s first-occurrence
+rule (the IoUs are never NaN); and the column argmax streams across tiles
+with a strictly-greater update, which keeps that rule across tiles (padded
+anchor rows are forced to -1 and sit after all real rows, so they can tie but
+never win). Tier-1 pins all four outputs bitwise (tests/test_pallas_iou.py).
+
+Like the NMS kernel, everything in the body is 2-D with reductions keeping
+their dims: per-anchor results are columns ``[tile, 1]``, per-gt results rows
+``[1, G]``, and the indices are reduced as float32 (exact below 2**24).
 """
 
 from __future__ import annotations
@@ -27,7 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from replication_faster_rcnn_tpu.ops.pallas.nms_kernel import _iou_cols
+from replication_faster_rcnn_tpu.ops.pallas.nms_kernel import (
+    _cols,
+    _iou_grid,
+    _rows,
+)
 
 Array = jnp.ndarray
 
@@ -54,27 +62,35 @@ def _match_kernel(
         bval_ref[...] = jnp.full_like(bval_ref, -jnp.inf)
 
     g_count = g_ref.shape[1]
-    ious = _iou_cols(a_ref[...], g_ref[...], z_ref[0, 0])  # [tile, G]
-    ious = jnp.where(m_ref[0, :][None, :] != 0, ious, -1.0)  # padded gt cols
+    ious = _iou_grid(_cols(a_ref), _rows(g_ref), z_ref[0, 0])  # [tile, G]
+    ious = jnp.where(m_ref[...] != 0, ious, -1.0)  # padded gt cols
     # padded anchor rows (beyond n_rows) must never win the column argmax;
     # they sit after every real row, so forcing -1 lets them tie but not beat
-    row_ok = (
-        jax.lax.broadcasted_iota(jnp.int32, (tile, g_count), 0) + i * tile
-    ) < n_rows
-    ious = jnp.where(row_ok, ious, -1.0)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (tile, g_count), 0) + i * tile
+    col_id = jax.lax.broadcasted_iota(jnp.int32, (tile, g_count), 1)
+    ious = jnp.where(row_id < n_rows, ious, -1.0)
 
     iou_ref[...] = ious
-    am_ref[0, :] = jnp.argmax(ious, axis=1).astype(jnp.int32)
-    mx_ref[0, :] = jnp.max(jnp.maximum(ious, 0.0), axis=1)
+    row_max = jnp.max(ious, axis=1, keepdims=True)  # [tile, 1]
+    am_ref[...] = jnp.min(
+        jnp.where(ious == row_max, col_id.astype(jnp.float32), float(g_count)),
+        axis=1,
+        keepdims=True,
+    ).astype(jnp.int32)
+    mx_ref[...] = jnp.max(jnp.maximum(ious, 0.0), axis=1, keepdims=True)
 
     # streaming column argmax: strictly-greater keeps the earliest row on
     # ties, matching jnp.argmax(axis=0) first-occurrence semantics
-    col_max = jnp.max(ious, axis=0)  # [G]
-    col_arg = jnp.argmax(ious, axis=0).astype(jnp.int32) + i * tile
-    prev = bval_ref[0, :]
+    col_max = jnp.max(ious, axis=0, keepdims=True)  # [1, G]
+    col_arg = jnp.min(
+        jnp.where(ious == col_max, row_id.astype(jnp.float32), float(2**24)),
+        axis=0,
+        keepdims=True,
+    ).astype(jnp.int32)
+    prev = bval_ref[...]
     beat = col_max > prev
-    bval_ref[0, :] = jnp.where(beat, col_max, prev)
-    best_ref[0, :] = jnp.where(beat, col_arg, best_ref[0, :])
+    bval_ref[...] = jnp.where(beat, col_max, prev)
+    best_ref[...] = jnp.where(beat, col_arg, best_ref[...])
 
 
 @partial(jax.jit, static_argnames=("tile", "interpret", "want_col"))
@@ -92,43 +108,43 @@ def _match_boxes_pallas(
     n_tiles = -(-n // tile)
     pad = n_tiles * tile - n
 
-    coords = jnp.pad(boxes.astype(jnp.float32), ((0, pad), (0, 0))).T  # [4, n_pad]
+    rows = jnp.pad(boxes.astype(jnp.float32), ((0, pad), (0, 0)))  # [n_pad, 4]
     gt_cols = gt_boxes.astype(jnp.float32).T  # [4, G]
     mask_row = gt_mask.astype(jnp.int32)[None, :]  # [1, G]
 
-    zero = jnp.zeros((1, 1), jnp.float32)  # runtime +0.0, see _iou_cols
+    zero = jnp.zeros((1, 1), jnp.float32)  # runtime +0.0, see _iou_grid
     # keep the pad/transpose producers out of the kernel body's fusion: on
     # XLA:CPU, fusing them in changes LLVM vectorization of the inlined
     # (interpret-mode) kernel and can drift the final division by 1 ulp
-    zero, coords, gt_cols, mask_row = jax.lax.optimization_barrier(
-        (zero, coords, gt_cols, mask_row)
+    zero, rows, gt_cols, mask_row = jax.lax.optimization_barrier(
+        (zero, rows, gt_cols, mask_row)
     )
     ious_p, am_p, mx_p, best_p = pl.pallas_call(
         partial(_match_kernel, tile=tile, n_rows=n),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((4, tile), lambda i: (0, i)),
+            pl.BlockSpec((tile, 4), lambda i: (i, 0)),
             pl.BlockSpec((4, g), lambda i: (0, 0)),
             pl.BlockSpec((1, g), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((tile, g), lambda i: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
-            pl.BlockSpec((1, tile), lambda i: (0, i)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
+            pl.BlockSpec((tile, 1), lambda i: (i, 0)),
             pl.BlockSpec((1, g), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_tiles * tile, g), jnp.float32),
-            jax.ShapeDtypeStruct((1, n_tiles * tile), jnp.int32),
-            jax.ShapeDtypeStruct((1, n_tiles * tile), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles * tile, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles * tile, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, g), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((1, g), jnp.float32)],
         interpret=interpret,
-    )(zero, coords, gt_cols, mask_row)
+    )(zero, rows, gt_cols, mask_row)
 
-    out = (ious_p[:n], am_p[0, :n], mx_p[0, :n])
+    out = (ious_p[:n], am_p[:n, 0], mx_p[:n, 0])
     if want_col:
         return out + (best_p[0],)
     return out

@@ -4,8 +4,8 @@ Same algorithm, same recurrence, same arithmetic: candidates are processed
 in descending-score order one TILE per sequential grid step; within a tile
 the greedy keep vector is solved by fixpoint sweeps of
 ``g = m0 & ~any(suppress & g[:, None], axis=0)``; selected boxes accumulate
-into a compact ``[4, max_out]`` VMEM buffer that suppresses later tiles in
-one matrix op. The in-kernel IoU replicates `ops/boxes.py::iou` op-for-op
+into compact VMEM buffers that suppress later tiles in one matrix op. The
+in-kernel IoU replicates `ops/boxes.py::iou` op-for-op
 (maximum/minimum/subtract/multiply/where/divide in the same order), so every
 comparison against ``iou_thresh`` sees bitwise the same float as the XLA
 tiling and the selections are exactly identical — tier-1 pins this
@@ -16,9 +16,19 @@ while_loop that exits once the buffer fills; a ``count < max_out`` predicate
 skips the per-tile work instead, which appends nothing either way, so
 results match exactly.
 
-Interpret mode (the default off-TPU) runs the kernel as a pure JAX
-interpretation on any backend; on-chip lowering is reserved for the warmup
-ProgramSpec registry (see package docstring).
+Written for the Mosaic compiler as it is installed: every value in the
+kernel is 2-D — a row ``[1, N]`` (index on lanes) or a column ``[N, 1]``
+(index on sublanes) — and a reduction keeps its dims, so nothing asks the
+compiler to move a vector between the two layouts. The tile's boxes and
+scores arrive in both layouts from the wrapper; the one in-kernel
+conversion (the keep vector, row -> column, once per sweep) is a masked
+lane reduction against the identity. Boolean and integer reductions go
+through float32 (exact: counts and indices stay far below 2**24), and the
+in-tile prefix count is a masked reduction against the triangle instead of
+``cumsum``, which the TPU lowering does not implement.
+
+Interpret mode (the default off-TPU) runs the same kernel as a pure JAX
+interpretation on any backend.
 """
 
 from __future__ import annotations
@@ -35,47 +45,22 @@ Array = jnp.ndarray
 _NEG = -jnp.inf
 
 
-def _install_barrier_batching_rule() -> None:
-    """Backport the (identity) vmap rule for ``optimization_barrier``.
-
-    jax 0.4.37 has no batching rule for the primitive, so the producer
-    barriers in these wrappers would break `jax.vmap` over the kernels —
-    the batched `targets/anchor_targets.py` path. The barrier is
-    elementwise identity, so the rule is trivial: bind on the batched
-    operands, keep the dims. Newer jax registers exactly this upstream;
-    installing is a no-op there.
-    """
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:  # pragma: no cover - future jax moves the internals
-        return
-    if optimization_barrier_p in batching.primitive_batchers:
-        return
-
-    def _rule(args, dims):
-        return optimization_barrier_p.bind(*args), list(dims)
-
-    batching.primitive_batchers[optimization_barrier_p] = _rule
-
-
-_install_barrier_batching_rule()
-
-
-def _iou_cols(a: Array, b: Array, zero: Array) -> Array:
-    """`ops/boxes.py::iou` on column-major boxes: a [4, Na], b [4, Nb] ->
-    [Na, Nb]. The elementwise op sequence is identical to the row-major
-    original, so results are bitwise equal — with one subtlety: ``zero``
-    is a RUNTIME +0.0 scalar added to each product. The interpreter
-    inlines the kernel jaxpr into the caller's XLA module, where LLVM
-    codegen FMA-contracts a product into a following add/subtract in some
-    fusion contexts (a 1-ulp drift off strict IEEE; HLO-level bitcast
-    roundtrips are optimized away before codegen, so they can't pin it).
-    Routing each product through ``+ zero`` is bit-exact on every codegen
-    path: left alone it adds +0.0 (identity on the areas/intersection,
-    which are never -0.0 here), and if contracted it becomes
-    ``fma(x, y, 0)`` = ``round(x*y)`` — the strict product — while the
-    remaining add/subtract chain has no multiply left to contract.
+def _iou_grid(a, b, zero: Array) -> Array:
+    """`ops/boxes.py::iou` on split coordinates: ``a`` is four columns
+    ``[Na, 1]``, ``b`` four rows ``[1, Nb]`` (r1, c1, r2, c2 each) ->
+    ``[Na, Nb]``. The elementwise op sequence is identical to the
+    row-major original, so results are bitwise equal — with one subtlety:
+    ``zero`` is a RUNTIME +0.0 scalar added to each product. The
+    interpreter inlines the kernel jaxpr into the caller's XLA module,
+    where LLVM codegen FMA-contracts a product into a following
+    add/subtract in some fusion contexts (a 1-ulp drift off strict IEEE;
+    HLO-level bitcast roundtrips are optimized away before codegen, so
+    they can't pin it). Routing each product through ``+ zero`` is
+    bit-exact on every codegen path: left alone it adds +0.0 (identity on
+    the areas/intersection, which are never -0.0 here), and if contracted
+    it becomes ``fma(x, y, 0)`` = ``round(x*y)`` — the strict product —
+    while the remaining add/subtract chain has no multiply left to
+    contract.
 
     Together with the producer `optimization_barrier` in the wrappers
     (which keeps pad/transpose producers from fusing into the kernel loop
@@ -84,29 +69,48 @@ def _iou_cols(a: Array, b: Array, zero: Array) -> Array:
     XLA:CPU's own compilation of `ops/boxes.py::iou` drifts 1 ulp from
     strict under heavy producer fusion (tests pin the kernels against a
     strict numpy oracle as well as the XLA reference)."""
-    tl_r = jnp.maximum(a[0][:, None], b[0][None, :])
-    tl_c = jnp.maximum(a[1][:, None], b[1][None, :])
-    br_r = jnp.minimum(a[2][:, None], b[2][None, :])
-    br_c = jnp.minimum(a[3][:, None], b[3][None, :])
+    tl_r = jnp.maximum(a[0], b[0])
+    tl_c = jnp.maximum(a[1], b[1])
+    br_r = jnp.minimum(a[2], b[2])
+    br_c = jnp.minimum(a[3], b[3])
     wh_r = br_r - tl_r
     wh_c = br_c - tl_c
     valid = (wh_r > 0) & (wh_c > 0)
     inter = jnp.where(valid, wh_r * wh_c, 0.0) + zero
     area_a = (a[2] - a[0]) * (a[3] - a[1]) + zero
     area_b = (b[2] - b[0]) * (b[3] - b[1]) + zero
-    union = area_a[:, None] + area_b[None, :] - inter
+    union = area_a + area_b - inter
     return jnp.where(union > 0, inter / jnp.where(union > 0, union, 1.0), 0.0)
+
+
+def _cols(ref):
+    """A ``[N, 4]`` box block as four ``[N, 1]`` columns."""
+    return [ref[:, c : c + 1] for c in range(4)]
+
+
+def _rows(ref):
+    """A ``[4, N]`` box block as four ``[1, N]`` rows."""
+    return [ref[c : c + 1, :] for c in range(4)]
+
+
+def _count(mask: Array, axis: int) -> Array:
+    """Number of set entries along ``axis`` (dims kept), as float32."""
+    return jnp.sum(jnp.where(mask, 1.0, 0.0), axis=axis, keepdims=True)
 
 
 def _nms_kernel(
     thresh_ref,
     zero_ref,
     coords_ref,
+    boxes_ref,
     scores_ref,
     order_ref,
     idx_ref,
     valid_ref,
-    selbox_ref,
+    sel_r1,
+    sel_c1,
+    sel_r2,
+    sel_c2,
     count_ref,
     *,
     tile: int,
@@ -114,13 +118,16 @@ def _nms_kernel(
 ):
     i = pl.program_id(0)
     n_tiles = pl.num_programs(0)
+    sel_refs = (sel_r1, sel_c1, sel_r2, sel_c2)
+    slots = idx_ref.shape[0]  # max_out rounded up to the sublane tile
 
     @pl.when(i == 0)
     def _init():
         count_ref[0] = 0
         idx_ref[...] = jnp.zeros_like(idx_ref)
         valid_ref[...] = jnp.zeros_like(valid_ref)
-        selbox_ref[...] = jnp.zeros_like(selbox_ref)
+        for ref in sel_refs:
+            ref[...] = jnp.zeros_like(ref)
 
     count = count_ref[0]
 
@@ -128,55 +135,63 @@ def _nms_kernel(
     def _tile_step():
         thresh = thresh_ref[0, 0]
         zero = zero_ref[0, 0]
-        tb = coords_ref[...]  # [4, tile] column-major boxes
-        ts = scores_ref[0, :]  # [tile]
-        ti = order_ref[0, :]  # [tile] original indices
-        tv = ts > _NEG
-        sel = selbox_ref[...]  # [4, max_out]
+        t_rows = _rows(coords_ref)  # 4 x [1, tile]
+        t_cols = _cols(boxes_ref)  # 4 x [tile, 1]
+        tv = scores_ref[...] > _NEG  # [1, tile]
+        ti = order_ref[...].astype(jnp.float32)  # [1, tile] original indices
+        sel = [ref[...] for ref in sel_refs]  # 4 x [slots, 1]
 
         # cross-tile: suppressed by any already-selected box (one matrix op)
-        cross = _iou_cols(sel, tb, zero) > thresh  # [max_out, tile]
-        kmask = jax.lax.broadcasted_iota(jnp.int32, (max_out, tile), 0) < count
-        m0 = tv & ~jnp.any(cross & kmask, axis=0)
+        cross = _iou_grid(sel, t_rows, zero) > thresh  # [slots, tile]
+        slot_id = jax.lax.broadcasted_iota(jnp.int32, (slots, tile), 0)
+        m0 = tv & (_count(cross & (slot_id < count), 0) == 0.0)  # [1, tile]
 
         # in-tile greedy via fixpoint sweeps (exact; see nms_tiled docstring)
-        later = (
-            jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
-            < jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-        )  # a before b
-        suppress = (_iou_cols(tb, tb, zero) > thresh) & later
+        ia = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+        ib = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+        suppress = (_iou_grid(t_cols, t_rows, zero) > thresh) & (ia < ib)
+        eye = ia == ib
+
+        def to_col(row):  # [1, tile] 0/1 float -> [tile, 1]
+            return jnp.max(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
         def sweep_cond(gs):
-            _, stable = gs
-            return ~stable
+            return gs[2] == 0
 
         def sweep_body(gs):
-            g, _ = gs
-            g2 = m0 & ~jnp.any(suppress & g[:, None], axis=0)
-            return g2, jnp.all(g2 == g)
+            g_row, g_col, _ = gs
+            hit = _count(suppress & (g_col > 0.0), 0) > 0.0  # [1, tile]
+            g2 = jnp.where(m0 & ~hit, 1.0, 0.0)
+            stable = jnp.sum(jnp.abs(g2 - g_row)) == 0.0
+            return g2, to_col(g2), stable.astype(jnp.int32)
 
-        g, _ = jax.lax.while_loop(
-            sweep_cond, sweep_body, (m0, jnp.array(False, dtype=bool))
+        g0 = jnp.where(m0, 1.0, 0.0)
+        g_row, g_col, _ = jax.lax.while_loop(
+            sweep_cond, sweep_body, (g0, to_col(g0), jnp.int32(0))
         )
+        g = g_row > 0.0
 
         # append this tile's selections in order; the scatter of the XLA
         # tiling (`at[slot].set(mode="drop")`) becomes a one-hot
         # gather-free write: each output slot takes at most one candidate
-        pos = count + jnp.cumsum(g) - 1  # [tile] target slot per kept box
-        slots = jax.lax.broadcasted_iota(jnp.int32, (max_out, tile), 0)
-        onehot = g[None, :] & (slots == pos[None, :]) & (pos[None, :] < max_out)
-        taken = jnp.any(onehot, axis=1)  # [max_out]
-        new_box = jnp.sum(jnp.where(onehot[None, :, :], tb[:, None, :], 0.0), axis=2)
-        new_idx = jnp.sum(jnp.where(onehot, ti[None, :], 0), axis=1)
-        selbox_ref[...] = jnp.where(taken[None, :], new_box, sel)
-        idx_ref[0, :] = jnp.where(taken, new_idx, idx_ref[0, :]).astype(jnp.int32)
-        count_ref[0] = jnp.minimum(count + jnp.sum(g), max_out).astype(jnp.int32)
+        pos = (
+            count + _count((ia <= ib) & (g_col > 0.0), 0).astype(jnp.int32) - 1
+        )  # [1, tile] target slot per kept box
+        onehot = g & (slot_id == pos) & (pos < max_out)  # [slots, tile]
+        taken = _count(onehot, 1) > 0.0  # [slots, 1]
+        for ref, old, row in zip(sel_refs, sel, t_rows):
+            new = jnp.sum(jnp.where(onehot, row, 0.0), axis=1, keepdims=True)
+            ref[...] = jnp.where(taken, new, old)
+        new_idx = jnp.sum(jnp.where(onehot, ti, 0.0), axis=1, keepdims=True)
+        idx_ref[...] = jnp.where(taken, new_idx.astype(jnp.int32), idx_ref[...])
+        kept = jnp.sum(g_row).astype(jnp.int32)
+        count_ref[0] = jnp.minimum(count + kept, max_out)
 
     @pl.when(i == n_tiles - 1)
     def _finalize():
         final = count_ref[0]
         valid_ref[...] = (
-            jax.lax.broadcasted_iota(jnp.int32, (1, max_out), 1) < final
+            jax.lax.broadcasted_iota(jnp.int32, valid_ref.shape, 0) < final
         ).astype(jnp.int32)
 
 
@@ -216,44 +231,44 @@ def _nms_fixed_pallas(
         b_sorted = jnp.pad(boxes.astype(jnp.float32)[order], ((0, pad), (0, 0)))
 
     thresh = jnp.full((1, 1), iou_thresh, jnp.float32)
-    zero = jnp.zeros((1, 1), jnp.float32)  # runtime +0.0, see _iou_cols
-    coords = b_sorted.T  # [4, n_pad] — lane-major for the kernel
+    zero = jnp.zeros((1, 1), jnp.float32)  # runtime +0.0, see _iou_grid
+    coords = b_sorted.T  # [4, n_pad] — the tile as rows (index on lanes)
     s_row = s_sorted[None, :]
     o_row = order_p[None, :]
     # producer barrier: keep the sort/pad/transpose prep from fusing into
     # the inlined kernel body on CPU, where it perturbs LLVM vectorization
-    # of the IoU arithmetic (see _iou_cols docstring)
-    thresh, zero, coords, s_row, o_row = jax.lax.optimization_barrier(
-        (thresh, zero, coords, s_row, o_row)
+    # of the IoU arithmetic (see _iou_grid docstring)
+    thresh, zero, coords, b_sorted, s_row, o_row = jax.lax.optimization_barrier(
+        (thresh, zero, coords, b_sorted, s_row, o_row)
     )
 
-    idx_row, valid_row = pl.pallas_call(
+    slots = -(-max_out // 8) * 8  # column buffers: whole sublane tiles
+    idx_col, valid_col = pl.pallas_call(
         partial(_nms_kernel, tile=tile, max_out=max_out),
         grid=(n_tiles,),
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((4, tile), lambda i: (0, i)),
+            pl.BlockSpec((tile, 4), lambda i: (i, 0)),
             pl.BlockSpec((1, tile), lambda i: (0, i)),
             pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((1, max_out), lambda i: (0, 0)),
-            pl.BlockSpec((1, max_out), lambda i: (0, 0)),
+            pl.BlockSpec((slots, 1), lambda i: (0, 0)),
+            pl.BlockSpec((slots, 1), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((1, max_out), jnp.int32),
-            jax.ShapeDtypeStruct((1, max_out), jnp.int32),
+            jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+            jax.ShapeDtypeStruct((slots, 1), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((4, max_out), jnp.float32),
-            pltpu.SMEM((1,), jnp.int32),
-        ],
+        scratch_shapes=[pltpu.VMEM((slots, 1), jnp.float32)] * 4
+        + [pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
-    )(thresh, zero, coords, s_row, o_row)
+    )(thresh, zero, coords, b_sorted, s_row, o_row)
 
-    valid = valid_row[0].astype(bool)
-    return jnp.where(valid, idx_row[0], 0), valid
+    valid = valid_col[:max_out, 0].astype(bool)
+    return jnp.where(valid, idx_col[:max_out, 0], 0), valid
 
 
 def nms_fixed_pallas(

@@ -40,20 +40,6 @@ import warnings
 from typing import Any, Callable, Dict, Optional, Tuple
 
 
-def _resolve_shard_map():
-    """jax >= 0.6 promotes shard_map to the top level and renames the
-    replication-check kwarg check_rep -> check_vma; 0.4.x only has the
-    experimental module. Resolved lazily so importing this module (for
-    the decision table / intent declarations) needs no jax."""
-    import jax
-
-    if hasattr(jax, "shard_map"):  # pragma: no cover - jax >= 0.6 only
-        return jax.shard_map, {"check_vma": False}
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map, {"check_rep": False}
-
-
 # ------------------------------------------------ declarative sharding intent
 #
 # What each train/serve feed DECLARES about the state tree's placement —
@@ -146,6 +132,8 @@ def compile_step_with_plan(step_fn: Callable, plan: Plan):
     pjit plans jit with donation + out_shardings; bare plans jit plain.
     Empty donation / absent out_shardings are NOT passed through, so a
     bare plan lowers the identical program a bare ``jax.jit`` did."""
+    import jax
+
     if plan.mode == "shard_map":
         if plan.mesh is None:
             raise ValueError("a shard_map plan needs a mesh")
@@ -153,15 +141,13 @@ def compile_step_with_plan(step_fn: Callable, plan: Plan):
             raise ValueError(
                 "a shard_map plan needs both in_specs and out_specs"
             )
-        shard_map_fn, no_check = _resolve_shard_map()
-        step_fn = shard_map_fn(
+        step_fn = jax.shard_map(
             step_fn,
             mesh=plan.mesh,
             in_specs=plan.in_specs,
             out_specs=plan.out_specs,
-            **no_check,
+            check_vma=False,
         )
-    import jax
 
     kwargs = {}
     if plan.donate_argnums:

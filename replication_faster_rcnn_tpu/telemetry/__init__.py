@@ -11,8 +11,8 @@ Four pillars, each usable on its own:
 - :mod:`.mfu` — model FLOPs utilisation from the step FLOPs the bench
   already derives, with a measured-matmul CPU peak so MFU is non-null
   even off-TPU.
-- :mod:`.watchdog` — heartbeat daemon that detects a wedged device or
-  tunnel and dumps a diagnostic snapshot (last span, queue depth,
+- :mod:`.watchdog` — heartbeat daemon that detects a stalled run and
+  dumps a diagnostic snapshot (last span, queue depth,
   elapsed-since-progress) instead of leaving a hung process to guess at.
 - :mod:`.tracecontext` — W3C-traceparent-style request tracing: trace
   and span ids that propagate across the serving fleet's process hops

@@ -47,7 +47,7 @@ from replication_faster_rcnn_tpu.train import fault
 from replication_faster_rcnn_tpu.train.async_checkpoint import (
     AsyncCheckpointWriter,
 )
-from replication_faster_rcnn_tpu.train.warmup import maybe_enable_compile_cache
+from replication_faster_rcnn_tpu.train.warmup import place_compile_cache
 from replication_faster_rcnn_tpu.train.train_step import (
     TrainState,
     build_multi_step,
@@ -110,10 +110,10 @@ class Trainer:
     ) -> None:
         self.config = config
         self.workdir = workdir
-        # persistent XLA compilation cache (compile.cache_dir): must be
-        # enabled before the first jitted call traces — jit is lazy, so
-        # doing it here covers every program this trainer compiles
-        maybe_enable_compile_cache(config)
+        # persistent XLA compilation cache: must be placed before the
+        # first jitted call traces — jit is lazy, so doing it here covers
+        # every program this trainer compiles
+        place_compile_cache(config.compile.cache_dir)
         validate_parallel(
             config, len(devices) if devices is not None else None
         )

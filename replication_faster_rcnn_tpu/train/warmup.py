@@ -4,12 +4,13 @@ Every process start re-pays full XLA compilation of the train step
 (minutes for the big presets on TPU) before the first batch dispatches.
 Two pieces take that off the startup critical path:
 
-* :func:`enable_compile_cache` — opt into JAX's persistent compilation
-  cache (``compile.cache_dir`` in the config / ``--compile-cache`` on the
-  CLI). Compiled executables are keyed by HLO + compile options and
-  written under the directory; a later process compiling the *same*
-  program (same config, same mesh, same jaxlib) deserializes instead of
-  re-running XLA.
+* :func:`place_compile_cache` — the one place that decides where JAX's
+  persistent compilation cache lives (``JAX_COMPILATION_CACHE_DIR`` if
+  set, else ``compile.cache_dir`` / ``--compile-cache``, else a fixed
+  path inside the checkout). Compiled executables are keyed by HLO +
+  compile options and written under the directory; a later process
+  compiling the *same* program (same config, same mesh, same jaxlib)
+  deserializes instead of re-running XLA.
 * :func:`warmup_compile` — AOT-lower and compile the training-step
   program(s) (and optionally the eval inference program) for a config
   WITHOUT building datasets, allocating parameters or running a step:
@@ -44,32 +45,47 @@ from replication_faster_rcnn_tpu.config import FasterRCNNConfig
 from replication_faster_rcnn_tpu.telemetry import spans as tspans
 
 
-def enable_compile_cache(cache_dir: str) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (created if missing; ~ expanded). Returns the absolute path.
+# where the persistent compilation cache lives when the environment does
+# not place it: one fixed path inside the checkout (gitignored). The path
+# is part of the cache key's world — a directory that moves never hits.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".compile_cache",
+)
+
+
+def place_compile_cache(cache_dir: str = "") -> Optional[str]:
+    """The one owner of the persistent compilation cache's location;
+    returns the directory in use (None: no cache).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache stays there: JAX
+    read the variable itself at import, and no directory is set in code —
+    ``cache_dir`` (``compile.cache_dir`` / ``--compile-cache``) does not
+    move it. Where it is not set the cache goes to ``cache_dir`` if given
+    (~ expanded), else to :data:`DEFAULT_COMPILE_CACHE_DIR` — on an
+    accelerator. On the CPU backend nothing is kept unless the
+    environment asks: XLA:CPU's loader logs a page of machine-feature
+    warnings on every hit, and debug runs should not fill the checkout.
 
     The min-compile-time / min-entry-size gates are dropped to zero so
     even cheap programs persist — this cache exists to make *restarts*
     free, and a restart replays every program, not just the slow ones."""
-    path = os.path.abspath(os.path.expanduser(cache_dir))
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except Exception:  # pragma: no cover - knob renamed across jax versions
-            pass
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        if jax.default_backend() == "cpu":
+            jax.config.update("jax_enable_compilation_cache", False)
+            return None
+        path = (
+            os.path.abspath(os.path.expanduser(cache_dir))
+            if cache_dir
+            else DEFAULT_COMPILE_CACHE_DIR
+        )
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
-
-
-def maybe_enable_compile_cache(config: FasterRCNNConfig) -> Optional[str]:
-    """Config-driven variant: enable when ``compile.cache_dir`` is set."""
-    if config.compile.cache_dir:
-        return enable_compile_cache(config.compile.cache_dir)
-    return None
 
 
 def _mesh_for(config: FasterRCNNConfig):

@@ -332,3 +332,29 @@ class TestRunSupervisor:
             assert g1["rank"] == {0: 0, 2: 1}[rank]
         plan = elastic.read_plan(str(tmp_path / "shared"), 1)
         assert plan == {"generation": 1, "survivors": [0, 2], "world": 2}
+
+
+class TestSupervisorStaysOffTheChip:
+    def test_supervisor_imports_initialise_no_backend(self):
+        """libtpu gives the chip to one process. The `train --elastic`
+        supervisor spawns the training child, so everything it imports
+        before that — this module, the config, the fault codes — must
+        leave JAX's backends uninitialised, or the child it starts would
+        find the chip taken."""
+        import subprocess
+        import sys
+
+        code = (
+            "import replication_faster_rcnn_tpu.parallel.elastic\n"
+            "from replication_faster_rcnn_tpu.config import get_config\n"
+            "get_config('voc_resnet18').elastic\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized(), 'backend up'\n"
+            "print('OFF-CHIP-OK')\n"
+        )
+        r = subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=300,
+            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        )
+        assert r.returncode == 0 and "OFF-CHIP-OK" in r.stdout, r.stderr[-2000:]

@@ -1,6 +1,8 @@
 """Native C++ library tests: builds via make, binds via ctypes, and matches
 the numpy behavioral specs exactly (the fallbacks ARE the spec)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -182,21 +184,21 @@ class TestJpegDecode:
             is None
         )
 
-    def test_stale_so_rebuilds_and_reloads(self, lib_available):
-        """A pre-JPEG .so on disk must be rebuilt AND the fresh build must
-        actually be used (dlopen caches by pathname, so a naive reload
-        returns the stale handle — the rebuilt lib must come in under a
-        unique path). Runs in a subprocess: the dlopen cache is per-process
-        state this test must own from scratch."""
+    def test_hand_built_so_is_rebuilt_not_loaded(self, lib_available):
+        """An object this module did not build itself (here: a hand-run
+        ``make JPEG=0``, i.e. no JPEG entry points) fails the stamp check
+        and is rebuilt BEFORE anything is dlopened, so the process gets
+        the full library. Runs in a subprocess: it must own the load from
+        scratch."""
         import subprocess
         import sys
 
         code = """
 import subprocess, numpy as np
 import replication_faster_rcnn_tpu.data.native_ops as native_ops
-# simulate the stale library: a build without the JPEG entry points
-subprocess.run(["make", "-B", "-C", native_ops._REPO + "/native", "JPEG=0"],
+subprocess.run(["make", "-B", "-C", native_ops._NATIVE_DIR, "JPEG=0"],
                check=True, capture_output=True)
+assert not native_ops._stamp_matches()
 import io
 from PIL import Image
 rng = np.random.RandomState(0)
@@ -204,12 +206,10 @@ img = rng.randint(0, 256, (64, 64, 3), np.uint8)
 buf = io.BytesIO(); Image.fromarray(img).save(buf, "JPEG")
 mean = np.zeros(3, np.float32); std = np.ones(3, np.float32)
 got = native_ops.decode_jpeg_resize_normalize(buf.getvalue(), (32, 32), mean, std)
-assert got is not None, "stale .so was not rebuilt/reloaded"
+assert got is not None, "hand-built .so was loaded instead of rebuilt"
 assert got[1:] == (64, 64)
-# the stale-handle core bindings must still work after the swap
-out = native_ops.resize_normalize(img, (32, 32), mean, std)
-assert out.shape == (32, 32, 3)
-print("STALE-RELOAD-OK")
+assert native_ops._stamp_matches()
+print("REBUILT-OK")
 """
         r = subprocess.run(
             [sys.executable, "-c", code],
@@ -218,16 +218,9 @@ print("STALE-RELOAD-OK")
             timeout=300,
             cwd=native_ops._REPO,
         )
-        try:
-            assert r.returncode == 0 and "STALE-RELOAD-OK" in r.stdout, (
-                r.stdout + r.stderr
-            )
-        finally:  # restore the full build for later tests/processes
-            subprocess.run(
-                ["make", "-B", "-C", native_ops._REPO + "/native"],
-                capture_output=True,
-                timeout=300,
-            )
+        assert r.returncode == 0 and "REBUILT-OK" in r.stdout, (
+            r.stdout + r.stderr
+        )
 
     def test_png_in_jpg_falls_back_to_pil(self, tmp_path, lib_available):
         """_load_image must survive a non-JPEG file with a .jpg name (the
@@ -266,3 +259,77 @@ class TestScaleBoxes:
         labels = np.asarray([1], np.int32)
         out = native_ops.scale_boxes(boxes, labels, 1.5, 1.5)
         np.testing.assert_array_equal(out[0], np.round(boxes[0] * 1.5))
+
+
+class TestNeverLoadsAStaleObject:
+    """native/build/ is gitignored and the chip tool copies the tree as it
+    stands on disk, so an object can arrive from another checkout or
+    another CPU. Only one this host built from the current source loads."""
+
+    @pytest.fixture
+    def sandbox(self, tmp_path, monkeypatch):
+        import shutil
+
+        native = tmp_path / "native"
+        native.mkdir()
+        for name in ("frcnn_native.cpp", "Makefile"):
+            shutil.copy(os.path.join(native_ops._NATIVE_DIR, name), native)
+        monkeypatch.setattr(native_ops, "_NATIVE_DIR", str(native))
+        monkeypatch.setattr(native_ops, "_lib", None)
+        monkeypatch.setattr(native_ops, "_lib_checked", False)
+        builds = []
+        real_build = native_ops._try_build
+
+        def counting_build():
+            builds.append(1)
+            return real_build()
+
+        monkeypatch.setattr(native_ops, "_try_build", counting_build)
+        return native, builds
+
+    def _reload(self, monkeypatch):
+        monkeypatch.setattr(native_ops, "_lib", None)
+        monkeypatch.setattr(native_ops, "_lib_checked", False)
+        return native_ops._load_lib()
+
+    def test_builds_once_then_trusts_its_own_stamp(self, sandbox, monkeypatch):
+        _, builds = sandbox
+        if native_ops._load_lib() is None:
+            pytest.skip("no toolchain on this host")
+        assert builds == [1] and native_ops._stamp_matches()
+        assert self._reload(monkeypatch) is not None
+        assert builds == [1]  # same source, same host: no rebuild
+
+    def test_source_newer_than_object_rebuilds(self, sandbox, monkeypatch):
+        native, builds = sandbox
+        if native_ops._load_lib() is None:
+            pytest.skip("no toolchain on this host")
+        with open(native / "frcnn_native.cpp", "a") as f:
+            f.write("\n// edited after the object was built\n")
+        assert not native_ops._stamp_matches()
+        assert self._reload(monkeypatch) is not None
+        assert builds == [1, 1] and native_ops._stamp_matches()
+
+    def test_object_from_another_host_is_rebuilt_or_ignored(
+        self, sandbox, monkeypatch
+    ):
+        _, builds = sandbox
+        if native_ops._load_lib() is None:
+            pytest.skip("no toolchain on this host")
+        # the same files, seen from a machine with another CPU
+        monkeypatch.setattr(
+            native_ops, "_host_signature", lambda: "elsewhere\nmodel name: x"
+        )
+        assert not native_ops._stamp_matches()
+        assert self._reload(monkeypatch) is not None
+        assert builds == [1, 1]
+        # ... and where it cannot be rebuilt it is ignored, never loaded
+        monkeypatch.setattr(
+            native_ops, "_host_signature", lambda: "a third\nmodel name: y"
+        )
+        monkeypatch.setattr(native_ops, "_try_build", lambda: False)
+        monkeypatch.setattr(
+            native_ops.ctypes, "CDLL",
+            lambda *a, **k: pytest.fail("loaded an object built elsewhere"),
+        )
+        assert self._reload(monkeypatch) is None

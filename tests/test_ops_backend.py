@@ -66,6 +66,32 @@ class TestResolutionOrder:
     def test_interpret_mode_on_cpu(self):
         assert ops_pkg.interpret_mode() is True  # conftest pins CPU
 
+    def test_pallas_chosen_but_kernels_do_not_import_raises(self, monkeypatch):
+        """Choosing pallas and not getting it is an error — not an XLA
+        program under a pallas name."""
+        import sys
+
+        from replication_faster_rcnn_tpu.ops.nms import nms_fixed_auto
+
+        # a None entry makes `import ...ops.pallas` raise ImportError
+        monkeypatch.setitem(
+            sys.modules, "replication_faster_rcnn_tpu.ops.pallas", None
+        )
+        monkeypatch.delattr(ops_pkg, "pallas", raising=False)
+        cfg = FasterRCNNConfig(ops=OpsConfig(backend="pallas"))
+        with pytest.raises(RuntimeError, match="failed to import"):
+            ops_pkg.want_pallas("nms", cfg)
+        boxes, scores = np.zeros((4, 4), np.float32), np.zeros(4, np.float32)
+        with ops_pkg.backend_scope("pallas"):
+            with pytest.raises(RuntimeError, match="failed to import"):
+                nms_fixed_auto(boxes, scores, 0.5, 2)
+        monkeypatch.setattr(ops_pkg, "_env_backend", "pallas")  # the env way
+        with pytest.raises(RuntimeError, match="failed to import"):
+            ops_pkg.want_pallas("roi_align")
+        # the default backend never asks for the kernels at all
+        monkeypatch.setattr(ops_pkg, "_env_backend", "")
+        assert ops_pkg.want_pallas("nms") is False
+
 
 class TestOpsConfig:
     def test_default_backend_xla(self):

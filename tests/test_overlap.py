@@ -233,6 +233,95 @@ class TestConfigKnobs:
         assert cfg.data.prefetch_device == 0
 
 
+class TestCompileCachePlacement:
+    """`train/warmup.py::place_compile_cache` is the one owner of where
+    the persistent compilation cache lives."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """Record jax.config.update calls instead of applying them."""
+        import jax
+
+        seen = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: seen.__setitem__(k, v)
+        )
+        # the in-checkout default is for accelerators; see the CPU test
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        return seen
+
+    def test_env_places_it_and_flag_and_config_do_not_move_it(
+        self, updates, monkeypatch, tmp_path
+    ):
+        from replication_faster_rcnn_tpu.train import warmup
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        used = warmup.place_compile_cache(str(tmp_path / "flag"))
+        assert used == str(tmp_path / "env")
+        # JAX read the variable itself; no directory is set in code
+        assert "jax_compilation_cache_dir" not in updates
+        assert not (tmp_path / "flag").exists()
+        # cheap programs persist too
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+    def test_unset_env_uses_the_fixed_path_in_the_checkout(
+        self, updates, monkeypatch
+    ):
+        import os
+
+        from replication_faster_rcnn_tpu.train import warmup
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        used = warmup.place_compile_cache()
+        assert used == os.path.join(repo, ".compile_cache")
+        assert updates["jax_compilation_cache_dir"] == used
+        assert warmup.place_compile_cache() == used  # fixed, not per call
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".compile_cache/" in f.read().split()
+
+    def test_unset_env_honours_the_config_field(
+        self, updates, monkeypatch, tmp_path
+    ):
+        from replication_faster_rcnn_tpu.train import warmup
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        used = warmup.place_compile_cache(str(tmp_path / "flag"))
+        assert used == updates["jax_compilation_cache_dir"]
+        assert used == str(tmp_path / "flag")
+
+    def test_cpu_backend_keeps_no_cache_unless_the_env_asks(
+        self, updates, monkeypatch, tmp_path
+    ):
+        import jax
+
+        from replication_faster_rcnn_tpu.train import warmup
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert warmup.place_compile_cache(str(tmp_path / "flag")) is None
+        assert updates == {"jax_enable_compilation_cache": False}
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        assert warmup.place_compile_cache() == str(tmp_path / "env")
+
+    def test_one_call_site_sets_the_directory(self):
+        import os
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        hits = []
+        for top in ("replication_faster_rcnn_tpu", "benchmarks", "."):
+            for root, dirs, files in os.walk(os.path.join(repo, top)):
+                if top == ".":
+                    dirs.clear()  # the root-level scripts only
+                for name in files:
+                    if name.endswith(".py"):
+                        with open(os.path.join(root, name)) as f:
+                            if "jax_compilation_cache_dir" in f.read():
+                                hits.append(name)
+        assert hits == ["warmup.py"]
+
+
 class TestCLI:
     def _parse(self, argv):
         from replication_faster_rcnn_tpu import cli

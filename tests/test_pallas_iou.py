@@ -2,7 +2,7 @@
 EXACT parity — float outputs bitwise equal, integer outputs equal.
 
 The kernel is strict-IEEE by construction (runtime-zero products inside
-`_iou_cols` plus an optimization_barrier on the wrapper's kernel inputs,
+`_iou_grid` plus an optimization_barrier on the wrapper's kernel inputs,
 so XLA:CPU can neither FMA-contract the products nor fuse producers into
 the inlined interpret-mode body). Direct calls are therefore bitwise
 equal both to the XLA reference (`ops/boxes.py::iou` + jnp reductions)

@@ -5,7 +5,7 @@ same tile/fixpoint recurrence, so parity is exact equality of the
 interpret mode (pure JAX): the numerics tier-1 gates here are exactly
 what Mosaic compiles on a TPU, minus the codegen — which is why the
 wrapper pins strict-IEEE float behavior (runtime-zero products + an
-optimization_barrier on the kernel inputs; see `_iou_cols`)."""
+optimization_barrier on the kernel inputs; see `_iou_grid`)."""
 
 import jax
 import jax.numpy as jnp

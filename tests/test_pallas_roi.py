@@ -106,9 +106,15 @@ def test_spatial_scale_applied_inside_kernel():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_gradients_match_einsum_vjp_exactly():
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_gradients_match_einsum_vjp_exactly(dtype):
+    """bfloat16 is the presets' compute dtype: the kernel must return what
+    the XLA twin returns for it (float32 — the rois promote), or the
+    custom_vjp's cotangent does not fit the einsum VJP and the first
+    backward raises (found on the chip in PR 21; float32-only tests had
+    hidden it)."""
     rng = np.random.default_rng(3)
-    feat = _feat(8, 8, 3)
+    feat = _feat(8, 8, 3).astype(dtype)
     tl = rng.uniform(0, 5, (3, 2)).astype(np.float32)
     wh = rng.uniform(1, 2, (3, 2)).astype(np.float32)
     rois = jnp.asarray(np.concatenate([tl, tl + wh], axis=1))
@@ -122,10 +128,17 @@ def test_gradients_match_einsum_vjp_exactly():
     def loss_einsum(f):
         return jnp.vdot(roi_ops.roi_align(f, rois, method="einsum"), cot)
 
+    assert (
+        roi_align_pallas(feat, rois, interpret=True).dtype
+        == roi_ops.roi_align(feat, rois, method="einsum").dtype
+    )
     g_pal = jax.grad(loss_pallas)(feat)
     g_ein = jax.grad(loss_einsum)(feat)
+    assert g_pal.dtype == g_ein.dtype == dtype
     # custom_vjp replays the einsum formulation for the backward: exact
-    np.testing.assert_array_equal(np.asarray(g_pal), np.asarray(g_ein))
+    np.testing.assert_array_equal(
+        np.asarray(g_pal, np.float32), np.asarray(g_ein, np.float32)
+    )
 
 
 def test_vmap_over_batch():

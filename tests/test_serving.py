@@ -678,31 +678,6 @@ class TestServingProfileGate:
             assert banked[leg]["p99_ms"] >= banked[leg]["p50_ms"]
 
 
-def test_mfu_default_order_puts_wedge_risks_last():
-    """VERDICT round 5 item 5: safe validations first, FPN/trace/
-    transfer-stress legs last — pinned so appends can't silently
-    reshuffle ahead of the wedge classes."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "mfu_experiments", os.path.join(REPO, "benchmarks", "mfu_experiments.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    order = mod.DEFAULT_ORDER
-    assert sorted(order) == list(range(len(mod.EXPERIMENTS)))
-    names = [mod.EXPERIMENTS[i]["name"] for i in order]
-    # the four known wedge classes close the queue, in blast order
-    assert names[-5:] == [
-        "fpn_b8_reverify",
-        "fpn_b16",
-        "profile_trace_b16",
-        "loader_trainer_600",
-        "loader_trainer_600_u8",
-    ]
-    assert names.index("loader_trainer_600_devcache") < names.index("fpn_b16")
-
-
 # ------------------------------------------------------------- live engine
 
 

@@ -224,7 +224,9 @@ class TestMFU:
         assert tpu_peak_flops_per_sec("TPU v6e", 1) == 918e12
         # v5p must not fall through to the bare-v5 bucket and vice versa
         assert tpu_peak_flops_per_sec("TPU v5", 1) == 459e12
-        assert tpu_peak_flops_per_sec("Unknown Gen", 1) is None
+        # a TPU that is not in the table is an error, never a null peak
+        with pytest.raises(KeyError, match="Unknown Gen"):
+            tpu_peak_flops_per_sec("Unknown Gen", 1)
 
     def test_cpu_backend_peak_is_measured_and_nonnull(self):
         """On the CPU test backend the peak must come from the measured
