@@ -1,0 +1,506 @@
+"""One run of one cell: set-up, the timed window, the comparison with the
+reference, the result line.
+
+Every cell drives the program's `Trainer` (built as `cli train` builds it)
+through `Trainer.train_one_batch` over the feed its traffic mix describes:
+the bounded-step loop of `cli.py` with a deadline in place of the step
+count. `next(feed)` -> `train_one_batch` -> a host sync of the metrics every
+`SYNC_EVERY` steps (the trainer's `log_every` default); the window closes
+with `block_until_ready` on state and metrics.
+
+From the program this takes the trainer, its spans and its strict harness
+(which raises on a recompilation after warm-up). Traffic, weights, FLOP
+counts, peaks, the trace reduction and the reference are the benchmark's.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import importlib
+import importlib.util
+import json
+import math
+import os
+import shutil
+import sys
+import time
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import numpy as np
+
+from perf import compare, flops, manifest, peaks, traffic, xtrace
+
+SYNC_EVERY = 10  # the trainer's log_every default
+CHECK_STEPS = 3  # steps the reference follows
+WARM_STEPS = 4  # steps driven during set-up, through the window's own call
+TRACE_SLICE_S = 6.0  # the profiler is started this long before the window closes
+MIN_TRACED_S = 3.0  # and runs at least this long (a traced window may run over)
+EXIT_NO_CHIP = 3
+
+
+class RunFailed(RuntimeError):
+    pass
+
+
+# ------------------------------------------------------- program config
+
+
+def _set_dotted(cfg, dotted: Dict[str, Any]):
+    by_section: Dict[str, Dict[str, Any]] = {}
+    for key, value in dotted.items():
+        section, field = key.split(".", 1)
+        by_section.setdefault(section, {})[field] = _tuples(value)
+    for section, fields in by_section.items():
+        cfg = cfg.replace(**{section: dataclasses.replace(getattr(cfg, section), **fields)})
+    return cfg
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
+
+
+def _lists(v):
+    return [_lists(x) for x in v] if isinstance(v, (list, tuple)) else v
+
+
+def program_config(cell: manifest.Cell, seed: int, devkit: str, cache_dir: str):
+    """The preset with the cell's overrides; the run fails where the program
+    disagrees with a size the configuration's file states."""
+    from replication_faster_rcnn_tpu.config import get_config
+
+    conf, mix = cell.config, cell.mix
+    cfg = get_config(conf["program"]["preset"])
+    dotted = dict(conf["program"].get("overrides", {}))
+    dotted.update(mix.get("overrides", {}))
+    dotted.update(
+        {
+            "train.batch_size": int(conf["per_chip_batch"]) * cell.chips,
+            "train.seed": seed % (2**31 - 1),
+            "data.dataset": "voc",
+            "data.root_dir": devkit,
+            "debug.strict": True,
+            "compile.cache_dir": cache_dir,
+        }
+    )
+    cfg = _set_dotted(cfg, dotted)
+    for key, want in conf["sizes"].items():
+        section, field = key.split(".", 1)
+        have = _lists(getattr(getattr(cfg, section), field))
+        if have != want:
+            raise RunFailed(f"the program has {key}={have!r}, the configuration file {want!r}")
+    return cfg
+
+
+# ---------------------------------------------------------- the program
+
+
+def _adam_mu(opt_state):
+    for part in opt_state:
+        if hasattr(part, "mu"):
+            return part.mu
+    raise RunFailed("no Adam moments in the optimizer state")
+
+
+def _flat(tree) -> Dict[str, Any]:
+    from flax import traverse_util
+
+    return traverse_util.flatten_dict(tree, sep="/")
+
+
+def inject_weights(trainer, ref, sz, seed: int) -> None:
+    """Weights, Adam state and sampling key from the seed, made by the
+    benchmark in one jitted call and put where the trainer keeps its own."""
+    import jax
+    from flax import traverse_util
+
+    key = jax.random.PRNGKey(seed % (2**31 - 1))
+    flat = jax.jit(lambda k: ref.init_params(sz, jax.random.fold_in(k, 1)))(key)
+    have = _flat(trainer.state.params)
+    if {k: v.shape for k, v in flat.items()} != {k: v.shape for k, v in have.items()}:
+        odd = sorted(set(flat) ^ set(have))[:6]
+        raise RunFailed(f"the reference's parameters do not match the program's: {odd}")
+    params = traverse_util.unflatten_dict({tuple(k.split("/")): v for k, v in flat.items()})
+    sh = trainer._state_shardings
+    params = jax.device_put(params, sh.params)
+    trainer.state = trainer.state.replace(
+        params=params,
+        opt_state=jax.device_put(trainer.tx.init(params), sh.opt_state),
+        rng=jax.device_put(jax.random.fold_in(key, 2), sh.rng),
+    )
+
+
+def _norms_program():
+    import jax
+    import jax.numpy as jnp
+
+    def leaf_norms(tree):
+        return jax.tree_util.tree_map(lambda v: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))), tree)
+
+    return (
+        jax.jit(leaf_norms),
+        jax.jit(lambda a, b: leaf_norms(jax.tree_util.tree_map(lambda x, y: x - y, a, b))),
+    )
+
+
+def first_steps(trainer, feed, step_call: Callable) -> Dict[str, Any]:
+    """Drive the trainer through its first steps with the window's own call
+    and feed, and take what the comparison needs from that same object:
+    each step's loss, Adam's first moment after step 1 (the first gradient
+    is mu / (1 - b1)) and the parameters' change over the steps."""
+    import jax
+    import jax.numpy as jnp
+
+    copy = jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))
+    norms, diff_norms = _norms_program()
+    p0 = copy(trainer.state.params)
+    losses, mu1, p3 = [], None, None
+    with trainer.strict_session():
+        for i in range(WARM_STEPS):
+            metrics = step_call(next(feed))
+            if i < CHECK_STEPS:
+                losses.append(metrics)
+            if i == 0:
+                mu1 = copy(_adam_mu(trainer.state.opt_state))
+            if i == CHECK_STEPS - 1:
+                p3 = copy(trainer.state.params)
+    grad = jax.device_get(norms(mu1))
+    change = jax.device_get(diff_norms(p3, p0))
+    rows = jax.device_get(losses)
+    return {
+        "losses": [float(r["loss"]) for r in rows],
+        "parts": {k: float(rows[0][k]) for k in compare.LOSS_PARTS},
+        "skipped": [float(r.get("skipped", 0.0)) for r in rows],
+        "grad_norms": {k: float(v) / 0.1 for k, v in _flat(grad).items()},
+        "change_norms": {k: float(v) for k, v in _flat(change).items()},
+    }
+
+
+# -------------------------------------------------------- the reference
+
+
+def reference_numbers(
+    ref, sz, seed: int, batches: List[Dict[str, np.ndarray]], precision: str = "float32",
+    rows: Optional[int] = None, jitted: Optional[Dict[Any, Any]] = None,
+) -> Dict[str, Any]:
+    """The reference over the same batches from the same seed: its own
+    weights, its own steps. `rows` keeps only the first rows of each batch
+    (the half-batch fault of the control tests). A caller that runs many
+    seeds passes one `jitted` dict to keep the traced programs."""
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.PRNGKey(seed % (2**31 - 1))
+    params = jax.jit(lambda k: ref.init_params(sz, jax.random.fold_in(k, 1)))(key)
+    rng = jax.random.fold_in(key, 2)
+    adam = ref.init_adam(params)
+    jitted = {} if jitted is None else jitted
+    if precision not in jitted:
+        jitted[precision] = jax.jit(lambda p, a, b, r, s: ref.train_step(p, a, b, r, s, sz, precision))
+        jitted.setdefault("norms", jax.jit(ref.leaf_norms))
+    step, norms = jitted[precision], jitted["norms"]
+    p0, losses, grad, first = params, [], None, None
+    for i, host in enumerate(batches):
+        batch = {k: jnp.asarray(host[k][:rows]) for k in ("image", "boxes", "labels", "mask")}
+        params, adam, parts, seen = step(params, adam, batch, rng, jnp.asarray(i, jnp.int32))
+        losses.append(float(parts["loss"]))
+        if i == 0:
+            grad = jax.device_get(norms(seen))
+            first = {k: float(parts[k]) for k in compare.LOSS_PARTS}
+    change = jax.device_get(norms({k: params[k] - p0[k] for k in params}))
+    return {
+        "losses": losses,
+        "parts": first,
+        "grad_norms": {k: float(v) for k, v in grad.items()},
+        "change_norms": {k: float(v) for k, v in change.items()},
+    }
+
+
+def load_reference(cell: manifest.Cell):
+    return importlib.import_module("perf.references." + cell.config["reference"])
+
+
+def load_feed_reference(cell: manifest.Cell):
+    return importlib.import_module("perf.references." + cell.config["feed_reference"])
+
+
+def memory_held(stats: Dict[str, Any]) -> int:
+    """Peak bytes a chip held: the buffers the allocator counts
+    (`peak_bytes_in_use`: weights, optimizer state, staged batches) plus what
+    the runtime reserves for the programs' scratch, which it counts apart
+    (`peak_bytes_reserved`; on the v5e free = limit - in use - reserved)."""
+    return int(stats.get("peak_bytes_in_use", 0)) + int(stats.get("peak_bytes_reserved", 0))
+
+
+# --------------------------------------------------------------- window
+
+
+def _annotate(on: bool, name: str):
+    if not on:
+        return contextlib.nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
+
+
+def window(trainer, feed, step_call: Callable, seconds: float, batch: int,
+           trace_dir: Optional[str]) -> Dict[str, Any]:
+    """The timed loop. With `trace_dir` the profiler runs over the last
+    `TRACE_SLICE_S` seconds, between two drained queues; `pre` holds the
+    images and time before it started."""
+    import jax
+
+    tracing = trace_dir is not None
+    tracer = trainer.tracer
+    steps = bad = 0
+    metrics = None
+    out: Dict[str, Any] = {}
+    profiling = False
+    span_t0 = tracer.now_us() if tracing else 0.0
+    with trainer.strict_session():
+        t0 = time.perf_counter()
+        deadline = t0 + seconds
+        while True:
+            now = time.perf_counter()
+            if tracing and not profiling and steps > 0 and now >= deadline - TRACE_SLICE_S:
+                jax.block_until_ready((trainer.state, metrics))
+                t_pre = time.perf_counter()
+                out["pre"] = {"images": steps * batch, "seconds": t_pre - t0, "steps": steps}
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
+                profiling = True
+                now = out["profile_t0"] = time.perf_counter()
+                # starting the profiler can take seconds on a busy host, and a
+                # sync can outlast the slice: a traced window is never cut
+                # shorter than MIN_TRACED_S after the profiler is up
+                deadline = max(deadline, now + MIN_TRACED_S)
+            if now >= deadline:
+                break
+            with tracer.span("data/fetch", cat="data"), _annotate(profiling, "bench/fetch"):
+                kw = next(feed)
+            with _annotate(profiling, "bench/step"):
+                metrics = step_call(kw)
+            steps += 1
+            if steps % SYNC_EVERY == 0:
+                with tracer.span("step/sync", cat="sync"), _annotate(profiling, "bench/sync"):
+                    row = jax.device_get(metrics)
+                if row.get("skipped", 0.0) > 0 or not math.isfinite(float(row["loss"])):
+                    bad += 1
+        with _annotate(profiling, "bench/drain"):
+            jax.block_until_ready((trainer.state, metrics))
+        t1 = time.perf_counter()
+    if profiling:
+        jax.profiler.stop_trace()
+        out["profile_window_s"] = t1 - out.pop("profile_t0")
+        out["traced_steps"] = steps - out["pre"]["steps"]
+    last = jax.device_get(metrics)
+    if last.get("skipped", 0.0) > 0 or not math.isfinite(float(last["loss"])):
+        bad += 1
+    out.update(steps=steps, images=steps * batch, seconds=t1 - t0, bad=bad)
+    if tracing:
+        out["span_window_us"] = (span_t0, tracer.now_us())
+    return out
+
+
+def step_executable(trainer, host_batch) -> Dict[str, Any]:
+    """The step program a traced window drove, looked up again (the
+    persistent cache holds it): the names of its operations that hold a
+    convolution, which the trace alone does not say (a fused convolution is
+    printed as `%fusion.N`), and each operation's `op_name` metadata (the
+    jaxpr path, flax module names in it), with which the breakdown labels
+    the operations. The compiler's sizes of the program are in the
+    configuration's file, from `reckon_memory.py`."""
+    import re
+
+    staged = trainer._stage_batch(host_batch)
+    compiled = trainer.jitted_step.lower(trainer.state, staged).compile()
+    text = compiled.as_text()
+    conv_comps, comp = set(), None
+    calls: Dict[str, str] = {}
+    convs = set()
+    origin: Dict[str, str] = {}
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        # the result type may be a tuple with spaces; layouts hold none
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, opcode = m.groups()
+        where = re.search(r'op_name="([^"]*)"', line)
+        if where:
+            origin[name] = where.group(1)
+        if opcode == "convolution":
+            convs.add(name)
+            if comp:
+                conv_comps.add(comp)
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        if opcode == "fusion" and called:
+            calls[name] = called.group(1)
+    convs |= {name for name, c in calls.items() if c in conv_comps}
+    return {"conv_ops": convs, "origin": origin, "hlo_text": text}
+
+
+# ------------------------------------------------------------------ run
+
+
+def _load_reader(path: str):
+    spec = importlib.util.spec_from_file_location("perf_metric_" + os.path.basename(path)[:-3].replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def run_cell(
+    root: str,
+    manifest_path: str,
+    workload: str,
+    seed: int,
+    seconds: float,
+    trace: bool,
+    t_start: Optional[float] = None,
+    scratch: Optional[str] = None,
+    require_tpu: bool = True,
+    break_step: Optional[Callable] = None,
+    err=sys.stderr,
+) -> Tuple[Optional[Dict[str, Any]], int]:
+    """Run one cell once. Returns (result, exit code); the result is None
+    where no result line may be printed. `require_tpu=False` and `scratch`
+    are for the CPU rehearsals; `break_step` wraps the step call with a
+    fault, for the tests that must see `correct` come out false."""
+    t_start = time.perf_counter() if t_start is None else t_start
+    cell = manifest.Cell(root, manifest_path, workload)
+    import jax
+
+    devices = jax.devices()
+    if require_tpu and (devices[0].platform != "tpu" or len(devices) < cell.chips):
+        print(
+            f"{workload} needs {cell.chips} TPU chip(s); found {len(devices)} x "
+            f"{devices[0].platform}. No result.", file=err,
+        )
+        return None, EXIT_NO_CHIP
+    devices = devices[: cell.chips]
+    kind = devices[0].device_kind
+    # a rehearsal only walks the readers' code: its numbers are never reported
+    peak = peaks.peaks_for(kind) if require_tpu else peaks.PEAKS["TPU v5 lite"]
+
+    scratch = scratch or os.path.join(root, ".perf_scratch")
+    run_dir = os.path.join(scratch, "runs", workload)
+    shutil.rmtree(run_dir, ignore_errors=True)
+    # written anew in every run, at the cell's own fixed path: the same
+    # set-up work whether or not the seed was seen before
+    devkit = os.path.join(run_dir, "devkit")
+    record = traffic.build_devkit(devkit, seed, cell.mix)
+
+    cfg = program_config(cell, seed, devkit, os.path.join(root, ".compile_cache"))
+    batch = cfg.train.batch_size
+    telemetry = os.path.join(run_dir, "telemetry") if trace else None
+    trace_dir = os.path.join(run_dir, "profile") if trace else None
+
+    from replication_faster_rcnn_tpu.train import Trainer
+
+    trainer = Trainer(
+        cfg, workdir=os.path.join(run_dir, "workdir"), devices=devices, telemetry_dir=telemetry
+    )
+    ref = load_reference(cell)
+    sz = ref.Sizes(cell.config["sizes"], batch)
+    inject_weights(trainer, ref, sz, seed)
+    feed = traffic.Feed(trainer, cell.mix, keep=CHECK_STEPS)
+
+    def step_call(kw):
+        return trainer.train_one_batch(**kw)
+
+    if break_step is not None:
+        step_call = break_step(trainer, step_call)
+    try:
+        program = first_steps(trainer, feed, step_call)
+        jax.block_until_ready(trainer.state)
+        setup_s = time.perf_counter() - t_start
+        win = window(trainer, feed, step_call, seconds, batch, trace_dir)
+    finally:
+        feed.close()
+    del step_call
+    strict = trainer.strict.report() if trainer.strict is not None else {"programs": {}}
+    recompiles = sum(p["recompiles_after_warmup"] for p in strict["programs"].values())
+    stats = max(((d.memory_stats() or {}) for d in devices), key=memory_held)
+    mem_peak = memory_held(stats)
+    host_batches = feed.first_host_batches
+    if trace:
+        exe = step_executable(trainer, host_batches[0])
+        trainer.flush_telemetry()
+        with open(os.path.join(run_dir, "step_hlo.txt"), "w") as f:
+            f.write(exe["hlo_text"])
+    # free the program's state before the reference takes the chip
+    feed.free()
+    trainer.state = None
+    del trainer, feed
+    import gc
+
+    gc.collect()
+
+    t_ref = time.perf_counter()
+    reference = reference_numbers(ref, sz, seed, host_batches)
+    nums = compare.numbers(program, reference)
+    nums.update(load_feed_reference(cell).numbers(devkit, host_batches, cell.config["sizes"]))
+    ref_s = time.perf_counter() - t_ref
+    correct = compare.judge(nums, cell.config["limits"])
+    sound = recompiles == 0 and win["bad"] == 0 and not any(program["skipped"])
+    correct = bool(correct and sound)
+
+    values: Dict[str, float] = {}
+    if not trace:
+        # a cell's end-to-end metrics are `setup_s` and its image rate, under
+        # the name the manifest gives the rate in this cell
+        for metric in cell.end_to_end:
+            is_setup = metric["name"] == "setup_s"
+            values[metric["name"]] = setup_s if is_setup else win["images"] / win["seconds"]
+    device = {
+        "platform": devices[0].platform, "kind": kind, "count": len(devices),
+        "memory_peak_bytes": int(mem_peak),
+    }
+    result: Dict[str, Any] = {
+        "correct": correct,
+        "attempted": win["steps"] + WARM_STEPS,
+        "failed": win["bad"] + int(sum(1 for s in program["skipped"] if s)),
+    }
+    if trace:
+        reduced = xtrace.reduce(
+            xtrace.load_xplane(xtrace.find_xplane(trace_dir), rehearsal=not require_tpu),
+            win["profile_window_s"], exe["conv_ops"], exe["origin"],
+        )
+        with open(os.path.join(telemetry, "trace.json")) as f:
+            spans = json.load(f)["traceEvents"]
+        ctx = {
+            "cell": cell.name, "chips": cell.chips, "batch": batch, "sizes": cell.config["sizes"],
+            "mix": cell.mix, "peaks": peak, "window": win, "trace": reduced, "spans": spans,
+            "memory_peak_bytes": int(mem_peak), "flops": flops,
+        }
+        for metric in cell.per_layer:
+            value = _load_reader(cell.reader_path(metric["name"]))(ctx)
+            if value is not None:
+                values[metric["name"]] = float(value)
+        device["busy_s"] = reduced["busy_s"]
+        device["window_s"] = reduced["trace_window_s"]
+        result["breakdown"] = {
+            "device_ops": reduced["device_ops"], "idle_gaps": reduced["idle_gaps"],
+        }
+    units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
+    result["metrics"] = {k: {"value": v, "unit": units[k]} for k, v in values.items()}
+    result["device"] = device
+    result["notes"] = {
+        "steps": win["steps"], "window_s": win["seconds"], "recompiles": recompiles,
+        "reference_s": ref_s, "memory_stats": {k: int(v) for k, v in stats.items()},
+        "devkit_mean_file_bytes": record["mean_file_bytes"],
+        "losses_program": program["losses"], "losses_reference": reference["losses"],
+    }
+    compared = {k: {"value": v["value"], "limit": v["limit"]} for k, v in nums.items()}
+    compared["recompiles"] = {"value": recompiles, "limit": 0}
+    compared["bad_steps"] = {"value": result["failed"], "limit": 0}
+    result["compared"] = compared  # last, as the contract asks
+    for name, entry in compared.items():
+        where = f" at {nums[name]['at']}" if name in nums and nums[name].get("at") else ""
+        print(f"compared {name} = {entry['value']!r} limit {entry['limit']!r}{where}", file=err)
+    return result, 0
