@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Any, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from replication_faster_rcnn_tpu.config import FasterRCNNConfig
@@ -30,6 +31,7 @@ from replication_faster_rcnn_tpu.models.head import DetectionHead
 from replication_faster_rcnn_tpu.models.resnet import ResNetTrunk
 from replication_faster_rcnn_tpu.models.rpn import RPNHead, batched_proposals
 from replication_faster_rcnn_tpu.ops import anchors as anchor_ops
+from replication_faster_rcnn_tpu.telemetry import stages
 
 Array = jnp.ndarray
 
@@ -118,7 +120,8 @@ class FasterRCNN(nn.Module):
         """images NHWC [N, H, W, 3] -> shared features.
 
         Single-scale: one [N, H/16, W/16, C] map. FPN: list [P2..P6]."""
-        images = self.preprocess(images)
+        with jax.named_scope(stages.INPUT):
+            images = self.preprocess(images)
         if self.config.model.fpn:
             return self.neck(self.trunk(images, train))
         return self.trunk(images, train)

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from replication_faster_rcnn_tpu.models.resnet import ResNetTail
 from replication_faster_rcnn_tpu.ops import roi_ops
+from replication_faster_rcnn_tpu.telemetry import stages
 
 Array = jnp.ndarray
 
@@ -101,12 +102,6 @@ class DetectionHead(nn.Module):
         n, r = rois.shape[0], rois.shape[1]
         fh, fw = feat.shape[1], feat.shape[2]
 
-        # image -> feature coordinates (reference `nets/heads.py:42-44`)
-        scale = jnp.array(
-            [fh / img_h, fw / img_w, fh / img_h, fw / img_w], rois.dtype
-        )
-        feat_rois = rois * scale
-
         def extract(f: Array, rb: Array) -> Array:
             return roi_ops.extract_roi_features(
                 f,
@@ -116,8 +111,14 @@ class DetectionHead(nn.Module):
                 sampling_ratio=self.sampling_ratio,
             )
 
-        crops = jax.vmap(extract)(feat, feat_rois)  # [N, R, s, s, C]
-        crops = crops.reshape((n * r,) + crops.shape[2:])
+        with jax.named_scope(stages.ROI_POOL):
+            # image -> feature coordinates (reference `nets/heads.py:42-44`)
+            scale = jnp.array(
+                [fh / img_h, fw / img_w, fh / img_h, fw / img_w], rois.dtype
+            )
+            feat_rois = rois * scale
+            crops = jax.vmap(extract)(feat, feat_rois)  # [N, R, s, s, C]
+            crops = crops.reshape((n * r,) + crops.shape[2:])
 
         # Backbone tail: layer4+avgpool for ResNets (the reference's
         # `classifier`, `nets/heads.py:51-52`); fc6/fc7 for the
@@ -167,9 +168,10 @@ class FPNDetectionHead(nn.Module):
         from replication_faster_rcnn_tpu.models.fpn import multilevel_roi_align
 
         n, r = rois.shape[0], rois.shape[1]
-        crops = multilevel_roi_align(
-            feats, rois, img_h, img_w, self.roi_size, self.sampling_ratio
-        )  # [N, R, s, s, C]
+        with jax.named_scope(stages.ROI_POOL):
+            crops = multilevel_roi_align(
+                feats, rois, img_h, img_w, self.roi_size, self.sampling_ratio
+            )  # [N, R, s, s, C]
         x = crops.reshape(n * r, -1).astype(self.dtype)
         # dtype=self.dtype keeps the two big matmuls on the MXU in bf16
         # (param_dtype stays f32; flax would otherwise promote to f32).

@@ -55,6 +55,7 @@ from replication_faster_rcnn_tpu.config import FasterRCNNConfig
 from replication_faster_rcnn_tpu.models.faster_rcnn import FasterRCNN
 from replication_faster_rcnn_tpu.parallel import zero
 from replication_faster_rcnn_tpu.parallel.plan import Plan, compile_step_with_plan
+from replication_faster_rcnn_tpu.telemetry import stages
 from replication_faster_rcnn_tpu.train import fault
 from replication_faster_rcnn_tpu.train.train_step import TrainState, compute_losses
 
@@ -143,20 +144,21 @@ def make_shard_map_train_step(
         # sum to the global gradient. grad_allreduce_dtype=bfloat16 halves
         # the bytes this collective moves; the de-cast right after keeps
         # the optimizer math in the params' fp32.
-        if allreduce_dt != jnp.float32:
-            dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(allreduce_dt)
-                if jnp.issubdtype(g.dtype, jnp.floating)
-                else g,
-                grads,
-            )
-            grads = jax.lax.psum(grads, axis)
-            grads = jax.tree_util.tree_map(
-                lambda g, dt: g.astype(dt), grads, dtypes
-            )
-        else:
-            grads = jax.lax.psum(grads, axis)
+        with jax.named_scope(stages.UPDATE):
+            if allreduce_dt != jnp.float32:
+                dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(allreduce_dt)
+                    if jnp.issubdtype(g.dtype, jnp.floating)
+                    else g,
+                    grads,
+                )
+                grads = jax.lax.psum(grads, axis)
+                grads = jax.tree_util.tree_map(
+                    lambda g, dt: g.astype(dt), grads, dtypes
+                )
+            else:
+                grads = jax.lax.psum(grads, axis)
         # loss/count metrics are local-contribution / global-normalizer (or
         # plain local counts), so psum yields the batch-global values.
         metrics = jax.lax.psum(metrics, axis)
@@ -264,65 +266,65 @@ def make_shard_map_train_step(
             )(state.params)
             metrics = jax.lax.psum(metrics, axis)
 
-            if allreduce_dt != jnp.float32:
-                dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(allreduce_dt)
-                    if jnp.issubdtype(g.dtype, jnp.floating)
-                    else g,
-                    grads,
-                )
-                grads = jax.tree_util.tree_map(_reduce_grad, grads, param_dims)
-                grads = jax.tree_util.tree_map(
-                    lambda g, dt: g.astype(dt), grads, dtypes
-                )
-            else:
-                grads = jax.tree_util.tree_map(_reduce_grad, grads, param_dims)
+            with jax.named_scope(stages.UPDATE):
+                if allreduce_dt != jnp.float32:
+                    dtypes = jax.tree_util.tree_map(lambda g: g.dtype, grads)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(allreduce_dt)
+                        if jnp.issubdtype(g.dtype, jnp.floating)
+                        else g,
+                        grads,
+                    )
+                    grads = jax.tree_util.tree_map(_reduce_grad, grads, param_dims)
+                    grads = jax.tree_util.tree_map(
+                        lambda g, dt: g.astype(dt), grads, dtypes
+                    )
+                else:
+                    grads = jax.tree_util.tree_map(_reduce_grad, grads, param_dims)
 
-            # this shard's parameter slices; the optimizer chain is
-            # elementwise (add_decayed_weights / scale_by_adam / lr), so
-            # updating slices against the local moment slices computes
-            # exactly the slice of the full update
-            param_sl = jax.tree_util.tree_map(_slice, state.params, param_dims)
-            updates, new_opt = tx.update(grads, state.opt_state, param_sl)
-            new_param_sl = optax.apply_updates(param_sl, updates)
+                # this shard's parameter slices; the optimizer chain is
+                # elementwise (add_decayed_weights / scale_by_adam / lr), so
+                # updating slices against the local moment slices computes
+                # exactly the slice of the full update
+                param_sl = jax.tree_util.tree_map(_slice, state.params, param_dims)
+                updates, new_opt = tx.update(grads, state.opt_state, param_sl)
+                new_param_sl = optax.apply_updates(param_sl, updates)
 
-            # health on sharded trees: psum'd sums-of-squares reproduce the
-            # replicated backend's global norms (same numbers, modulo
-            # reduction order) and the nonfinite gate stays GLOBAL — every
-            # shard takes the same branch below
-            grad_norm = jnp.sqrt(_sharded_sumsq(grads, param_dims, _sumsq))
-            update_norm = jnp.sqrt(_sharded_sumsq(updates, param_dims, _sumsq))
-            param_norm = optax.global_norm(state.params)
-            nonfinite = _sharded_sumsq(grads, param_dims, _nonfin)
-            health = {
-                "grad_norm": grad_norm,
-                "param_norm": param_norm,
-                "update_norm": update_norm,
-                "update_ratio": update_norm / (param_norm + 1e-12),
-                "nonfinite_count": nonfinite,
-            }
-            if config.train.nonfinite_policy == "apply":
-                health["skipped"] = jnp.zeros((), jnp.float32)
-                sel_p, sel_opt, sel_stats = new_param_sl, new_opt, new_stats
-            else:
-                ok = nonfinite == 0
+                # health on sharded trees: psum'd sums-of-squares reproduce the
+                # replicated backend's global norms (same numbers, modulo
+                # reduction order) and the nonfinite gate stays GLOBAL — every
+                # shard takes the same branch below
+                grad_norm = jnp.sqrt(_sharded_sumsq(grads, param_dims, _sumsq))
+                update_norm = jnp.sqrt(_sharded_sumsq(updates, param_dims, _sumsq))
+                param_norm = optax.global_norm(state.params)
+                nonfinite = _sharded_sumsq(grads, param_dims, _nonfin)
+                health = {
+                    "grad_norm": grad_norm,
+                    "param_norm": param_norm,
+                    "update_norm": update_norm,
+                    "update_ratio": update_norm / (param_norm + 1e-12),
+                    "nonfinite_count": nonfinite,
+                }
+                if config.train.nonfinite_policy == "apply":
+                    health["skipped"] = jnp.zeros((), jnp.float32)
+                    sel_p, sel_opt, sel_stats = new_param_sl, new_opt, new_stats
+                else:
+                    ok = nonfinite == 0
 
-                def keep(new, old):
-                    # select BEFORE the gather: on a skipped step every
-                    # shard contributes its OLD slice, so the gathered
-                    # params are bit-identical to the pre-step tree
-                    return jnp.where(ok, new, old)
+                    def keep(new, old):
+                        # select BEFORE the gather: on a skipped step every
+                        # shard contributes its OLD slice, so the gathered
+                        # params are bit-identical to the pre-step tree
+                        return jnp.where(ok, new, old)
 
-                sel_p = jax.tree_util.tree_map(keep, new_param_sl, param_sl)
-                sel_opt = jax.tree_util.tree_map(keep, new_opt, state.opt_state)
-                sel_stats = jax.tree_util.tree_map(
-                    keep, new_stats, state.batch_stats
-                )
-                health["skipped"] = 1.0 - ok.astype(jnp.float32)
+                    sel_p = jax.tree_util.tree_map(keep, new_param_sl, param_sl)
+                    sel_opt = jax.tree_util.tree_map(keep, new_opt, state.opt_state)
+                    sel_stats = jax.tree_util.tree_map(
+                        keep, new_stats, state.batch_stats
+                    )
+                    health["skipped"] = 1.0 - ok.astype(jnp.float32)
+                new_params = jax.tree_util.tree_map(_gather, sel_p, param_dims)
             metrics.update(health)
-
-            new_params = jax.tree_util.tree_map(_gather, sel_p, param_dims)
             new_state = state.replace(
                 step=state.step + 1,
                 params=new_params,

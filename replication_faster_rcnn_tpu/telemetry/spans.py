@@ -18,6 +18,18 @@ once compiled; the first occurrence absorbs compilation), while the
 ``step/sync`` span at a log boundary measures the wait for the device to
 drain — i.e. device compute time for the interval. Feed-bound runs show
 fat ``data/*`` spans and a thin sync; compute-bound runs the reverse.
+
+On the profiler's clock too: an enabled tracer mirrors every ``span`` as a
+``jax.profiler.TraceAnnotation`` of the same name and arguments on the
+thread that runs it, so a ``--profile`` trace (`.xplane.pb`) holds the
+program's spans beside the device operations, loader threads on their own
+lines. The annotation costs under a microsecond while no profiler runs.
+jax is imported on the first span, never with this module, which the
+report tool imports on hosts without it. Spans of one step share an
+identifier: ``step/dispatch`` and the ``data/device_put`` that staged its
+batch carry ``step=<host step>``. A tracer that writes to a file opens with
+an instant ``telemetry/open`` whose ``args`` hold its absolute directory,
+which is how a reader handed the events alone finds the run's other files.
 """
 
 from __future__ import annotations
@@ -115,6 +127,29 @@ class SpanTracer:
         # Written lock-free on span entry; the watchdog reads it to report
         # what the process was last doing when a stall fires.
         self._last_span: Optional[Dict[str, Any]] = None
+        # jax.profiler.TraceAnnotation once a span has run (False: no jax)
+        self._annotation: Any = None
+        if path is not None:
+            self.instant(
+                "telemetry/open", cat="meta",
+                dir=os.path.dirname(os.path.abspath(path)),
+            )
+
+    def _annotate(self, name: str, args: Dict[str, Any]) -> Any:
+        """The span's twin on the profiler's clock, entered; None without
+        jax. A no-op of ~0.5 us while no profiler is recording."""
+        cls = self._annotation
+        if cls is None:
+            try:
+                from jax.profiler import TraceAnnotation as cls
+            except ImportError:
+                cls = False
+            self._annotation = cls
+        if cls is False:
+            return None
+        annotation = cls(name, **args)
+        annotation.__enter__()
+        return annotation
 
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
@@ -132,9 +167,12 @@ class SpanTracer:
     def span(self, name: str, cat: str = "phase", **args: Any) -> Iterator[None]:
         ts = self._now_us()
         self._last_span = {"name": name, "cat": cat, "started_wall": time.time()}
+        annotation = self._annotate(name, args)
         try:
             yield
         finally:
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             event = {
                 "name": name,
                 "cat": cat,

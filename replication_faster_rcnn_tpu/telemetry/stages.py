@@ -1,0 +1,36 @@
+"""The step program's stages, by the names a profiler trace shows.
+
+Each stage of the train step runs under one ``jax.named_scope`` of these
+names, so every device operation's ``op_name`` metadata says which stage it
+belongs to: ``jvp(frcnn.rpn)/...`` forward, ``transpose(jvp(frcnn.rpn))/...``
+backward. A scope nested in another (``frcnn.roi_pool`` inside
+``frcnn.box_head``, ``frcnn.input`` inside ``frcnn.trunk``) is the later one
+in the path, and the later one is the operation's stage. Scopes are metadata:
+they change no instruction of the compiled program.
+
+The names are what a trace reduction keys on (``perf/stagecut.py``), so they
+are fixed here and never built from configuration. No jax import: the
+constants are read by host-side tools too.
+"""
+
+INPUT = "frcnn.input"  # device-side jitter / augment / bucket resample / preprocess
+TRUNK = "frcnn.trunk"  # extract_features: trunk, FPN neck where there is one
+RPN = "frcnn.rpn"  # rpn_forward and the two RPN losses
+ANCHOR_TARGETS = "frcnn.anchor_targets"  # batched_anchor_targets
+PROPOSALS = "frcnn.proposals"  # propose: decode, clip, top-k, NMS
+ROI_TARGETS = "frcnn.roi_targets"  # batched_proposal_targets
+ROI_POOL = "frcnn.roi_pool"  # ROIPool / ROIAlign / multilevel align inside the head
+BOX_HEAD = "frcnn.box_head"  # the tail, the two heads, select_class_deltas, the head losses
+UPDATE = "frcnn.update"  # gradient exchange and rounding, the guard, the optimizer, health norms
+
+STAGES = (
+    INPUT,
+    TRUNK,
+    RPN,
+    ANCHOR_TARGETS,
+    PROPOSALS,
+    ROI_TARGETS,
+    ROI_POOL,
+    BOX_HEAD,
+    UPDATE,
+)

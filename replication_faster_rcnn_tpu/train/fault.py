@@ -52,6 +52,7 @@ import optax
 
 from replication_faster_rcnn_tpu.faultlib import failpoints
 from replication_faster_rcnn_tpu.telemetry import spans as tspans
+from replication_faster_rcnn_tpu.telemetry import stages
 from replication_faster_rcnn_tpu.telemetry.health import health_metrics
 
 # Distinct exit code for "preempted with a verified emergency checkpoint;
@@ -138,11 +139,19 @@ def guarded_update(
 
     ``'halt'`` gates exactly like ``'skip'`` — params must be clean when
     the host-side :class:`SkipMonitor` raises on the flag.
+
+    The whole of it runs under the ``frcnn.update`` scope
+    (`telemetry/stages.py`), in every step program that calls it.
     """
     if policy not in NONFINITE_POLICIES:
         raise ValueError(
             f"nonfinite_policy must be one of {NONFINITE_POLICIES}, got {policy!r}"
         )
+    with jax.named_scope(stages.UPDATE):
+        return _guarded_update(tx, state, grads, new_stats, policy)
+
+
+def _guarded_update(tx, state, grads, new_stats, policy):
     updates, new_opt = tx.update(grads, state.opt_state, state.params)
     new_params = optax.apply_updates(state.params, updates)
     health = health_metrics(grads, state.params, updates)

@@ -33,6 +33,7 @@ from replication_faster_rcnn_tpu.targets import (
     batched_anchor_targets,
     batched_proposal_targets,
 )
+from replication_faster_rcnn_tpu.telemetry import stages
 from replication_faster_rcnn_tpu.train import fault, losses
 
 Array = jnp.ndarray
@@ -66,6 +67,53 @@ def create_train_state(
         opt_state=tx.init(params),
         rng=state_rng,
     )
+
+
+def _device_input(config: FasterRCNNConfig, batch: Dict[str, Array], train_resolution):
+    """The batch as the trunk sees it: device-side jitter, augmentation and
+    bucket resample (each only where the batch or the program asks for
+    it). Returns (images, gt_boxes, gt_labels, gt_mask)."""
+    images = batch["image"]
+    if "jitter" in batch:
+        # device-side scale-jitter resample (data.augment_scale_device):
+        # the host shipped raw images + integer jitter geometry; the
+        # boxes in this batch are already transformed host-side
+        from replication_faster_rcnn_tpu.ops.image import batched_scale_jitter
+
+        images = batched_scale_jitter(images, batch["jitter"])
+    gt_boxes = batch["boxes"]
+    gt_labels = batch["labels"]
+    gt_mask = batch["mask"]
+    if "aug" in batch:
+        # FULLY on-device augmentation (data.augment_device): the host
+        # shipped raw samples + int32 (idx, epoch) rows; flip, translate
+        # and scale-jitter decisions are splitmix draws of
+        # (seed, epoch, idx) computed here, identical on every shard and
+        # every resume with zero communication. Runs at the base canvas,
+        # ahead of the bucket resample below.
+        from replication_faster_rcnn_tpu.ops.image import augment_batch
+
+        images, gt_boxes, gt_labels, gt_mask = augment_batch(
+            images,
+            gt_boxes,
+            gt_labels,
+            gt_mask,
+            batch["aug"],
+            seed=config.train.seed,
+            hflip=config.data.augment_hflip,
+            scale_range=config.data.augment_scale,
+            translate=config.data.augment_translate,
+        )
+    if train_resolution is not None:
+        # multi-scale bucket resample (static shape, per-bucket program)
+        from replication_faster_rcnn_tpu.ops.image import (
+            resize_batch_with_boxes,
+        )
+
+        images, gt_boxes = resize_batch_with_boxes(
+            images, gt_boxes, train_resolution
+        )
+    return images, gt_boxes, gt_labels, gt_mask
 
 
 def compute_losses(
@@ -108,46 +156,13 @@ def compute_losses(
     the jitter resample — so each bucket is its own compiled program,
     exactly like a serving bucket. None (the default) leaves the program
     byte-identical to the pre-bucket trace.
+
+    Each stage runs under its ``jax.named_scope`` of `telemetry/stages.py`
+    (metadata only), which is how a profiler trace is cut by stage.
     """
-    images = batch["image"]
-    if "jitter" in batch:
-        # device-side scale-jitter resample (data.augment_scale_device):
-        # the host shipped raw images + integer jitter geometry; the
-        # boxes in this batch are already transformed host-side
-        from replication_faster_rcnn_tpu.ops.image import batched_scale_jitter
-
-        images = batched_scale_jitter(images, batch["jitter"])
-    gt_boxes = batch["boxes"]
-    gt_labels = batch["labels"]
-    gt_mask = batch["mask"]
-    if "aug" in batch:
-        # FULLY on-device augmentation (data.augment_device): the host
-        # shipped raw samples + int32 (idx, epoch) rows; flip, translate
-        # and scale-jitter decisions are splitmix draws of
-        # (seed, epoch, idx) computed here, identical on every shard and
-        # every resume with zero communication. Runs at the base canvas,
-        # ahead of the bucket resample below.
-        from replication_faster_rcnn_tpu.ops.image import augment_batch
-
-        images, gt_boxes, gt_labels, gt_mask = augment_batch(
-            images,
-            gt_boxes,
-            gt_labels,
-            gt_mask,
-            batch["aug"],
-            seed=config.train.seed,
-            hflip=config.data.augment_hflip,
-            scale_range=config.data.augment_scale,
-            translate=config.data.augment_translate,
-        )
-    if train_resolution is not None:
-        # multi-scale bucket resample (static shape, per-bucket program)
-        from replication_faster_rcnn_tpu.ops.image import (
-            resize_batch_with_boxes,
-        )
-
-        images, gt_boxes = resize_batch_with_boxes(
-            images, gt_boxes, train_resolution
+    with jax.named_scope(stages.INPUT):
+        images, gt_boxes, gt_labels, gt_mask = _device_input(
+            config, batch, train_resolution
         )
     img_h, img_w = float(images.shape[1]), float(images.shape[2])
     variables = {"params": params, "batch_stats": batch_stats}
@@ -163,29 +178,39 @@ def compute_losses(
         rng_do = jax.random.fold_in(rng_do, jax.lax.axis_index(axis_name))
 
     # trunk + RPN (train mode: BN batch stats update)
-    feat, mut = model.apply(
-        variables, images, train, method="extract_features", mutable=["batch_stats"]
-    )
+    with jax.named_scope(stages.TRUNK):
+        feat, mut = model.apply(
+            variables, images, train, method="extract_features",
+            mutable=["batch_stats"],
+        )
     if features_wall:
         feat = jax.tree_util.tree_map(jax.lax.stop_gradient, feat)
-    logits, deltas, anchors = model.apply(variables, feat, method="rpn_forward")
+    with jax.named_scope(stages.RPN):
+        logits, deltas, anchors = model.apply(
+            variables, feat, method="rpn_forward"
+        )
 
     # first-stage targets, on device
-    reg_t, lab_t = batched_anchor_targets(
-        rng_at, gt_boxes, gt_mask, anchors, config.rpn_targets, positions
-    )
-    rpn_reg_loss = losses.loc_loss(deltas, reg_t, lab_t, sigma, axis_name)
-    rpn_cls_loss = losses.ignore_cross_entropy(logits, lab_t, axis_name)
+    with jax.named_scope(stages.ANCHOR_TARGETS):
+        reg_t, lab_t = batched_anchor_targets(
+            rng_at, gt_boxes, gt_mask, anchors, config.rpn_targets, positions
+        )
+    with jax.named_scope(stages.RPN):
+        rpn_reg_loss = losses.loc_loss(deltas, reg_t, lab_t, sigma, axis_name)
+        rpn_cls_loss = losses.ignore_cross_entropy(logits, lab_t, axis_name)
 
     # proposals (stop-grad, reference detach semantics) + second-stage targets
-    rois, roi_valid = model.apply(
-        variables, logits, deltas, anchors, img_h, img_w, train, method="propose"
-    )
-    sample_rois, reg_t2, lab_t2 = batched_proposal_targets(
-        rng_pt, rois, roi_valid, gt_boxes, gt_labels, gt_mask, config.roi_targets,
-        positions,
-        strategy=config.train.sampling_strategy,
-    )
+    with jax.named_scope(stages.PROPOSALS):
+        rois, roi_valid = model.apply(
+            variables, logits, deltas, anchors, img_h, img_w, train,
+            method="propose",
+        )
+    with jax.named_scope(stages.ROI_TARGETS):
+        sample_rois, reg_t2, lab_t2 = batched_proposal_targets(
+            rng_pt, rois, roi_valid, gt_boxes, gt_labels, gt_mask,
+            config.roi_targets, positions,
+            strategy=config.train.sampling_strategy,
+        )
     if targets_only:
         probe = (
             reg_t.sum() + lab_t.sum() + sample_rois.sum()
@@ -195,22 +220,25 @@ def compute_losses(
 
     # head on the sampled rois (BN in the tail also updates; the VGG16
     # tail's dropout draws from the 'dropout' rng in train mode)
-    (cls_out, reg_out), mut2 = model.apply(
-        # norm="group" models carry no batch_stats collection — flax then
-        # omits the key from the mutated-state dict
-        {"params": params, "batch_stats": mut.get("batch_stats", {})},
-        feat,
-        sample_rois,
-        img_h,
-        img_w,
-        train,
-        method="head_forward",
-        mutable=["batch_stats"],
-        rngs={"dropout": rng_do} if train else None,
-    )
-    reg_sel = select_class_deltas(reg_out, lab_t2)
-    head_reg_loss = losses.loc_loss(reg_sel, reg_t2, lab_t2, sigma, axis_name)
-    head_cls_loss = losses.ignore_cross_entropy(cls_out, lab_t2, axis_name)
+    with jax.named_scope(stages.BOX_HEAD):
+        (cls_out, reg_out), mut2 = model.apply(
+            # norm="group" models carry no batch_stats collection — flax
+            # then omits the key from the mutated-state dict
+            {"params": params, "batch_stats": mut.get("batch_stats", {})},
+            feat,
+            sample_rois,
+            img_h,
+            img_w,
+            train,
+            method="head_forward",
+            mutable=["batch_stats"],
+            rngs={"dropout": rng_do} if train else None,
+        )
+        reg_sel = select_class_deltas(reg_out, lab_t2)
+        head_reg_loss = losses.loc_loss(
+            reg_sel, reg_t2, lab_t2, sigma, axis_name
+        )
+        head_cls_loss = losses.ignore_cross_entropy(cls_out, lab_t2, axis_name)
 
     w1, w2, w3, w4 = config.train.loss_weights
     total = (
@@ -243,12 +271,13 @@ def quantize_grads(grads: Any, dtype_str: str) -> Any:
     if dtype_str == "float32":
         return grads
     dt = jnp.dtype(dtype_str)
-    return jax.tree_util.tree_map(
-        lambda g: g.astype(dt).astype(g.dtype)
-        if jnp.issubdtype(g.dtype, jnp.floating)
-        else g,
-        grads,
-    )
+    with jax.named_scope(stages.UPDATE):
+        return jax.tree_util.tree_map(
+            lambda g: g.astype(dt).astype(g.dtype)
+            if jnp.issubdtype(g.dtype, jnp.floating)
+            else g,
+            grads,
+        )
 
 
 def make_train_step(

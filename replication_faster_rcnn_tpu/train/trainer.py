@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import os
 import sys
 import time
@@ -864,23 +865,33 @@ class Trainer:
     # ---------------------------------------------------------------- train
 
     def _stage_batch(
-        self, batch: Dict[str, np.ndarray], wait: bool = False
+        self,
+        batch: Dict[str, np.ndarray],
+        wait: bool = False,
+        step: Optional[int] = None,
     ) -> Dict[str, jax.Array]:
         """One host batch (or --cache-device selection dict) -> sharded
         device arrays: the ``data/device_put`` half of a step. ``wait``
         blocks until the transfer lands — used by the device stager's
-        producer thread so the copy itself is off the critical path."""
+        producer thread so the copy itself is off the critical path.
+        ``step`` is the host step the batch will train, where the caller
+        knows it: the span carries it, as that step's ``step/dispatch``
+        does."""
         feed = "device_cache" if self.device_cache is not None else "loader"
-        with self.tracer.span("data/device_put", cat="data", feed=feed):
+        ids = {} if step is None else {"step": step}
+        with self.tracer.span("data/device_put", cat="data", feed=feed, **ids):
             return stage_to_devices(
                 batch, self.mesh, self.config.mesh, wait=wait
             )
 
-    def _stage_chunk(self, batches, wait: bool = False) -> Dict[str, jax.Array]:
+    def _stage_chunk(
+        self, batches, wait: bool = False, step: Optional[int] = None
+    ) -> Dict[str, jax.Array]:
         """K host batches -> one stacked [K, B, ...] sharded device chunk
         for the fused dispatch (stack_selections in --cache-device mode,
-        np.stack otherwise)."""
+        np.stack otherwise). ``step``: the chunk's first host step."""
         k = len(batches)
+        ids = {} if step is None else {"step": step}
         if self.device_cache is not None:
             from replication_faster_rcnn_tpu.data.device_cache import (
                 stack_selections,
@@ -894,7 +905,7 @@ class Trainer:
             }
             feed = "loader"
         with self.tracer.span(
-            "data/device_put", cat="data", feed=feed, steps=k
+            "data/device_put", cat="data", feed=feed, steps=k, **ids
         ):
             return stage_to_devices(
                 stacked, self.mesh, self.config.mesh, stacked=True, wait=wait
@@ -913,10 +924,11 @@ class Trainer:
         ``bucket_of`` assignment); None dispatches the single-scale
         program."""
         tracer = self.tracer
+        step = self._host_step + 1  # the identifier this step's spans share
         if staged is None:
             # in --cache-device mode `batch` is a selection dict (idx/flip/
             # jitter — bytes, not megabytes); the images never leave device
-            staged = self._stage_batch(batch)
+            staged = self._stage_batch(batch, step=step)
         step_fn = self.jitted_step
         program = "train_step"
         if bucket is not None and self.jitted_bucket_steps is not None:
@@ -925,12 +937,12 @@ class Trainer:
             program = f"train_step_{bh}x{bw}"
         strict = self._strict_dispatch(program, step_fn)
         if self.device_cache is not None:
-            with tracer.span("step/dispatch", cat="step"), strict:
+            with tracer.span("step/dispatch", cat="step", step=step), strict:
                 self.state, metrics = step_fn(
                     self.state, self.device_cache.arrays, staged
                 )
         else:
-            with tracer.span("step/dispatch", cat="step"), strict:
+            with tracer.span("step/dispatch", cat="step", step=step), strict:
                 self.state, metrics = step_fn(self.state, staged)
         self._host_step += 1
         # hand the monitor this step's `skipped` flag as a DEVICE scalar —
@@ -955,13 +967,14 @@ class Trainer:
         chunk's dispatch overlaps device compute.
         """
         k = self.steps_per_dispatch
+        first = self._host_step + 1  # the chunk's spans carry its first step
         if staged is None:
             if len(batches) != k:
                 raise ValueError(
                     f"train_chunk got {len(batches)} batches; the fused step "
                     f"was compiled for steps_per_dispatch={k}"
                 )
-            staged = self._stage_chunk(batches)
+            staged = self._stage_chunk(batches, step=first)
         tracer = self.tracer
         step_fn = self.jitted_multi_step
         program = f"multi_step_k{k}"
@@ -971,16 +984,19 @@ class Trainer:
             program = f"multi_step_k{k}_{bh}x{bw}"
         strict = self._strict_dispatch(program, step_fn)
         if self.device_cache is not None:
-            with tracer.span("step/dispatch", cat="step", steps=k), strict:
+            with tracer.span(
+                "step/dispatch", cat="step", steps=k, step=first
+            ), strict:
                 self.state, metrics = step_fn(
                     self.state, self.device_cache.arrays, staged
                 )
         else:
-            with tracer.span("step/dispatch", cat="step", steps=k), strict:
+            with tracer.span(
+                "step/dispatch", cat="step", steps=k, step=first
+            ), strict:
                 self.state, metrics = step_fn(
                     self.state, staged
                 )
-        first = self._host_step + 1
         self._host_step += k
         self.skip_monitor.observe(first, metrics)  # stacked [K] device flags
         return metrics
@@ -1216,10 +1232,16 @@ class Trainer:
                         # resumed epoch's trained prefix never reaches the
                         # producer — the feed itself starts at the resume
                         # offset (set_epoch start_batch above).
+                        # the producer stages in dispatch order, so the
+                        # n-th item it stages trains host steps
+                        # step + n*k + 1 .. (its spans' `step` identifier)
+                        stage_steps = itertools.count(step + 1, k)
                         stage = (
-                            (lambda bs: self._stage_chunk(bs, wait=True))
+                            (lambda bs: self._stage_chunk(
+                                bs, wait=True, step=next(stage_steps)))
                             if k > 1
-                            else (lambda bs: self._stage_batch(bs[0], wait=True))
+                            else (lambda bs: self._stage_batch(
+                                bs[0], wait=True, step=next(stage_steps)))
                         )
                         stager = DevicePrefetcher(
                             iter(feed), stage,

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -48,12 +49,10 @@ from replication_faster_rcnn_tpu.telemetry import spans as tspans
 # where the persistent compilation cache lives when the environment does
 # not place it: one fixed path inside the checkout (gitignored). The path
 # is part of the cache key's world — a directory that moves never hits.
-DEFAULT_COMPILE_CACHE_DIR = os.path.join(
-    os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    ),
-    ".compile_cache",
+CHECKOUT_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(CHECKOUT_ROOT, ".compile_cache")
 
 
 def place_compile_cache(cache_dir: str = "") -> Optional[str]:
@@ -71,7 +70,17 @@ def place_compile_cache(cache_dir: str = "") -> Optional[str]:
 
     The min-compile-time / min-entry-size gates are dropped to zero so
     even cheap programs persist — this cache exists to make *restarts*
-    free, and a restart replays every program, not just the slow ones."""
+    free, and a restart replays every program, not just the slow ones.
+
+    The key holds the program's metadata (source locations and name
+    scopes), which JAX leaves out by default: a cached executable carries
+    the ``op_name`` of whoever compiled it, so without this a run loads
+    another commit's executable and its profiler trace shows that commit's
+    names (found on the chip in PR 24: the step read 100 % outside the
+    stage scopes of `telemetry/stages.py`, from a step an earlier commit
+    had cached). A hit is now this source's own program: one with traced
+    lines moved compiles its own. Source files inside the checkout are
+    named relative to it, so where the checkout lies is not in the key."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         if jax.default_backend() == "cpu":
@@ -85,6 +94,11 @@ def place_compile_cache(cache_dir: str = "") -> Optional[str]:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(CHECKOUT_ROOT + os.sep),
+    )
     return path
 
 

@@ -265,6 +265,35 @@ class TestCompileCachePlacement:
         assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
         assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
 
+    def test_a_hit_is_this_sources_own_program(self, updates, monkeypatch, tmp_path):
+        """Name scopes and locations are part of the key: an executable
+        another commit cached carries that commit's `op_name`s, and a trace
+        of it would be cut by stages this program does not have."""
+        import jax
+
+        from replication_faster_rcnn_tpu.train import warmup
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        warmup.place_compile_cache(str(tmp_path / "flag"))
+        assert updates["jax_compilation_cache_include_metadata_in_key"] is True
+        updates.clear()
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+        warmup.place_compile_cache()
+        assert updates["jax_compilation_cache_include_metadata_in_key"] is True
+        # where the checkout lies is not part of it: its files are named
+        # relative to it
+        import os
+        import re
+
+        pattern = updates["jax_hlo_source_file_canonicalization_regex"]
+        inside = os.path.join(warmup.CHECKOUT_ROOT, "perf", "harness.py")
+        assert re.sub(pattern, "", inside) == os.path.join("perf", "harness.py")
+        assert re.sub(pattern, "", "/opt/venv/lib/x.py") == "/opt/venv/lib/x.py"
+        assert os.path.isdir(os.path.join(warmup.CHECKOUT_ROOT, "replication_faster_rcnn_tpu"))
+        # both options exist on the installed jax, under these names
+        for name in updates:
+            assert hasattr(jax.config, name), name
+
     def test_unset_env_uses_the_fixed_path_in_the_checkout(
         self, updates, monkeypatch
     ):

@@ -505,10 +505,17 @@ class TestHTTPOverload:
                 base, {"path": _png(tmp_path, "img.png")}, headers=header
             )
             assert status == 200
-            events = [e for e in tracer.to_dict()["traceEvents"]
-                      if e["ph"] == "X"
-                      and e.get("args", {}).get("trace_id") == tid]
-            names = {e["name"] for e in events}
+            # the handler emits `serve/request` after it has replied: the
+            # reply can reach this thread first, so wait for the span
+            deadline = time.monotonic() + 5.0
+            while True:
+                events = [e for e in tracer.to_dict()["traceEvents"]
+                          if e["ph"] == "X"
+                          and e.get("args", {}).get("trace_id") == tid]
+                names = {e["name"] for e in events}
+                if "serve/request" in names or time.monotonic() > deadline:
+                    break
+                time.sleep(0.01)
             assert {"serve/request", "serve/queue_wait",
                     "serve/dispatch"} <= names
             # the hops are phases of ONE replica-side span: they share

@@ -8,27 +8,6 @@ from replication_faster_rcnn_tpu.utils import debug, profiling
 
 
 class TestProfiling:
-    def test_step_timer_window(self):
-        t = profiling.StepTimer(window=3)
-        assert t.update(8) is None
-        assert t.update(8) is None
-        ips = t.update(8)
-        assert ips is not None and ips > 0
-
-    def test_measure_throughput_carries_state(self):
-        calls = []
-
-        def fake_step(state, batch):
-            calls.append(state)
-            return state + 1, {"loss": jnp.asarray(1.0)}
-
-        out = profiling.measure_throughput(
-            fake_step, (jnp.asarray(0), None), batch_size=4, n_steps=5, warmup=2
-        )
-        assert out["images_per_sec"] > 0
-        # warmup advanced state before the timed loop
-        assert int(calls[2]) == 2
-
     def test_trace_writes_dir(self, tmp_path):
         d = str(tmp_path / "trace")
         with profiling.trace(d):
