@@ -117,6 +117,23 @@ class TestStageScopes:
         assert any(f"jvp({stages.TRUNK})" in n and stages.INPUT in n for n in names)
         assert any(f"{stages.UPDATE}/" in n and "jvp(" not in n for n in names)
 
+    def test_roi_pool_custom_vjp_keeps_the_scope_both_ways(self, jit_step):
+        """ROIPool's backward is hand-written (`jax.custom_vjp`): its
+        operations must still read under `frcnn.roi_pool`, as `transpose(`,
+        in the compiled step's `op_name`s, or the stage cut would put them
+        down as unscoped. The two selection matmuls stand for the rest."""
+        ops = set(re.findall(r'op_name="([^"]*)"', jit_step.compile().as_text()))
+        forward = [n for n in ops if n.endswith("rjk,khc->rjhc/dot_general")]
+        backward = [n for n in ops if n.endswith("rjk,rjhc->khc/dot_general")]
+        assert forward and backward
+        for n in forward:
+            assert f"jvp({stages.BOX_HEAD})" in n and f"/{stages.ROI_POOL}/" in n and "transpose(" not in n, n
+        for n in backward:
+            assert f"transpose(jvp({stages.BOX_HEAD}))" in n and f"/{stages.ROI_POOL}/" in n, n
+        # and nothing of `roi_pool`'s own jit is outside the scope
+        strays = [n for n in ops if "jit(roi_pool)" in n and stages.ROI_POOL not in n]
+        assert not strays, strays[:3]
+
     def test_scopes_change_no_instruction(self, jit_step, monkeypatch):
         """The step lowered with `jax.named_scope` patched to a null context
         is the same program, locations (the later `metadata={...}`) apart."""
