@@ -4,18 +4,17 @@ The timed path's first steps against the plain reference on the same
 batches: each step's loss, the norm of the first gradient as the optimizer
 got it, and the norm of the parameters' change after the steps. Norms are
 compared by the worst leaf: the gap between the program's norm and the
-reference's (not the norm of their difference: the two sample different
-anchors and ROIs once a rounding flips one selection), over the reference's
-norm of that leaf or of the median leaf, whichever is larger.
+reference's (not the norm of their difference: where a model samples, the
+two sample differently once a rounding flips one selection), over the
+reference's norm of that leaf or of the median leaf, whichever is larger.
+Which loss parts and which named leaves are compared besides is the
+model's: the configuration's reference module states them.
 """
 
 from __future__ import annotations
 
 import math
 from typing import Any, Dict, List, Sequence, Tuple
-
-
-LOSS_PARTS = ("rpn_cls_loss", "rpn_reg_loss", "head_cls_loss", "head_reg_loss")
 
 
 def leaf_gaps(
@@ -59,24 +58,25 @@ def moving_leaves(ref_grad: Dict[str, float]) -> List[str]:
 def numbers(program: Dict[str, Any], reference: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
     """{name: {"value": gap, "at": leaf}} for every number compared.
     `program` and `reference` hold `losses` (one per step), `parts` (the
-    first step's four losses), `grad_norms` and `change_norms` (per leaf)."""
+    first step's loss parts), `grad_norms` and `change_norms` (per leaf);
+    `reference` also `named_leaves`, the model's own numbers as
+    {name: leaf prefixes}: each the worst gap of the first gradient's norms
+    over the leaves under its prefixes."""
     out: Dict[str, Dict[str, Any]] = {}
     for i, (lp, lr) in enumerate(zip(program["losses"], reference["losses"])):
         out[f"loss{i + 1}_gap"] = {"value": abs(lp - lr) / max(abs(lr), 1e-30)}
-    for part in LOSS_PARTS:
-        lp, lr = program["parts"][part], reference["parts"][part]
-        out[f"{part[:-5]}1_gap"] = {"value": abs(lp - lr) / max(abs(lr), 1e-30)}
+    for part, lr in reference["parts"].items():
+        lp = program["parts"][part]
+        out[f"{part.removesuffix('_loss')}1_gap"] = {"value": abs(lp - lr) / max(abs(lr), 1e-30)}
     leaves = sorted(reference["grad_norms"])
     gap, at = worst_leaf_gap(program["grad_norms"], reference["grad_norms"], leaves)
     out["grad_norm_gap"] = {"value": gap, "at": at}
-    # the RPN heads' own leaves: their gradient comes from the two RPN losses
-    # alone, upstream of every proposal, so no flipped selection reaches it.
-    # The objectness kernel's is the steadiest (256 sampled anchors an image).
-    rpn = [k for k in leaves if k.startswith("rpn/cls/") or k.startswith("rpn/reg/")]
-    gap, at = worst_leaf_gap(program["grad_norms"], reference["grad_norms"], rpn)
-    out["rpn_grad_norm_gap"] = {"value": gap, "at": at}
-    gap, _ = worst_leaf_gap(program["grad_norms"], reference["grad_norms"], ["rpn/cls/kernel"])
-    out["rpn_cls_grad_gap"] = {"value": gap}
+    for name, prefixes in reference["named_leaves"].items():
+        picked = [k for k in leaves if k.startswith(tuple(prefixes))]
+        if not picked:
+            raise KeyError(f"{name}: no leaf of the reference lies under {prefixes!r}")
+        gap, at = worst_leaf_gap(program["grad_norms"], reference["grad_norms"], picked)
+        out[name] = {"value": gap, "at": at}
     out["grad_norm_median_gap"] = {
         "value": median_leaf_gap(program["grad_norms"], reference["grad_norms"], leaves)
     }

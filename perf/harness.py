@@ -8,28 +8,53 @@ count. `next(feed)` -> `train_one_batch` -> a host sync of the metrics every
 `SYNC_EVERY` steps (the trainer's `log_every` default); the window closes
 with `block_until_ready` on state and metrics.
 
-From the program this takes the trainer, its spans and its strict harness
-(which raises on a recompilation after warm-up). Traffic, weights, FLOP
-counts, peaks, the trace reduction and the reference are the benchmark's.
+The harness owns what is true of any training cell: the manifest, set-up and
+its clock, weights and Adam state injected from the seed, the first-steps
+capture, the window loop, the trace reduction, memory, the recompile count,
+the generic comparison (perf/compare.py), the result line. The rest is
+behind the two names in the configuration's file, each a module looked up as
+a reader is (`load_module`: `references/<name>.py` beside the cell's data
+files first, else perf/references/):
+
+`reference`, the model's side: `Sizes(sizes, batch)`, `init_params(sz, key)`
+(flat, "/"-joined leaf names), `init_adam`, `train_step(params, adam, batch,
+rng, step, sz, precision)`, `leaf_norms`; `BATCH_KEYS` the step takes,
+`LOSS_PARTS` compared at step 1, `LEAF_NUMBERS` {number: leaf prefixes},
+`SCOPE_PREFIX` of the program's stage scopes, and the functions the cell's
+readers call on `ctx["flops"]` (`train_flops_per_image(sizes)`: per sample).
+`feed_reference`, the data's side: `make(directory, seed, mix)` -> record,
+`overrides(directory)` -> dotted program keys, `batch_spec(sizes, batch)`,
+`notes(record)`, `numbers(directory, host batches, sizes)`.
+
+The program (`run_cell`'s `program`, default `package_program()`) offers
+`get_config(preset)`: a config with `replace` and dataclass sections, of
+which `train.batch_size`, `train.seed`, `debug.strict`, `compile.cache_dir`
+and what the overrides and `sizes` name; and `Trainer(cfg, workdir=,
+devices=, telemetry_dir=)` with `state` (`params`, `opt_state` holding Adam's
+`mu`, `rng`, `replace`), `tx`, `_state_shardings`, `loader` (`set_epoch`,
+iteration), `_stage_batch(batch, wait=)`, `train_one_batch(batch=|staged=)`
+giving metrics with `loss`, the parts (and `skipped` where the program has
+it), `strict_session()`, `strict` (None or `report()`), `tracer` (`span`,
+`now_us`), `jitted_step`, `flush_telemetry()`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib
 import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from perf import compare, flops, manifest, peaks, traffic, xtrace
+from perf import compare, manifest, peaks, stagecut, traffic, xtrace
 
 SYNC_EVERY = 10  # the trainer's log_every default
 CHECK_STEPS = 3  # steps the reference follows
@@ -64,25 +89,27 @@ def _lists(v):
     return [_lists(x) for x in v] if isinstance(v, (list, tuple)) else v
 
 
-def program_config(cell: manifest.Cell, seed: int, devkit: str, cache_dir: str):
-    """The preset with the cell's overrides; the run fails where the program
-    disagrees with a size the configuration's file states."""
+def package_program() -> Tuple[Callable, Any]:
+    """The package's own `(get_config, Trainer)`."""
     from replication_faster_rcnn_tpu.config import get_config
+    from replication_faster_rcnn_tpu.train import Trainer
 
+    return get_config, Trainer
+
+
+def program_config(cell: manifest.Cell, seed: int, data: Dict[str, Any], cache_dir: str, get_config: Callable):
+    """The preset with the cell's overrides and the data module's (`data`);
+    the run fails where the program disagrees with a size the
+    configuration's file states."""
     conf, mix = cell.config, cell.mix
     cfg = get_config(conf["program"]["preset"])
-    dotted = dict(conf["program"].get("overrides", {}))
-    dotted.update(mix.get("overrides", {}))
-    dotted.update(
-        {
-            "train.batch_size": int(conf["per_chip_batch"]) * cell.chips,
-            "train.seed": seed % (2**31 - 1),
-            "data.dataset": "voc",
-            "data.root_dir": devkit,
-            "debug.strict": True,
-            "compile.cache_dir": cache_dir,
-        }
-    )
+    dotted = {
+        **conf["program"].get("overrides", {}), **mix.get("overrides", {}), **data,
+        "train.batch_size": int(conf["per_chip_batch"]) * cell.chips,
+        "train.seed": seed % (2**31 - 1),
+        "debug.strict": True,
+        "compile.cache_dir": cache_dir,
+    }
     cfg = _set_dotted(cfg, dotted)
     for key, want in conf["sizes"].items():
         section, field = key.split(".", 1)
@@ -143,11 +170,12 @@ def _norms_program():
     )
 
 
-def first_steps(trainer, feed, step_call: Callable) -> Dict[str, Any]:
+def first_steps(trainer, feed, step_call: Callable, parts: Sequence[str]) -> Dict[str, Any]:
     """Drive the trainer through its first steps with the window's own call
     and feed, and take what the comparison needs from that same object:
-    each step's loss, Adam's first moment after step 1 (the first gradient
-    is mu / (1 - b1)) and the parameters' change over the steps."""
+    each step's loss (the first's `parts` too), Adam's first moment after
+    step 1 (the first gradient is mu / (1 - b1)) and the parameters' change
+    over the steps."""
     import jax
     import jax.numpy as jnp
 
@@ -169,7 +197,7 @@ def first_steps(trainer, feed, step_call: Callable) -> Dict[str, Any]:
     rows = jax.device_get(losses)
     return {
         "losses": [float(r["loss"]) for r in rows],
-        "parts": {k: float(rows[0][k]) for k in compare.LOSS_PARTS},
+        "parts": {k: float(rows[0][k]) for k in parts},
         "skipped": [float(r.get("skipped", 0.0)) for r in rows],
         "grad_norms": {k: float(v) / 0.1 for k, v in _flat(grad).items()},
         "change_norms": {k: float(v) for k, v in _flat(change).items()},
@@ -201,27 +229,47 @@ def reference_numbers(
     step, norms = jitted[precision], jitted["norms"]
     p0, losses, grad, first = params, [], None, None
     for i, host in enumerate(batches):
-        batch = {k: jnp.asarray(host[k][:rows]) for k in ("image", "boxes", "labels", "mask")}
+        batch = {k: jnp.asarray(host[k][:rows]) for k in ref.BATCH_KEYS}
         params, adam, parts, seen = step(params, adam, batch, rng, jnp.asarray(i, jnp.int32))
         losses.append(float(parts["loss"]))
         if i == 0:
             grad = jax.device_get(norms(seen))
-            first = {k: float(parts[k]) for k in compare.LOSS_PARTS}
+            first = {k: float(parts[k]) for k in ref.LOSS_PARTS}
     change = jax.device_get(norms({k: params[k] - p0[k] for k in params}))
     return {
         "losses": losses,
         "parts": first,
         "grad_norms": {k: float(v) for k, v in grad.items()},
         "change_norms": {k: float(v) for k, v in change.items()},
+        "named_leaves": ref.LEAF_NUMBERS,
     }
 
 
+def load_file(path: str):
+    """The module a file holds, loaded once a process."""
+    name = "perf_file_" + re.sub(r"\W", "_", os.path.abspath(path))
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        sys.modules[name] = mod = importlib.util.module_from_spec(spec)
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[name]
+            raise
+    return sys.modules[name]
+
+
+def load_module(data_dir: str, kind: str, stem: str):
+    """`<kind>/<stem>.py` beside a cell's data files, else the harness's own."""
+    return load_file(manifest.beside(data_dir, kind, stem))
+
+
 def load_reference(cell: manifest.Cell):
-    return importlib.import_module("perf.references." + cell.config["reference"])
+    return load_module(cell.data_dir, "references", cell.config["reference"])
 
 
 def load_feed_reference(cell: manifest.Cell):
-    return importlib.import_module("perf.references." + cell.config["feed_reference"])
+    return load_module(cell.data_dir, "references", cell.config["feed_reference"])
 
 
 def memory_held(stats: Dict[str, Any]) -> int:
@@ -311,28 +359,21 @@ def step_executable(trainer, host_batch) -> Dict[str, Any]:
     jaxpr path, flax module names in it), with which the breakdown labels
     the operations. The compiler's sizes of the program are in the
     configuration's file, from `reckon_memory.py`."""
-    import re
-
     staged = trainer._stage_batch(host_batch)
     compiled = trainer.jitted_step.lower(trainer.state, staged).compile()
     text = compiled.as_text()
     conv_comps, comp = set(), None
     calls: Dict[str, str] = {}
     convs = set()
-    origin: Dict[str, str] = {}
     for line in text.splitlines():
         head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$", line)
         if head:
             comp = head.group(1)
             continue
-        # the result type may be a tuple with spaces; layouts hold none
-        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(", line)
+        m = stagecut.INSTRUCTION_RE.match(line)
         if not m:
             continue
         name, opcode = m.groups()
-        where = re.search(r'op_name="([^"]*)"', line)
-        if where:
-            origin[name] = where.group(1)
         if opcode == "convolution":
             convs.add(name)
             if comp:
@@ -341,17 +382,10 @@ def step_executable(trainer, host_batch) -> Dict[str, Any]:
         if opcode == "fusion" and called:
             calls[name] = called.group(1)
     convs |= {name for name, c in calls.items() if c in conv_comps}
-    return {"conv_ops": convs, "origin": origin, "hlo_text": text}
+    return {"conv_ops": convs, "origin": stagecut.load_origin(text), "hlo_text": text}
 
 
 # ------------------------------------------------------------------ run
-
-
-def _load_reader(path: str):
-    spec = importlib.util.spec_from_file_location("perf_metric_" + os.path.basename(path)[:-3].replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
 
 
 def run_cell(
@@ -365,12 +399,15 @@ def run_cell(
     scratch: Optional[str] = None,
     require_tpu: bool = True,
     break_step: Optional[Callable] = None,
+    program: Optional[Tuple[Callable, Any]] = None,
     err=sys.stderr,
 ) -> Tuple[Optional[Dict[str, Any]], int]:
     """Run one cell once. Returns (result, exit code); the result is None
     where no result line may be printed. `require_tpu=False` and `scratch`
     are for the CPU rehearsals; `break_step` wraps the step call with a
-    fault, for the tests that must see `correct` come out false."""
+    fault, for the tests that must see `correct` come out false; `program`
+    is the `(get_config, Trainer)` driven, the package's own unless a
+    rehearsal brings another."""
     t_start = time.perf_counter() if t_start is None else t_start
     cell = manifest.Cell(root, manifest_path, workload)
     import jax
@@ -392,16 +429,15 @@ def run_cell(
     shutil.rmtree(run_dir, ignore_errors=True)
     # written anew in every run, at the cell's own fixed path: the same
     # set-up work whether or not the seed was seen before
-    devkit = os.path.join(run_dir, "devkit")
-    record = traffic.build_devkit(devkit, seed, cell.mix)
+    feed_ref = load_feed_reference(cell)
+    data_dir = os.path.join(run_dir, "data")
+    record = feed_ref.make(data_dir, seed, cell.mix)
 
-    cfg = program_config(cell, seed, devkit, os.path.join(root, ".compile_cache"))
+    get_config, Trainer = program or package_program()
+    cfg = program_config(cell, seed, feed_ref.overrides(data_dir), os.path.join(root, ".compile_cache"), get_config)
     batch = cfg.train.batch_size
     telemetry = os.path.join(run_dir, "telemetry") if trace else None
     trace_dir = os.path.join(run_dir, "profile") if trace else None
-
-    from replication_faster_rcnn_tpu.train import Trainer
-
     trainer = Trainer(
         cfg, workdir=os.path.join(run_dir, "workdir"), devices=devices, telemetry_dir=telemetry
     )
@@ -416,7 +452,7 @@ def run_cell(
     if break_step is not None:
         step_call = break_step(trainer, step_call)
     try:
-        program = first_steps(trainer, feed, step_call)
+        first = first_steps(trainer, feed, step_call, ref.LOSS_PARTS)
         jax.block_until_ready(trainer.state)
         setup_s = time.perf_counter() - t_start
         win = window(trainer, feed, step_call, seconds, batch, trace_dir)
@@ -433,6 +469,8 @@ def run_cell(
         trainer.flush_telemetry()
         with open(os.path.join(run_dir, "step_hlo.txt"), "w") as f:
             f.write(exe["hlo_text"])
+        with open(os.path.join(run_dir, stagecut.SCOPE_FILE), "w") as f:
+            f.write(ref.SCOPE_PREFIX)
     # free the program's state before the reference takes the chip
     feed.free()
     trainer.state = None
@@ -443,17 +481,18 @@ def run_cell(
 
     t_ref = time.perf_counter()
     reference = reference_numbers(ref, sz, seed, host_batches)
-    nums = compare.numbers(program, reference)
-    nums.update(load_feed_reference(cell).numbers(devkit, host_batches, cell.config["sizes"]))
+    nums = compare.numbers(first, reference)
+    nums.update(feed_ref.numbers(data_dir, host_batches, cell.config["sizes"]))
     ref_s = time.perf_counter() - t_ref
     correct = compare.judge(nums, cell.config["limits"])
-    sound = recompiles == 0 and win["bad"] == 0 and not any(program["skipped"])
+    sound = recompiles == 0 and win["bad"] == 0 and not any(first["skipped"])
     correct = bool(correct and sound)
 
     values: Dict[str, float] = {}
     if not trace:
-        # a cell's end-to-end metrics are `setup_s` and its image rate, under
-        # the name the manifest gives the rate in this cell
+        # a cell's end-to-end metrics are `setup_s` and its rate in samples
+        # (what its data module batches), under the name the manifest gives
+        # the rate in this cell
         for metric in cell.end_to_end:
             is_setup = metric["name"] == "setup_s"
             values[metric["name"]] = setup_s if is_setup else win["images"] / win["seconds"]
@@ -464,7 +503,7 @@ def run_cell(
     result: Dict[str, Any] = {
         "correct": correct,
         "attempted": win["steps"] + WARM_STEPS,
-        "failed": win["bad"] + int(sum(1 for s in program["skipped"] if s)),
+        "failed": win["bad"] + int(sum(1 for s in first["skipped"] if s)),
     }
     if trace:
         reduced = xtrace.reduce(
@@ -476,10 +515,10 @@ def run_cell(
         ctx = {
             "cell": cell.name, "chips": cell.chips, "batch": batch, "sizes": cell.config["sizes"],
             "mix": cell.mix, "peaks": peak, "window": win, "trace": reduced, "spans": spans,
-            "memory_peak_bytes": int(mem_peak), "flops": flops,
+            "memory_peak_bytes": int(mem_peak), "flops": ref,
         }
         for metric in cell.per_layer:
-            value = _load_reader(cell.reader_path(metric["name"]))(ctx)
+            value = load_file(cell.reader_path(metric["name"])).read(ctx)
             if value is not None:
                 values[metric["name"]] = float(value)
         device["busy_s"] = reduced["busy_s"]
@@ -493,8 +532,8 @@ def run_cell(
     result["notes"] = {
         "steps": win["steps"], "window_s": win["seconds"], "recompiles": recompiles,
         "reference_s": ref_s, "memory_stats": {k: int(v) for k, v in stats.items()},
-        "devkit_mean_file_bytes": record["mean_file_bytes"],
-        "losses_program": program["losses"], "losses_reference": reference["losses"],
+        **feed_ref.notes(record),
+        "losses_program": first["losses"], "losses_reference": reference["losses"],
     }
     compared = {k: {"value": v["value"], "limit": v["limit"]} for k, v in nums.items()}
     compared["recompiles"] = {"value": recompiles, "limit": 0}

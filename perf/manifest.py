@@ -2,10 +2,15 @@
 manifest must pass before any run (names, units, which cell reports what).
 
 The harness finds everything by name from here: a cell's configuration
-file (`configs[].file`), its traffic mix (`<dir of the manifest's first
-path>/mixes/<traffic>.json`) and each per-layer metric's reader
-(`.../metrics/<name up to its first dot>.py`). Adding a configuration, a mix, a cell or a
-metric therefore adds files and manifest entries and edits nothing.
+file (`configs[].file`); beside it (`<dir of that file>/../`) its traffic mix
+(`mixes/<traffic>.json`); and, beside it first and else with the harness
+(`beside`), each per-layer metric's reader (`metrics/<name up to its first
+dot>.py`) and the two modules the configuration's file names: `reference`,
+which owns all that is the model's, and `feed_reference`, all that is the
+data's (`references/<name>.py`; perf/harness.py lists what each states and
+what the harness asks of the program). Adding a configuration, a mix, a
+cell, a metric, or another kind of model with its data therefore adds files
+and manifest entries and edits nothing.
 """
 
 from __future__ import annotations
@@ -25,6 +30,18 @@ WIDTH_WORDS = ("hidden", "intermediate", "latent", "state_size", "proj", "head_d
 
 class ManifestError(ValueError):
     pass
+
+
+def data_dir_of(config_file: str) -> str:
+    """Where a configuration's mixes, readers and references sit: beside `configs/`."""
+    return os.path.dirname(os.path.dirname(os.path.abspath(config_file)))
+
+
+def beside(data_dir: str, kind: str, stem: str) -> str:
+    """`<kind>/<stem>.py` beside a cell's data files, else with the harness
+    (the rehearsal cells read through the harness's own)."""
+    own = os.path.join(data_dir, kind, stem + ".py")
+    return own if os.path.exists(own) else os.path.join(os.path.dirname(os.path.abspath(__file__)), kind, stem + ".py")
 
 
 def load(path: str) -> Dict[str, Any]:
@@ -214,8 +231,7 @@ class Cell:
         cfg_entry = next(c for c in self.manifest["configs"] if c["name"] == self.cell["config"])
         self.config_entry = cfg_entry
         self.config = load(os.path.join(root, cfg_entry["file"]))
-        # mixes and metric readers sit beside the configuration files
-        self.data_dir = os.path.dirname(os.path.dirname(os.path.join(root, cfg_entry["file"])))
+        self.data_dir = data_dir_of(os.path.join(root, cfg_entry["file"]))
         self.mix = load(os.path.join(self.data_dir, "mixes", self.cell["traffic"] + ".json"))
 
     def _reported(self, metrics: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
@@ -230,12 +246,7 @@ class Cell:
         return self._reported(self.manifest["per_layer"])
 
     def reader_path(self, metric: str) -> str:
-        """The metric's reader: beside the cell's data files, else with the
-        harness (the rehearsal cells read through the same readers). A
-        quantity split by the end-to-end metric it moves (`dispatch_ms.fed`,
-        `dispatch_ms.resident`) is read by the one reader of its first part."""
-        reader = metric.split(".", 1)[0] + ".py"
-        own = os.path.join(self.data_dir, "metrics", reader)
-        if os.path.exists(own):
-            return own
-        return os.path.join(os.path.dirname(os.path.abspath(__file__)), "metrics", reader)
+        """The metric's reader. A quantity split by the end-to-end metric it
+        moves (`dispatch_ms.fed`, `dispatch_ms.resident`) is read by the one
+        reader of its first part."""
+        return beside(self.data_dir, "metrics", metric.split(".", 1)[0])

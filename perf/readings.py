@@ -3,9 +3,9 @@ a cell's own size, many seeds in one process, each judged as a run judges.
 
     python3 perf/readings.py --workload <cell> --seeds 12 --control-seeds 3
 
-For each seed: fresh weights and batches from the seed, the trainer's first
-steps through `train_one_batch`, and the loader's rows against the feed's
-reference (the LOWER readings: the program against the plain references).
+For each seed: fresh weights and batches from the seed (the data set made by
+the configuration's data module), the trainer's first steps through
+`train_one_batch`, and the loader's rows against the feed's reference (the LOWER readings: the program against the plain references).
 On the first `--control-seeds` seeds also the UPPER readings, each put in
 the program's place: the control (the reference in float8; the feed's
 reference with pixels rounded to uint8), the half-batch fault (the
@@ -36,7 +36,7 @@ KINDS = ("program", "control_float8", "half_batch", "control_feed_uint8", "feed_
 
 
 def fresh_loader(trainer, root_dir: str, seed: int):
-    """A loader over another devkit, with the arguments Trainer.__init__
+    """A loader over another data set, with the arguments Trainer.__init__
     gives its own."""
     import dataclasses
 
@@ -77,17 +77,17 @@ def take(cell, devices, seeds, control_seeds: int, scratch: str, out_path: str, 
     """Read and judge `seeds`; True where every limit held what it has to."""
     import jax
 
-    from perf import compare, harness, traffic
-    from replication_faster_rcnn_tpu.train import Trainer
+    from perf import compare, harness
 
     limits, sizes = cell.config["limits"], cell.config["sizes"]
     ref = harness.load_reference(cell)
     feed_ref = harness.load_feed_reference(cell)
-    kit = os.path.join(scratch, "devkit")
-    cfg = harness.program_config(cell, seeds[0], kit, os.path.join(ROOT, ".compile_cache"))
+    get_config, Trainer = harness.package_program()
+    kit = os.path.join(scratch, "data")
+    cfg = harness.program_config(cell, seeds[0], feed_ref.overrides(kit), os.path.join(ROOT, ".compile_cache"), get_config)
     batch = cfg.train.batch_size
     mix = dict(cell.mix, n_images=harness.WARM_STEPS * batch)
-    traffic.build_devkit(kit, seeds[0], mix)
+    feed_ref.make(kit, seeds[0], mix)
     trainer = Trainer(cfg, workdir=os.path.join(scratch, "workdir"), devices=devices)
     init_stats = jax.device_get(trainer.state.batch_stats)
     sz = ref.Sizes(sizes, batch)
@@ -96,12 +96,12 @@ def take(cell, devices, seeds, control_seeds: int, scratch: str, out_path: str, 
     with open(out_path, "w") as out:
         for n, seed in enumerate(seeds):
             t0 = time.time()
-            traffic.build_devkit(kit, seed, mix)
+            feed_ref.make(kit, seed, mix)
             host = list(fresh_loader(trainer, kit, seed % (2**31 - 1)))[: harness.WARM_STEPS]
             harness.inject_weights(trainer, ref, sz, seed)
             reset_state(trainer, init_stats)
             feed = iter([{"batch": b} for b in host])
-            program = harness.first_steps(trainer, feed, lambda kw: trainer.train_one_batch(**kw))
+            program = harness.first_steps(trainer, feed, lambda kw: trainer.train_one_batch(**kw), ref.LOSS_PARTS)
             three = host[: harness.CHECK_STEPS]
             reference = harness.reference_numbers(ref, sz, seed, three, jitted=jitted)
             sound_feed = feed_ref.numbers(kit, three, sizes)

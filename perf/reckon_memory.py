@@ -2,10 +2,11 @@
 
     JAX_PLATFORMS=cpu python3 perf/reckon_memory.py <config> [<config> ...] [--write]
 
-Lowers the program's own `make_train_step` (and the benchmark's float32
-reference step) at the configuration's per-chip batch for a DESCRIBED TPU
-v5e (`jax.experimental.topologies`, no chip attached), compiles with the
-TPU compiler installed here and prints `memory_analysis()`. `--write` puts
+Lowers the program's own train step (and the benchmark's float32 reference
+step) over the batch the configuration's data module describes
+(`batch_spec`), at the per-chip batch, for a DESCRIBED TPU v5e
+(`jax.experimental.topologies`, no chip attached), compiles with the TPU
+compiler installed here and prints `memory_analysis()`. `--write` puts
 the bytes into the configuration file's `memory_reckoning`. Nothing runs:
 these are the compiler's sizes, not a chip's readings. About a minute a
 program; not a test.
@@ -24,34 +25,16 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def reckon(config_path: str, with_reference: bool) -> dict:
+def package_step(cfg):
+    """The package's own `(step, state)`: the un-jitted train step
+    `(state, batch) -> (state, metrics)` and the shapes of its state."""
     import jax
-    import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
-    from perf import harness
-    from replication_faster_rcnn_tpu.config import get_config
     from replication_faster_rcnn_tpu.train.train_step import (
         create_train_state, make_optimizer, make_train_step,
     )
 
-    with open(config_path) as f:
-        conf = json.load(f)
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    chip = SingleDeviceSharding(topo.devices[0])
-    b = int(conf["per_chip_batch"])
-    cfg = get_config(conf["program"]["preset"])
-    cfg = harness._set_dotted(cfg, dict(conf["program"].get("overrides", {}), **{"train.batch_size": b}))
     tx, _ = make_optimizer(cfg, 64)
-    h, w = cfg.data.image_size
-    m = cfg.data.max_boxes
-
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
-        )
-
     model = None
 
     def init():
@@ -59,17 +42,43 @@ def reckon(config_path: str, with_reference: bool) -> dict:
         model, state = create_train_state(cfg, jax.random.PRNGKey(0), tx)
         return state
 
-    state = on_chip(jax.eval_shape(init))
-    batch = on_chip(
-        {
-            "image": jax.ShapeDtypeStruct((b, h, w, 3), jnp.float32),
-            "boxes": jax.ShapeDtypeStruct((b, m, 4), jnp.float32),
-            "labels": jax.ShapeDtypeStruct((b, m), jnp.int32),
-            "mask": jax.ShapeDtypeStruct((b, m), jnp.bool_),
-            "difficult": jax.ShapeDtypeStruct((b, m), jnp.bool_),
-        }
-    )
-    out = {"per_chip_batch": b, "device": "described v5e:2x2, one chip; compiler sizes, nothing ran"}
+    state = jax.eval_shape(init)
+    return make_train_step(model, cfg, tx), state
+
+
+def reckon(config_path: str, with_reference: bool, program=None, chip=None) -> dict:
+    """`program` is `(get_config, step_and_state)`, the package's own unless
+    a rehearsal brings another; `chip` the sharding compiled for, a described
+    v5e chip unless a test has none to describe."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from perf import harness, manifest
+
+    with open(config_path) as f:
+        conf = json.load(f)
+    device = "described v5e:2x2, one chip" if chip is None else str(chip)
+    if chip is None:
+        from jax.experimental import topologies
+
+        chip = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
+    get_config, step_and_state = program or (harness.package_program()[0], package_step)
+    b = int(conf["per_chip_batch"])
+    cfg = get_config(conf["program"]["preset"])
+    cfg = harness._set_dotted(cfg, dict(conf["program"].get("overrides", {}), **{"train.batch_size": b}))
+    data_dir = manifest.data_dir_of(config_path)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+        )
+
+    train_step, state = step_and_state(cfg)
+    state = on_chip(state)
+    spec = harness.load_module(data_dir, "references", conf["feed_reference"]).batch_spec(conf["sizes"], b)
+    batch = on_chip({k: jax.ShapeDtypeStruct(shape, dtype) for k, (shape, dtype) in spec.items()})
+    out = {"per_chip_batch": b, "device": device + "; compiler sizes, nothing ran"}
 
     def sizes(compiled, secs):
         ma = compiled.memory_analysis()
@@ -81,17 +90,15 @@ def reckon(config_path: str, with_reference: bool) -> dict:
         }
 
     t = time.time()
-    step = jax.jit(make_train_step(model, cfg, tx), donate_argnums=(0,))
+    step = jax.jit(train_step, donate_argnums=(0,))
     out["train_step"] = sizes(step.lower(state, batch).compile(), time.time() - t)
     print(conf["name"], "train_step", out["train_step"], flush=True)
     if with_reference:
-        import importlib
-
-        ref = importlib.import_module("perf.references." + conf["reference"])
+        ref = harness.load_module(data_dir, "references", conf["reference"])
         sz = ref.Sizes(conf["sizes"], b)
         params = on_chip(jax.eval_shape(lambda: ref.init_params(sz, jax.random.PRNGKey(0))))
         adam = {"mu": params, "nu": params}
-        rbatch = {k: batch[k] for k in ("image", "boxes", "labels", "mask")}
+        rbatch = {k: batch[k] for k in ref.BATCH_KEYS}
         key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
         i = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
         t = time.time()

@@ -8,8 +8,9 @@ losses, the gradients, and Adam with L2 weight decay. No kernels, no
 tiling, no mixed precision: float32 everywhere, matrix products at
 `Precision.HIGHEST`.
 
-It imports nothing of the program. It reads its sizes from the
-configuration's JSON file (`sizes`, keyed by the program's dotted config
+It imports nothing of the program. It is a configuration's `reference`
+(perf/harness.py lists what such a module states; the last of it ends this
+file). It reads its sizes from the configuration's JSON file (`sizes`, keyed by the program's dotted config
 names so that the harness can hold the program to the same numbers) and
 makes its own weights from a seed (`init_params`).
 
@@ -673,3 +674,17 @@ def train_step(params: Params, adam, batch, rng, step, sz: Sizes, precision: str
 
 def leaf_norms(tree: Params) -> Dict[str, jnp.ndarray]:
     return {k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32)))) for k, v in tree.items()}
+
+
+# ------------------------------------------- what else the harness reads
+
+BATCH_KEYS = ("image", "boxes", "labels", "mask")  # of a batch, what `train_step` takes
+LOSS_PARTS = ("rpn_cls_loss", "rpn_reg_loss", "head_cls_loss", "head_reg_loss")  # compared at step 1
+# The model's own numbers: the worst leaf gap of the first gradient's norms
+# under these prefixes. The RPN heads' gradient comes from the two RPN
+# losses alone, upstream of every proposal, so no flipped selection reaches
+# it; the objectness kernel's is the steadiest (256 sampled anchors an image).
+LEAF_NUMBERS = {"rpn_grad_norm_gap": ("rpn/cls/", "rpn/reg/"), "rpn_cls_grad_gap": ("rpn/cls/kernel",)}
+SCOPE_PREFIX = "frcnn."  # of the step program's stage scopes (`telemetry/stages.py`)
+# the readers' `ctx["flops"]` is this module: the counter is perf/flops.py
+from perf.flops import conv_roofline_seconds, train_flops_per_image  # noqa: E402,F401

@@ -1,5 +1,14 @@
-"""Plain reference of the data feed: what a row of a batch has to hold,
-worked out from the devkit's files alone.
+"""The data's side of a Pascal-VOC configuration (its `feed_reference`):
+the run's data set made from the seed, the program overrides that point the
+trainer at it, one host batch's shapes, and the plain reference of the data
+feed: what a row of a batch has to hold, worked out from the devkit's files
+alone.
+
+Mix parameters of the devkit (`make`): `n_images`, `image_wh`,
+`jpeg_quality`, `noise_amplitude`, `boxes_per_image`, `box_frac`. Every seed
+gets the same sizes and counts, other pixels, boxes and classes.
+`noise_amplitude` sets the files' size (120 grey levels: about 100 KB at
+500x375 and quality 85, a Pascal VOC photograph's).
 
 For every row of the batches the timed path consumed: find the image it was
 made from by its labels and boxes (the reference's own parse of the
@@ -21,8 +30,11 @@ nothing of the program.
 
 from __future__ import annotations
 
+import json
 import os
+import shutil
 import xml.etree.ElementTree as ET
+from concurrent import futures
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -35,6 +47,90 @@ VOC_NAMES = (
     "sheep", "sofa", "train", "tvmonitor",
 )
 NO_MATCH = 1e9
+DEVKIT_KEYS = ("n_images", "image_wh", "jpeg_quality", "noise_amplitude", "boxes_per_image", "box_frac")
+NOISE_MID = 116  # the noise field's mean grey level
+
+
+def _one_image(root: str, img_id: str, seed: int, index: int, mix: Dict[str, Any], noise: np.ndarray) -> int:
+    from PIL import Image
+
+    rng = np.random.RandomState((seed * 1_000_003 + index) % (2**32))
+    w, h = mix["image_wh"]
+    # colour blocks over noise whose amplitude gives a photograph's file
+    # size: decode work follows the coded bytes. The noise is a window of
+    # one field made once a devkit (drawing it anew for every image holds
+    # the interpreter lock and tripled the set-up).
+    dy, dx = rng.randint(0, noise.shape[0] - h), rng.randint(0, noise.shape[1] - w)
+    arr = noise[dy : dy + h, dx : dx + w].copy()
+    lo, hi = mix["boxes_per_image"]
+    objs = []
+    for _ in range(rng.randint(lo, hi + 1)):
+        f_lo, f_hi = mix["box_frac"]
+        bh = int(h * rng.uniform(f_lo, f_hi))
+        bw = int(w * rng.uniform(f_lo, f_hi))
+        y1, x1 = rng.randint(0, h - bh), rng.randint(0, w - bw)
+        cls = rng.randint(0, len(VOC_NAMES))
+        colour = np.asarray([(cls * 37) % 200, (cls * 91 + 60) % 200, (cls * 53 + 120) % 200], np.int16)
+        patch = arr[y1 : y1 + bh, x1 : x1 + bw].astype(np.int16) - NOISE_MID + colour + 20
+        arr[y1 : y1 + bh, x1 : x1 + bw] = np.clip(patch, 0, 255).astype(np.uint8)
+        objs.append(
+            f"<object><name>{VOC_NAMES[cls]}</name><difficult>0</difficult>"
+            f"<bndbox><xmin>{x1 + 1}</xmin><ymin>{y1 + 1}</ymin>"
+            f"<xmax>{x1 + bw}</xmax><ymax>{y1 + bh}</ymax></bndbox></object>"
+        )
+    path = os.path.join(root, "JPEGImages", img_id + ".jpg")
+    Image.fromarray(arr).save(path, quality=mix["jpeg_quality"])
+    with open(os.path.join(root, "Annotations", img_id + ".xml"), "w") as f:
+        f.write(
+            f"<annotation><size><width>{w}</width><height>{h}</height></size>"
+            f"{''.join(objs)}</annotation>"
+        )
+    return os.path.getsize(path)
+
+
+def make(root: str, seed: int, mix: Dict[str, Any]) -> Dict[str, Any]:
+    """A VOC devkit under `root` made from `seed`, anew in every run: the
+    same set-up work whether or not the seed was seen before. Returns its
+    record."""
+    record = {k: mix[k] for k in DEVKIT_KEYS}
+    record["seed"] = seed
+    shutil.rmtree(root, ignore_errors=True)
+    for d in ("ImageSets/Main", "JPEGImages", "Annotations"):
+        os.makedirs(os.path.join(root, d))
+    ids = [f"{i:06d}" for i in range(mix["n_images"])]
+    with open(os.path.join(root, "ImageSets", "Main", "train.txt"), "w") as f:
+        f.write("\n".join(ids) + "\n")
+    w, h = mix["image_wh"]
+    half = int(mix["noise_amplitude"]) // 2
+    noise = np.random.RandomState(seed % (2**32)).randint(
+        NOISE_MID - half, NOISE_MID + half, (h + 64, w + 64, 3)
+    ).astype(np.uint8)
+    # PIL's encoder releases the interpreter lock: threads run side by side
+    with futures.ThreadPoolExecutor(8) as pool:
+        sizes = list(pool.map(lambda a: _one_image(root, a[1], seed, a[0], mix, noise), enumerate(ids)))
+    record.update(mean_file_bytes=float(np.mean(sizes)), total_bytes=int(np.sum(sizes)))
+    with open(os.path.join(root, "devkit.json"), "w") as f:
+        json.dump(record, f)
+    return record
+
+
+def overrides(root: str) -> Dict[str, Any]:
+    """The dotted program config keys that point the trainer at `root`."""
+    return {"data.dataset": "voc", "data.root_dir": root}
+
+
+def batch_spec(sizes: Dict[str, Any], batch: int) -> Dict[str, Tuple[Tuple[int, ...], Any]]:
+    """{key: (shape, dtype)} of one host batch as the loader collates it."""
+    (h, w), m = sizes["data.image_size"], int(sizes["data.max_boxes"])
+    return {
+        "image": ((batch, h, w, 3), np.float32), "boxes": ((batch, m, 4), np.float32),
+        "labels": ((batch, m), np.int32), "mask": ((batch, m), np.bool_), "difficult": ((batch, m), np.bool_),
+    }
+
+
+def notes(record: Dict[str, Any]) -> Dict[str, Any]:
+    """What of `make`'s record a run's result line notes."""
+    return {"devkit_mean_file_bytes": record["mean_file_bytes"]}
 
 
 def annotations(root: str, image_hw: Tuple[int, int], max_boxes: int) -> Dict[Tuple[int, ...], List[Tuple[str, np.ndarray]]]:

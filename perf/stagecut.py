@@ -4,14 +4,16 @@ of the step program, and the chip's idle time by the program's own spans.
 The harness hands a reader `ctx` and no path, and `ctx["trace"]` holds sums,
 not operations. So this module finds the run's directory from the tracer's
 first event (`telemetry/open`, whose `args` hold the telemetry directory; the
-run's `profile/` and `step_hlo.txt` lie beside it), loads the `.xplane.pb`
-once per run (kept in `ctx`) and the `op_name` of every instruction of the
-compiled step, and from there is arithmetic on (name, start_ns, duration_ns,
+run's `profile/`, `step_hlo.txt` and `stage_scope.txt` lie beside it: the
+last holds the prefix of the program's stage scopes, which the harness
+writes there from the configuration's reference module), loads the
+`.xplane.pb` once per run (kept in `ctx`) and the `op_name` of every
+instruction of the compiled step, and from there is arithmetic on (name, start_ns, duration_ns,
 stats) tuples as `xtrace` is, so a hand-built trace tests it:
 
 - each device nanosecond goes to the innermost operation running then (a
   `while` and the fusions inside it never both count), and that operation to
-  the LAST `frcnn.*` scope in its `op_name`: backward where the path holds
+  the LAST stage scope (`<prefix>[a-z_]+`) in its `op_name`: backward where the path holds
   `transpose(`, `unscoped` where it holds no scope. Stage sums + unscoped =
   the chip's busy time;
 - each idle gap of the first chip goes to the innermost program span that
@@ -42,8 +44,10 @@ Event = xtrace.Event
 Interval = xtrace.Interval
 
 OPEN_EVENT = "telemetry/open"
-SCOPE_RE = re.compile(r"frcnn\.[a-z_]+")
+SCOPE_FILE = "stage_scope.txt"
 UNSCOPED = "unscoped"
+# the result type may be a tuple with spaces; layouts hold none
+INSTRUCTION_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(")
 TABLE_SHARE = 0.9  # of the busy time, listed operation by operation by `table`
 CPU_EXECUTOR = "tf_XLAPjRtCpuClient"  # a rehearsal's stand-in for a device, as in xtrace
 
@@ -67,10 +71,10 @@ def span_names(spans: Iterable[Dict[str, Any]]) -> set:
 
 def load_origin(hlo_text: str) -> Dict[str, str]:
     """instruction name -> `op_name` metadata, from the compiled module's
-    text (the regular expressions of `harness.step_executable`)."""
+    text."""
     origin: Dict[str, str] = {}
     for line in hlo_text.splitlines():
-        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\(", line)
+        m = INSTRUCTION_RE.match(line)
         if not m:
             continue
         where = re.search(r'op_name="([^"]*)"', line)
@@ -149,14 +153,18 @@ def self_times(events: Sequence[Event]) -> List[float]:
     return own
 
 
-def stage_of(path: str) -> Tuple[str, bool]:
-    """(the last `frcnn.*` scope of an `op_name` path, or "unscoped";
-    whether the path lies in the backward pass)."""
-    scopes = SCOPE_RE.findall(path)
-    return (scopes[-1] if scopes else UNSCOPED), "transpose(" in path
+def scope_re(prefix: str) -> "re.Pattern[str]":
+    return re.compile(re.escape(prefix) + r"[a-z_]+")
 
 
-def cut_device(planes: Dict[str, List[Event]], origin: Dict[str, str]) -> Dict[str, Any]:
+def stage_of(path: str, scopes: "re.Pattern[str]") -> Tuple[str, bool]:
+    """(the last stage scope of an `op_name` path, or "unscoped"; whether
+    the path lies in the backward pass)."""
+    found = scopes.findall(path)
+    return (found[-1] if found else UNSCOPED), "transpose(" in path
+
+
+def cut_device(planes: Dict[str, List[Event]], origin: Dict[str, str], scopes: "re.Pattern[str]") -> Dict[str, Any]:
     """Self time of every operation, summed over the chips, by stage and
     direction, and by operation."""
     stage_ns: Dict[str, Dict[str, float]] = {}
@@ -168,7 +176,7 @@ def cut_device(planes: Dict[str, List[Event]], origin: Dict[str, str]) -> Dict[s
                 continue
             busy += own
             path = origin.get(xtrace.op_name(name), "")
-            stage, backward = stage_of(path)
+            stage, backward = stage_of(path, scopes)
             side = "backward" if backward else "forward"
             by_side = stage_ns.setdefault(stage, {"forward": 0.0, "backward": 0.0})
             by_side[side] += own
@@ -219,18 +227,19 @@ def cut_idle(planes: Dict[str, List[Event]], host: Sequence[Event]) -> Dict[str,
 def cut_run(where: str, names: set) -> Optional[Dict[str, Any]]:
     """Everything the readers start from, for the run that wrote `where`;
     None where the run left no trace or no compiled module there."""
-    hlo = os.path.join(where, "step_hlo.txt")
     try:
         xplane = xtrace.find_xplane(os.path.join(where, "profile"))
-        with open(hlo) as f:
+        with open(os.path.join(where, "step_hlo.txt")) as f:
             origin = load_origin(f.read())
+        with open(os.path.join(where, SCOPE_FILE)) as f:
+            scopes = scope_re(f.read().strip())
     except FileNotFoundError:
         return None
     trace = load_trace(xplane, names)
     planes = {k: v for k, v in trace["devices"].items() if v}
     if not planes:
         return None
-    out = cut_device(planes, origin)
+    out = cut_device(planes, origin, scopes)
     out.update(cut_idle(planes, trace["host"]))
     out["chips"] = len(planes)
     out["dispatches"] = sum(1 for e in trace["host"] if e[0] == "step/dispatch")
@@ -270,7 +279,7 @@ def backward_pct(ctx: Dict[str, Any]) -> Optional[float]:
 
 
 def unscoped_pct(ctx: Dict[str, Any]) -> Optional[float]:
-    """Share of the chips' busy time in no `frcnn.*` scope."""
+    """Share of the chips' busy time in no stage scope."""
     cut = of(ctx)
     if cut is None or cut["busy_ns"] <= 0:
         return None

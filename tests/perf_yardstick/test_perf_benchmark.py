@@ -19,7 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perf import compare, flops, harness, manifest, readings, traffic, xtrace  # noqa: E402
+from perf import compare, flops, harness, manifest, readings, xtrace  # noqa: E402
 from perf.references import vocfeed  # noqa: E402
 
 REHEARSAL = os.path.join(ROOT, "perf", "rehearsal", "BENCHMARK.json")
@@ -281,7 +281,7 @@ def test_the_feeds_reference_in_the_loaders_place(tmp_path, how, sound):
     cell = manifest.Cell(ROOT, os.path.join(ROOT, "BENCHMARK.json"), "r18c4.resident")
     sizes, limits = cell.config["sizes"], cell.config["limits"]
     kit = str(tmp_path / "kit")
-    traffic.build_devkit(kit, SEED, dict(manifest.load(os.path.join(ROOT, "perf", "mixes", "fed.json")), n_images=4))
+    vocfeed.make(kit, SEED, dict(manifest.load(os.path.join(ROOT, "perf", "mixes", "fed.json")), n_images=4))
     known = vocfeed.annotations(kit, (600, 600), 32)
     rows = [(labels, boxes) for labels, found in known.items() for _, boxes in found]
     batch = {

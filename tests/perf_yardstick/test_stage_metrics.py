@@ -27,6 +27,7 @@ NEW_METRICS = (
     "idle_unattributed_pct.resident",
 )
 MS = 1e6  # ns
+SCOPES = stagecut.scope_re("frcnn.")  # the detector reference's SCOPE_PREFIX
 
 
 def _line(name, opcode="fusion"):
@@ -86,12 +87,12 @@ def test_a_while_with_nested_fusions_counts_once():
     ],
 )
 def test_an_operation_goes_to_the_last_scope_of_its_op_name(path, stage, backward):
-    assert stagecut.stage_of(path) == (stage, backward)
+    assert stagecut.stage_of(path, SCOPES) == (stage, backward)
 
 
 def test_stage_sums_and_unscoped_close_on_busy_time():
     planes = {"/device:TPU:0": _chip(), "/device:TPU:1": _chip()[:2]}
-    cut = stagecut.cut_device(planes, ORIGIN)
+    cut = stagecut.cut_device(planes, ORIGIN, SCOPES)
     assert cut["busy_ns"] == (16 + 6) * MS
     assert sum(sum(v.values()) for v in cut["stage_ns"].values()) == cut["busy_ns"]
     assert cut["stage_ns"]["frcnn.proposals"] == {"forward": 6 * MS, "backward": 0.0}
@@ -155,12 +156,12 @@ def test_the_run_directory_comes_from_the_tracers_first_event():
 
 def _read(metric, ctx):
     cell = manifest.Cell(ROOT, STAGES_MANIFEST, "tiny.stages")
-    return harness._load_reader(cell.reader_path(metric))(ctx)
+    return harness.load_file(cell.reader_path(metric)).read(ctx)
 
 
 def _hand_ctx():
     planes = {"/device:TPU:0": _chip()}
-    cut = stagecut.cut_device(planes, ORIGIN)
+    cut = stagecut.cut_device(planes, ORIGIN, SCOPES)
     cut.update(stagecut.cut_idle(planes, [("step/sync", 13 * MS, 4 * MS, {})]))
     cut["chips"] = 1
     return {"window": {"traced_steps": 2}, "spans": [], "stagecut": cut}
