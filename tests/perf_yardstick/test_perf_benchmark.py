@@ -3,8 +3,11 @@
 What is checked here is arithmetic and control flow: the shape-derived FLOP
 count, the trace reducer, the manifest validator, the result line, the whole
 window loop at a tiny size (the rehearsal cells of perf/rehearsal/), and that
-the comparison behind `correct` fails what it must fail. No time, rate or
-share from these runs means anything.
+the comparison behind `correct` fails what it must fail. The toy pair
+(tests/perf_yardstick/toy/: a bigram language model, its data, the smallest
+trainer) goes through the same harness as the detector's rehearsal cells: the
+proof that what is the model's and the data's sits behind the two names of a
+configuration's file. No time, rate or share from these runs means anything.
 """
 
 import copy
@@ -12,6 +15,7 @@ import json
 import os
 import subprocess
 import sys
+import tokenize
 
 import pytest
 
@@ -23,7 +27,13 @@ from perf import compare, flops, harness, manifest, readings, xtrace  # noqa: E4
 from perf.references import vocfeed  # noqa: E402
 
 REHEARSAL = os.path.join(ROOT, "perf", "rehearsal", "BENCHMARK.json")
+TOY = os.path.join(ROOT, "tests", "perf_yardstick", "toy")
+TOY_MANIFEST = os.path.join(TOY, "BENCHMARK.json")
 SEED = 2_147_484_001  # more than 32 signed bits hold, as the driver's are
+
+
+def _toy_program():
+    return harness.load_file(os.path.join(TOY, "program.py"))
 
 
 def _sizes(name):
@@ -113,8 +123,12 @@ def test_a_trace_without_device_operations_is_refused():
 # ---------------------------------------------------------------- manifest
 
 
-@pytest.mark.parametrize("path", [os.path.join(ROOT, "BENCHMARK.json"), REHEARSAL])
+DETECTOR_LIMITS = {"rpn_cls_grad_gap", "change_norm_gap", "feed_box_gap", "feed_pixel_gap"}
+
+
+@pytest.mark.parametrize("path", [os.path.join(ROOT, "BENCHMARK.json"), REHEARSAL, TOY_MANIFEST])
 def test_manifests_are_sound_and_their_files_exist(path):
+    limits = {"embed_grad_gap", "change_norm_gap", "feed_row_gap"} if path == TOY_MANIFEST else DETECTOR_LIMITS
     m = manifest.load(path)
     assert manifest.validate(m) == []
     for w in m["workloads"]:
@@ -123,7 +137,9 @@ def test_manifests_are_sound_and_their_files_exist(path):
         for metric in cell.per_layer:
             assert os.path.exists(cell.reader_path(metric["name"])), metric["name"]
         assert {"setup_s"} < {e["name"] for e in cell.end_to_end}
-        assert set(cell.config["limits"]) >= {"rpn_cls_grad_gap", "change_norm_gap", "feed_box_gap", "feed_pixel_gap"}
+        assert set(cell.config["limits"]) >= limits
+        for module in (harness.load_reference(cell), harness.load_feed_reference(cell)):
+            assert (os.path.dirname(os.path.dirname(module.__file__)) == TOY) == (path == TOY_MANIFEST)
 
 
 def _breach(edit):
@@ -169,28 +185,46 @@ def test_run_exits_nonzero_and_prints_no_result_without_a_tpu(tmp_path):
 
 
 def _rehearse(tmp, workload, trace, break_step=None, seconds=1.0):
+    """A detector rehearsal cell, or the toy's: its own manifest and program."""
+    where, program = REHEARSAL, None
+    if workload.startswith("bigram."):
+        where, program = TOY_MANIFEST, (_toy_program().get_config, _toy_program().Trainer)
     result, code = harness.run_cell(
-        ROOT, REHEARSAL, workload, SEED, seconds, trace, scratch=str(tmp),
-        require_tpu=False, break_step=break_step,
+        ROOT, where, workload, SEED, seconds, trace, scratch=str(tmp),
+        require_tpu=False, break_step=break_step, program=program,
     )
     assert code == 0
     return result
 
 
-@pytest.fixture(scope="module")
-def fed_traced(tmp_path_factory):
-    return _rehearse(tmp_path_factory.mktemp("fed"), "tiny.fed", True, seconds=9.0)
+@pytest.fixture(scope="module", params=["tiny.fed", "bigram.fed"])
+def fed_traced(request, tmp_path_factory):
+    seconds = 9.0 if request.param == "tiny.fed" else 1.0
+    return request.param, _rehearse(tmp_path_factory.mktemp("fed"), request.param, True, seconds=seconds)
 
 
-def test_window_loop_and_result_line_keys(fed_traced):
-    r = fed_traced
+def test_window_loop_and_result_line_keys(fed_traced, tmp_path):
+    workload, r = fed_traced
     assert list(r)[-1] == "compared"
     assert {"correct", "attempted", "failed", "metrics", "device", "breakdown"} <= set(r)
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > harness.WARM_STEPS
     assert {"platform", "kind", "count", "memory_peak_bytes", "busy_s", "window_s"} <= set(r["device"])
     assert r["device"]["busy_s"] > 0 and r["device"]["count"] == 1
     # per-layer metrics of a traced run; a reader with nothing to read is left out
-    assert {"feed_wait_pct", "dispatch_ms.fed", "device_idle_pct.fed", "train_mfu_pct.fed"} == set(r["metrics"])
+    if workload == "bigram.fed":
+        # the harness's own readers, and one the toy brings over its own scopes
+        assert {"step_device_ms", "train_mfu_pct.fed", "dispatch_ms.fed", "stage_tables_ms", "stage_unscoped_pct"} == set(r["metrics"])
+        assert r["metrics"]["train_mfu_pct.fed"]["value"] > 0 and r["metrics"]["stage_tables_ms"]["value"] > 0
+        assert r["compared"]["feed_row_gap"] == {"value": 0.0, "limit": 0}
+        assert r["compared"]["embed_grad_gap"]["value"] <= r["compared"]["embed_grad_gap"]["limit"] == 1e-3
+        assert r["notes"]["data_rows"] == 256
+        # and untraced: its rate under the name and unit its manifest gives, the parts its reference names
+        u = _rehearse(tmp_path, workload, False)
+        assert u["correct"] is True and set(u["metrics"]) == {"train_rows_per_s", "setup_s"}
+        assert u["metrics"]["train_rows_per_s"]["unit"] == "rows/s" and u["metrics"]["train_rows_per_s"]["value"] > 0
+        assert list(u["compared"])[:4] == ["loss1_gap", "loss2_gap", "loss3_gap", "nll1_gap"]
+    else:
+        assert {"feed_wait_pct", "dispatch_ms.fed", "device_idle_pct.fed", "train_mfu_pct.fed"} == set(r["metrics"])
     for entry in r["metrics"].values():
         assert set(entry) == {"value", "unit"}
     assert len(r["breakdown"]["device_ops"]) <= 10 and len(r["breakdown"]["idle_gaps"]) <= 10
@@ -198,8 +232,9 @@ def test_window_loop_and_result_line_keys(fed_traced):
         assert set(entry) == {"value", "limit"}, name
     assert r["compared"]["recompiles"]["value"] == 0
     # the loader's rows against the feed's own reference, held to limits
-    assert r["compared"]["feed_box_gap"] == {"value": 0.0, "limit": 0}
-    assert 0 < r["compared"]["feed_pixel_gap"]["value"] < r["compared"]["feed_pixel_gap"]["limit"] == 2e-5
+    if workload == "tiny.fed":
+        assert r["compared"]["feed_box_gap"] == {"value": 0.0, "limit": 0}
+        assert 0 < r["compared"]["feed_pixel_gap"]["value"] < r["compared"]["feed_pixel_gap"]["limit"] == 2e-5
     json.dumps(r)
 
 
@@ -331,10 +366,13 @@ def _half_batch(trainer, step_call):
 
 @pytest.mark.parametrize("fault", [_unchanged_state, _half_batch])
 def test_a_broken_timed_path_comes_out_not_correct(tmp_path, fault):
-    r = _rehearse(tmp_path, "tiny.resident", False, break_step=fault)
-    assert r["correct"] is False
-    over = [k for k, v in r["compared"].items() if v["limit"] is not None and not v["value"] <= v["limit"]]
-    assert over, r["compared"]
+    """On the detector's tiny cell and on the toy's: both faults are written
+    over whatever keys a batch has and whatever state a trainer keeps."""
+    for workload in ("tiny.resident", "bigram.fed"):
+        r = _rehearse(tmp_path, workload, False, break_step=fault)
+        assert r["correct"] is False, workload
+        over = [k for k, v in r["compared"].items() if v["limit"] is not None and not v["value"] <= v["limit"]]
+        assert over, (workload, r["compared"])
 
 
 def test_the_control_in_float8_comes_out_not_correct(tmp_path):
@@ -385,3 +423,67 @@ def test_readings_judge_the_program_the_controls_and_the_faults(tmp_path):
     for kind in readings.KINDS:
         assert all(set(v) == {"value", "limit"} for v in first[kind]["numbers"].values())
     assert any("has to be 0" in " ".join(map(str, line)) for line in said)
+
+
+# ------------------------------------------------------------- the seam
+
+
+HARNESS_FILES = ("harness", "compare", "traffic", "stagecut", "reckon_memory", "readings")
+DETECTOR_WORDS = ("voc", "devkit", "rpn", "boxes", '"image"', "'image'", "frcnn.")
+
+
+def test_the_harness_files_hold_none_of_the_detectors_words():
+    """Outside comments (which may point at the modules that own them), what
+    is the detector's is behind the configuration's two modules; and nothing
+    under perf/ names the toy pair."""
+    for name in HARNESS_FILES:
+        with open(os.path.join(ROOT, "perf", name + ".py")) as f:
+            code = " ".join(t.string for t in tokenize.generate_tokens(f.readline) if t.type != tokenize.COMMENT).lower()
+        assert [w for w in DETECTOR_WORDS if w in code] == [], name
+    for where, _, files in os.walk(os.path.join(ROOT, "perf")):
+        for name in files:
+            if name.endswith((".py", ".json")):
+                with open(os.path.join(where, name)) as f:
+                    text = f.read().lower()
+                assert "toy" not in text and "bigram" not in text, os.path.join(where, name)
+
+
+def test_modules_beside_a_cells_data_files_are_found_first_and_reckon_memory_lowers_from_them(tmp_path):
+    """References and readers alike: `<data dir>/<kind>/<name>.py`, else the
+    harness's own; the data directory is where `configs/` lies. And
+    `reckon_memory.reckon` goes through the same lookup: it lowers the toy's
+    step and its reference step over its data module's `batch_spec` and
+    compiles them (for this host, where a test has no chip to describe);
+    no device runs anything."""
+    import jax
+
+    from perf import reckon_memory
+
+    (tmp_path / "references").mkdir()
+    (tmp_path / "references" / "frcnn.py").write_text("WHOSE = 'the cell'\n")
+    data_dir = manifest.data_dir_of(str(tmp_path / "configs" / "any.json"))
+    assert data_dir == str(tmp_path)
+    assert manifest.beside(data_dir, "references", "frcnn") == str(tmp_path / "references" / "frcnn.py")
+    assert harness.load_module(data_dir, "references", "frcnn").WHOSE == "the cell"
+    shared = os.path.join(ROOT, "perf", "references", "vocfeed.py")
+    assert manifest.beside(data_dir, "references", "vocfeed") == shared
+    assert manifest.beside(data_dir, "metrics", "step_device_ms") == os.path.join(ROOT, "perf", "metrics", "step_device_ms.py")
+    # a file is loaded once a process, whoever asks
+    assert harness.load_file(shared) is harness.load_module(os.path.join(ROOT, "perf"), "references", "vocfeed")
+    toy = manifest.Cell(ROOT, TOY_MANIFEST, "bigram.fed")
+    assert toy.reader_path("stage_tables_ms").startswith(TOY) and not toy.reader_path("step_device_ms").startswith(TOY)
+    assert harness.load_reference(toy).SCOPE_PREFIX == "bigram."
+    assert harness.load_reference(manifest.Cell(ROOT, REHEARSAL, "tiny.fed")).SCOPE_PREFIX == "frcnn."
+
+    program = _toy_program()
+    out = reckon_memory.reckon(
+        os.path.join(TOY, "configs", "bigram.json"), True, program=(program.get_config, program.step_and_state),
+        chip=jax.sharding.SingleDeviceSharding(jax.devices()[0]),
+    )
+    assert out["per_chip_batch"] == 16 and "described" not in out["device"]
+    for step in ("train_step", "reference_step"):
+        # the two tables, Adam's moments of them, and a batch of 16 x 64 tokens and targets
+        assert out[step]["argument_bytes"] >= 3 * 4 * (2 * 512 * 128 + 512) + 2 * 4 * 16 * 64
+        assert out[step]["total_bytes"] > 0
+    spec = harness.load_module(TOY, "references", "tokens").batch_spec({"data.seq_len": 64}, 16)
+    assert {k: v[0] for k, v in spec.items()} == {"tokens": (16, 64), "targets": (16, 64)}
