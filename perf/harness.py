@@ -36,6 +36,22 @@ iteration), `_stage_batch(batch, wait=)`, `train_one_batch(batch=|staged=)`
 giving metrics with `loss`, the parts (and `skipped` where the program has
 it), `strict_session()`, `strict` (None or `report()`), `tracer` (`span`,
 `now_us`), `jitted_step`, `flush_telemetry()`.
+
+What the harness itself may hold on the chip. While a step that the harness
+drives is running, no device buffer of the harness's own making is alive
+except the staged batches; between steps, at most one parameter-sized buffer
+(4 B a parameter); the reference runs at its own state's size, 16 B a
+parameter, plus its step. So `memory_peak_bytes` measures the program and
+not the yardstick, the set-up's peak is the window's, and a configuration
+may size its parameters to the chip at 16 B each. Function by function:
+`inject_weights`: the seed's parameters beside the trainer's own first state
+(16 B), that state released before Adam's is made anew (12 B);
+`first_steps`: the first parameters wait on the host, and after step 1 and
+step 3 one reduction to per-leaf scalars is dispatched on the state where
+it lies (after step 3 with the first parameters put back beside it, 4 B,
+gone before step 4 is dispatched); `reference_numbers`: parameters and both
+moments donated to each step, one gradient out (16 B), the first parameters
+on the host until the moments are dropped.
 """
 
 from __future__ import annotations
@@ -137,20 +153,27 @@ def _flat(tree) -> Dict[str, Any]:
 
 def inject_weights(trainer, ref, sz, seed: int) -> None:
     """Weights, Adam state and sampling key from the seed, made by the
-    benchmark in one jitted call and put where the trainer keeps its own."""
+    benchmark in one jitted call and put where the trainer keeps its own.
+    The trainer's own first parameters and optimizer state are released
+    once the seed's parameters are made and found to match, before Adam's
+    state is made anew: the seed's state replaces them and never stands
+    beside them (16 B a parameter at the most, 12 B after)."""
     import jax
     from flax import traverse_util
 
     key = jax.random.PRNGKey(seed % (2**31 - 1))
     flat = jax.jit(lambda k: ref.init_params(sz, jax.random.fold_in(k, 1)))(key)
-    have = _flat(trainer.state.params)
+    own = trainer.state
+    have = _flat(own.params)
     if {k: v.shape for k, v in flat.items()} != {k: v.shape for k, v in have.items()}:
         odd = sorted(set(flat) ^ set(have))[:6]
         raise RunFailed(f"the reference's parameters do not match the program's: {odd}")
+    for leaf in jax.tree_util.tree_leaves((own.params, own.opt_state)):
+        leaf.delete()
     params = traverse_util.unflatten_dict({tuple(k.split("/")): v for k, v in flat.items()})
     sh = trainer._state_shardings
     params = jax.device_put(params, sh.params)
-    trainer.state = trainer.state.replace(
+    trainer.state = own.replace(
         params=params,
         opt_state=jax.device_put(trainer.tx.init(params), sh.opt_state),
         rng=jax.device_put(jax.random.fold_in(key, 2), sh.rng),
@@ -175,25 +198,30 @@ def first_steps(trainer, feed, step_call: Callable, parts: Sequence[str]) -> Dic
     and feed, and take what the comparison needs from that same object:
     each step's loss (the first's `parts` too), Adam's first moment after
     step 1 (the first gradient is mu / (1 - b1)) and the parameters' change
-    over the steps."""
+    over the steps. Nothing parameter-sized of the harness's is on the chip
+    while a step runs: the first parameters wait on the host, and each
+    reduction to per-leaf norms is dispatched on the state where it lies,
+    straight after the step's call returns and before the next call donates
+    it (the order of dispatch is the order of execution)."""
     import jax
-    import jax.numpy as jnp
 
-    copy = jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t))
     norms, diff_norms = _norms_program()
-    p0 = copy(trainer.state.params)
-    losses, mu1, p3 = [], None, None
+    first_params = jax.device_get(trainer.state.params)
+    losses, grad, change = [], None, None
     with trainer.strict_session():
         for i in range(WARM_STEPS):
             metrics = step_call(next(feed))
             if i < CHECK_STEPS:
                 losses.append(metrics)
             if i == 0:
-                mu1 = copy(_adam_mu(trainer.state.opt_state))
+                grad = norms(_adam_mu(trainer.state.opt_state))
             if i == CHECK_STEPS - 1:
-                p3 = copy(trainer.state.params)
-    grad = jax.device_get(norms(mu1))
-    change = jax.device_get(diff_norms(p3, p0))
+                # fetched at once: the first parameters' buffer is gone
+                # before the next step is dispatched
+                back = jax.device_put(first_params, trainer._state_shardings.params)
+                change = jax.device_get(diff_norms(trainer.state.params, back))
+                del first_params, back
+    grad = jax.device_get(grad)
     rows = jax.device_get(losses)
     return {
         "losses": [float(r["loss"]) for r in rows],
@@ -214,20 +242,32 @@ def reference_numbers(
     """The reference over the same batches from the same seed: its own
     weights, its own steps. `rows` keeps only the first rows of each batch
     (the half-batch fault of the control tests). A caller that runs many
-    seeds passes one `jitted` dict to keep the traced programs."""
+    seeds passes one `jitted` dict to keep the traced programs.
+
+    Each step is given its parameters and moments to write over, so that
+    parameters, both moments and one gradient are alive at once: 16 B a
+    parameter beside the step's own temporaries. A reference whose step
+    would not fit computes it in blocks (a sequence or a few rows at a time,
+    summed) inside its own `train_step`."""
     import jax
     import jax.numpy as jnp
 
     key = jax.random.PRNGKey(seed % (2**31 - 1))
     params = jax.jit(lambda k: ref.init_params(sz, jax.random.fold_in(k, 1)))(key)
     rng = jax.random.fold_in(key, 2)
-    adam = ref.init_adam(params)
     jitted = {} if jitted is None else jitted
     if precision not in jitted:
-        jitted[precision] = jax.jit(lambda p, a, b, r, s: ref.train_step(p, a, b, r, s, sz, precision))
+        jitted[precision] = jax.jit(
+            lambda p, a, b, r, s: ref.train_step(p, a, b, r, s, sz, precision), donate_argnums=(0, 1)
+        )
         jitted.setdefault("norms", jax.jit(ref.leaf_norms))
+        # a reference may hand out one buffer of zeros as both moments, and a
+        # buffer is donated once: each moment gets its own
+        jitted.setdefault("unshared", jax.jit(lambda t: jax.tree_util.tree_map(jnp.copy, t)))
     step, norms = jitted[precision], jitted["norms"]
-    p0, losses, grad, first = params, [], None, None
+    adam = jitted["unshared"](ref.init_adam(params))
+    first_params = jax.device_get(params)
+    losses, grad, first = [], None, None
     for i, host in enumerate(batches):
         batch = {k: jnp.asarray(host[k][:rows]) for k in ref.BATCH_KEYS}
         params, adam, parts, seen = step(params, adam, batch, rng, jnp.asarray(i, jnp.int32))
@@ -235,7 +275,9 @@ def reference_numbers(
         if i == 0:
             grad = jax.device_get(norms(seen))
             first = {k: float(parts[k]) for k in ref.LOSS_PARTS}
-    change = jax.device_get(norms({k: params[k] - p0[k] for k in params}))
+        del seen
+    del adam
+    change = jax.device_get(norms({k: params[k] - jnp.asarray(first_params[k]) for k in params}))
     return {
         "losses": losses,
         "parts": first,

@@ -10,7 +10,9 @@ which owns all that is the model's, and `feed_reference`, all that is the
 data's (`references/<name>.py`; perf/harness.py lists what each states and
 what the harness asks of the program). Adding a configuration, a mix, a
 cell, a metric, or another kind of model with its data therefore adds files
-and manifest entries and edits nothing.
+and manifest entries and edits nothing. A configuration may size its
+parameters to the chip at 16 B each: the comparison adds none (the rule is
+in perf/harness.py, the reckoning in perf/reckon_memory.py).
 """
 
 from __future__ import annotations

@@ -3,10 +3,13 @@
     JAX_PLATFORMS=cpu python3 perf/reckon_memory.py <config> [<config> ...] [--write]
 
 Lowers the program's own train step (and the benchmark's float32 reference
-step) over the batch the configuration's data module describes
-(`batch_spec`), at the per-chip batch, for a DESCRIBED TPU v5e
-(`jax.experimental.topologies`, no chip attached), compiles with the TPU
-compiler installed here and prints `memory_analysis()`. `--write` puts
+step, donated as the harness runs it) over the batch the configuration's
+data module describes (`batch_spec`), at the per-chip batch, for a DESCRIBED
+TPU v5e (`jax.experimental.topologies`, no chip attached), compiles with the
+TPU compiler installed here and prints `memory_analysis()`, and for the
+reference the state it holds (16 B a parameter) beside its step's
+temporaries: the comparison adds nothing parameter-sized to either, so
+the larger of the two totals is what a cell needs of the chip. `--write` puts
 the bytes into the configuration file's `memory_reckoning`. Nothing runs:
 these are the compiler's sizes, not a chip's readings. About a minute a
 program; not a test.
@@ -102,9 +105,19 @@ def reckon(config_path: str, with_reference: bool, program=None, chip=None) -> d
         key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
         i = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
         t = time.time()
-        rstep = jax.jit(lambda p, a, bb, r, s: ref.train_step(p, a, bb, r, s, sz))
-        out["reference_step"] = sizes(rstep.lower(params, adam, rbatch, key, i).compile(), time.time() - t)
-        print(conf["name"], "reference_step", out["reference_step"], flush=True)
+        # as `harness.reference_numbers` runs it: parameters and moments written over
+        rstep = jax.jit(lambda p, a, bb, r, s: ref.train_step(p, a, bb, r, s, sz), donate_argnums=(0, 1))
+        out["reference_step"] = got = sizes(rstep.lower(params, adam, rbatch, key, i).compile(), time.time() - t)
+        print(conf["name"], "reference_step", got, flush=True)
+        # what the chip holds while the reference runs: parameters and both
+        # moments in, one gradient out, beside the step's temporaries
+        leaves = jax.tree_util.tree_leaves(params)
+        n, state = sum(v.size for v in leaves), 4 * sum(v.size * v.dtype.itemsize for v in leaves)
+        print(
+            f"{conf['name']} reference holds: state {state:,} B ({state / n:.0f} B x {n:,} parameters) + "
+            f"step temp {got['temp_bytes']:,} B = {state + got['temp_bytes']:,} B = "
+            f"{100 * (state + got['temp_bytes']) / 16e9:.1f} % of 16e9", flush=True,
+        )
     return out
 
 

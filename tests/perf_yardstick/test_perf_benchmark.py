@@ -29,6 +29,7 @@ from perf.references import vocfeed  # noqa: E402
 REHEARSAL = os.path.join(ROOT, "perf", "rehearsal", "BENCHMARK.json")
 TOY = os.path.join(ROOT, "tests", "perf_yardstick", "toy")
 TOY_MANIFEST = os.path.join(TOY, "BENCHMARK.json")
+TOY_WIDE = os.path.join(TOY, "BENCHMARK.wide.json")  # chip only, by hand (toy/run_wide.py): no test runs it
 SEED = 2_147_484_001  # more than 32 signed bits hold, as the driver's are
 
 
@@ -131,8 +132,13 @@ def test_manifests_are_sound_and_their_files_exist(path):
     limits = {"embed_grad_gap", "change_norm_gap", "feed_row_gap"} if path == TOY_MANIFEST else DETECTOR_LIMITS
     m = manifest.load(path)
     assert manifest.validate(m) == []
-    for w in m["workloads"]:
-        cell = manifest.Cell(ROOT, path, w["name"])
+    cells = [(path, w["name"]) for w in m["workloads"]]
+    if path == TOY_MANIFEST:
+        # the toy's second manifest, the same program with parameters that fill a chip
+        assert manifest.validate(manifest.load(TOY_WIDE)) == []
+        cells.append((TOY_WIDE, "bigram_wide.fed"))
+    for where, name in cells:
+        cell = manifest.Cell(ROOT, where, name)
         assert cell.config["sizes"] and cell.mix["feed"] in ("loader", "staged")
         for metric in cell.per_layer:
             assert os.path.exists(cell.reader_path(metric["name"])), metric["name"]
@@ -197,14 +203,60 @@ def _rehearse(tmp, workload, trace, break_step=None, seconds=1.0):
     return result
 
 
+def _live_bytes():
+    """Bytes of the buffers alive on the one device, each counted once (two
+    arrays may share one: `device_put` onto the sharding a buffer has)."""
+    import jax
+
+    return sum({a.unsafe_buffer_pointer(): a.nbytes for a in jax.live_arrays() if not a.is_deleted()}.values())
+
+
+def _tree_bytes(tree):
+    import jax
+
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree) if isinstance(x, jax.Array))
+
+
+def _alive_at_each_set_up_step(alive, before):
+    """No fault: hung on `break_step`, it records at the entry of each step
+    call of the set-up what is alive on the devices beside the trainer's
+    state, the staged batch and what was there before the run, and the
+    bytes of one parameter set."""
+
+    def probe(trainer, step_call):
+        def probed(kw):
+            if len(alive) < harness.WARM_STEPS:
+                others = _live_bytes() - before - _tree_bytes(trainer.state) - _tree_bytes(kw)
+                alive.append((others, _tree_bytes(trainer.state.params)))
+            return step_call(kw)
+
+        return probed
+
+    return probe
+
+
 @pytest.fixture(scope="module", params=["tiny.fed", "bigram.fed"])
 def fed_traced(request, tmp_path_factory):
+    import gc
+
     seconds = 9.0 if request.param == "tiny.fed" else 1.0
-    return request.param, _rehearse(tmp_path_factory.mktemp("fed"), request.param, True, seconds=seconds)
+    gc.collect()
+    alive = []
+    result = _rehearse(
+        tmp_path_factory.mktemp("fed"), request.param, True, seconds=seconds,
+        break_step=_alive_at_each_set_up_step(alive, _live_bytes()),
+    )
+    return request.param, result, alive
 
 
 def test_window_loop_and_result_line_keys(fed_traced, tmp_path):
-    workload, r = fed_traced
+    workload, r, alive = fed_traced
+    # the harness's rule: while a step of the set-up runs, nothing parameter-sized
+    # of the harness's own is on the device (no copy of the first parameters, of
+    # Adam's first moment, of the parameters after step 3)
+    assert len(alive) == harness.WARM_STEPS
+    for others, one_parameter_set in alive:
+        assert others < one_parameter_set, alive
     assert list(r)[-1] == "compared"
     assert {"correct", "attempted", "failed", "metrics", "device", "breakdown"} <= set(r)
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > harness.WARM_STEPS
@@ -395,8 +447,39 @@ def test_the_control_in_float8_comes_out_not_correct(tmp_path):
             {"image": rng.randn(4, 64, 64, 3).astype(np.float32), "boxes": boxes,
              "labels": labels, "mask": labels >= 0}
         )
-    plain = harness.reference_numbers(ref, sz, SEED, batches)
-    control = harness.reference_numbers(ref, sz, SEED, batches, precision="float8")
+    # the reference runs at its own state's size: at the entry of each step,
+    # parameters and both moments (the first parameters wait on the host, the
+    # last step's gradient is dropped), and each step writes over what it is given
+    import gc
+
+    import jax
+
+    entries = []
+
+    class Probed(dict):
+        """The `jitted` cache, each step program it is given wrapped."""
+
+        def __setitem__(self, key, program):
+            def probed(params, adam, batch, *rest):
+                entries.append((_live_bytes() - before - _tree_bytes(batch), _tree_bytes(params)))
+                out = program(params, adam, batch, *rest)
+                # on the CPU the host's copy of the first parameters is a view of their
+                # buffers, which the first step of a run can therefore not take
+                given = adam if len(entries) % harness.CHECK_STEPS == 1 else (params, adam)
+                assert all(x.is_deleted() for x in jax.tree_util.tree_leaves(given)), "not donated"
+                return out
+
+            super().__setitem__(key, probed)
+
+    gc.collect()
+    before = _live_bytes()
+    jitted = Probed()
+    plain = harness.reference_numbers(ref, sz, SEED, batches, jitted=jitted)
+    control = harness.reference_numbers(ref, sz, SEED, batches, precision="float8", jitted=jitted)
+    assert len(entries) == 2 * harness.CHECK_STEPS
+    slack = 1 << 16  # keys, the step's number, the losses
+    for alive, one_parameter_set in entries:
+        assert 3 * one_parameter_set <= alive <= 4 * one_parameter_set + slack, entries
     nums = compare.numbers(control, plain)
     # held to the limits of the cell of record, not to the rehearsal's
     assert compare.judge(nums, cell.config["limits"]) is False, nums
@@ -481,6 +564,8 @@ def test_modules_beside_a_cells_data_files_are_found_first_and_reckon_memory_low
         chip=jax.sharding.SingleDeviceSharding(jax.devices()[0]),
     )
     assert out["per_chip_batch"] == 16 and "described" not in out["device"]
+    # the reference step is lowered as the harness runs it: parameters and moments written over
+    assert out["reference_step"]["alias_bytes"] >= 3 * 4 * (2 * 512 * 128 + 512)
     for step in ("train_step", "reference_step"):
         # the two tables, Adam's moments of them, and a batch of 16 x 64 tokens and targets
         assert out[step]["argument_bytes"] >= 3 * 4 * (2 * 512 * 128 + 512) + 2 * 4 * 16 * 64

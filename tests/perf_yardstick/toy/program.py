@@ -75,7 +75,8 @@ def _build(cfg):
         with jax.named_scope("bigram.embed"):
             hidden = params["embed"]["table"][batch["tokens"]]
         with jax.named_scope("bigram.logits"):
-            logits = hidden @ params["out"]["kernel"] + params["out"]["bias"]
+            # float32 as the configuration states: a TPU's default is one bfloat16 pass
+            logits = jnp.matmul(hidden, params["out"]["kernel"], precision="highest") + params["out"]["bias"]
             picked = jnp.take_along_axis(jax.nn.log_softmax(logits), batch["targets"][..., None], axis=-1)
             return -picked.mean()
 
