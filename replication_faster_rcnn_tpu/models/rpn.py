@@ -105,15 +105,20 @@ def select_proposals(
     scores = jnp.where(keep, fg_scores, -jnp.inf)
 
     # top-pre_nms by score (reference sorts then truncates, `nets/rpn.py:70-72`).
-    # One stable argsort serves BOTH the truncation and the NMS's
-    # descending-order requirement (assume_sorted below) — top_k followed
-    # by the NMS-internal argsort sorted ~12k candidates twice per image.
-    # lax.top_k and stable argsort(-s) break ties identically (lowest
-    # original index first), so this is bit-identical to the old pipeline.
-    order = jnp.argsort(-scores)
-    top_idx = jax.lax.slice_in_dim(order, 0, pre_nms)
-    top_scores = scores[top_idx]
-    top_boxes = props[top_idx]
+    # ONE stable sort carries the boxes along with the key: what comes out is
+    # already the descending candidate list the NMS wants (assume_sorted
+    # below), and no value is picked by index afterwards. The order is that
+    # of a stable argsort(-scores) (ties and -inf rows: lowest original index
+    # first, as lax.top_k breaks them), the scores come back by an exact
+    # negation, so this is bit-identical to argsort + `scores[idx]`,
+    # `props[idx]` (tests/oracles.py::select_proposals_gather). Those two
+    # gathers cost 4.91 + 3.34 ms of the 91.0 ms step at 32 x 12,000 indices:
+    # the chip serves an XLA gather at 9-13 ns an index (PERF.md, PR 28).
+    neg, *columns = jax.lax.sort(
+        (-scores, *(props[:, i] for i in range(4))), num_keys=1, is_stable=True
+    )
+    top_scores = -neg[:pre_nms]
+    top_boxes = jnp.stack(columns, axis=-1)[:pre_nms]
 
     # tiled exact NMS by default; ops.backend=pallas (or FRCNN_NMS=pallas)
     # swaps in the bit-identical ops/pallas kernel, FRCNN_NMS=loop the

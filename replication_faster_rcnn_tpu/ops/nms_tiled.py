@@ -54,9 +54,10 @@ def nms_fixed_tiled(
     ``assume_sorted``: the caller guarantees ``scores`` (after applying
     ``mask``) are already non-increasing, so the internal stable sort and
     its gathers are skipped. The proposal path uses this to sort ONCE:
-    its top-pre_nms selection already produces descending candidates
-    (`models/rpn.py::select_proposals`), and sorting 12k candidates twice
-    per image was pure waste on the hot path.
+    its top-pre_nms selection is one stable sort that carries the boxes
+    with the scores (`models/rpn.py::select_proposals`), so what arrives
+    here is already the descending candidate list, and neither a second
+    sort nor a pick by index stands between the selection and the loop.
     """
     n = boxes.shape[0]
     tile = min(tile, max(n, 1))

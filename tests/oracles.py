@@ -174,3 +174,74 @@ def proposal_match_np(
         return np.zeros(len(rois), np.int32), np.zeros(len(rois))
     ious = iou_np(rois, gt)
     return ious.argmax(axis=1), ious.max(axis=1)
+
+
+# ------------------------------------- the gather writings (before PR 28)
+#
+# Three sites of the train step picked values by an integer index through
+# XLA's gather until PR 28 replaced each by a sort that carries its payload
+# or by compare-and-select. The old writings live on here, in jnp, as the
+# oracles the new ones must equal to the bit (jax is imported on use: the
+# oracles above are numpy alone).
+
+def select_proposals_gather(anchors, fg_scores, deltas, img_h, img_w, cfg, train):
+    """`models/rpn.py::select_proposals` as it was: one stable argsort of
+    the negated scores, then `scores[idx]` and `props[idx]`."""
+    import jax
+    import jax.numpy as jnp
+
+    from replication_faster_rcnn_tpu.ops import boxes as box_ops
+    from replication_faster_rcnn_tpu.ops.nms import nms_fixed_auto
+
+    pre_nms = min(cfg.pre_nms(train), anchors.shape[0])
+    props = box_ops.clip(box_ops.decode(anchors, deltas), img_h, img_w)
+    hs = props[:, 2] - props[:, 0]
+    ws = props[:, 3] - props[:, 1]
+    keep = (hs >= cfg.min_size) & (ws >= cfg.min_size)
+    scores = jnp.where(keep, fg_scores, -jnp.inf)
+    top_idx = jax.lax.slice_in_dim(jnp.argsort(-scores), 0, pre_nms)
+    top_scores = scores[top_idx]
+    top_boxes = props[top_idx]
+    idx, valid = nms_fixed_auto(
+        top_boxes, top_scores, cfg.nms_thresh, cfg.post_nms(train),
+        mask=jnp.isfinite(top_scores), assume_sorted=True,
+    )
+    return top_boxes[idx] * valid[:, None], valid
+
+
+def ignore_cross_entropy_optax(logits, labels):
+    """`train/losses.py::ignore_cross_entropy` as it was (axis_name=None):
+    optax's integer-label CE, whose label pick is a `take_along_axis`."""
+    import jax.numpy as jnp
+    import optax
+
+    valid = labels >= 0
+    safe = jnp.where(valid, labels, 0).astype(jnp.int32)
+    ce = optax.softmax_cross_entropy_with_integer_labels(logits, safe)
+    return jnp.where(valid, ce, 0.0).sum() / jnp.maximum(valid.sum(), 1)
+
+
+def matched_boxes_gather(gt_boxes, argmax):
+    """The matched-box lookup of `targets/anchor_targets.py` as it was."""
+    return gt_boxes[argmax]
+
+
+def largest_gather(lowered_text: str) -> int:
+    """The most index vectors any `stablehlo.gather` of a lowered module
+    (`jax.jit(f).lower(...).as_text()`) takes; 0 where it holds none."""
+    import math
+    import re
+
+    most = 0
+    for line in lowered_text.splitlines():
+        if '"stablehlo.gather"' not in line:
+            continue
+        found = re.search(r"index_vector_dim = (\d+)", line)  # printed unless 0
+        vector_dim = int(found.group(1)) if found else 0
+        # the operand types: (operand, start_indices) -> result
+        indices = re.search(r": \(tensor<[^>]*>, tensor<([^>]*)>\)", line).group(1)
+        shape = [int(d) for d in indices.split("x")[:-1]]
+        if vector_dim < len(shape):
+            del shape[vector_dim]
+        most = max(most, math.prod(shape))
+    return most

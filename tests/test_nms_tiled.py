@@ -3,6 +3,7 @@ the same boxes, in the same order, as the loop NMS (`ops/nms.py`) and the
 numpy oracle — across tile boundaries, ties, masks, and degenerate inputs."""
 
 import numpy as np
+import pytest
 import jax.numpy as jnp
 
 from replication_faster_rcnn_tpu.ops.nms import nms_fixed
@@ -129,8 +130,9 @@ def test_assume_sorted_bit_identical():
 
 
 def test_select_proposals_single_sort_matches_topk_pipeline():
-    # models/rpn.py now sorts once (argsort + slice + assume_sorted NMS);
-    # this pins bit-identity against the old top_k -> unsorted-NMS pipeline
+    # models/rpn.py sorts once (one stable sort that carries the boxes, a
+    # slice, assume_sorted NMS); this pins bit-identity against the oldest
+    # pipeline, top_k -> unsorted NMS
     import jax
 
     from replication_faster_rcnn_tpu.config import ProposalConfig
@@ -169,3 +171,62 @@ def test_select_proposals_single_sort_matches_topk_pipeline():
     old_rois = top_boxes[idx] * val[:, None]
     np.testing.assert_array_equal(np.asarray(valid), np.asarray(val))
     np.testing.assert_array_equal(np.asarray(rois), np.asarray(old_rois))
+
+
+def _proposal_inputs(a=333, seed=5):
+    """Anchors, scores with ties, and deltas that shrink some rows under
+    the min-size filter (their scores become -inf, tied with each other)."""
+    rng = np.random.default_rng(seed)
+    anchors = rand_boxes(a, rng, size=80.0).astype(np.float32)
+    deltas = rng.normal(0, 0.1, (a, 4)).astype(np.float32)
+    deltas[::7, 2:] = -3.0  # exp(-3) of the anchor's extent: under min_size
+    fg = rng.uniform(0, 1, a).astype(np.float32)
+    fg[10] = fg[20] = fg[30]
+    fg[200:204] = fg[100]
+    return jnp.array(anchors), jnp.array(fg), jnp.array(deltas)
+
+
+@pytest.mark.parametrize(
+    "kw,train",
+    [
+        # pre_nms >= A: every row kept, the -inf rows last in index order
+        (dict(), True),
+        # pre_nms < A, the inference shape: the cut falls inside the list
+        (dict(pre_nms_test=100, post_nms_test=30), False),
+    ],
+    ids=["train_keeps_all", "test_keeps_few"],
+)
+def test_select_proposals_equals_the_gather_writing_to_the_bit(kw, train):
+    from replication_faster_rcnn_tpu.config import ProposalConfig
+    from replication_faster_rcnn_tpu.models.rpn import select_proposals
+
+    cfg = ProposalConfig(**kw)
+    anchors, fg, deltas = _proposal_inputs()
+    rois, valid = select_proposals(anchors, fg, deltas, 96.0, 96.0, cfg, train)
+    want_rois, want_valid = oracles.select_proposals_gather(
+        anchors, fg, deltas, 96.0, 96.0, cfg, train
+    )
+    assert 0 < int(want_valid.sum()) <= cfg.post_nms(train)
+    np.testing.assert_array_equal(np.asarray(valid), np.asarray(want_valid))
+    np.testing.assert_array_equal(np.asarray(rois), np.asarray(want_rois))
+
+
+def test_select_proposals_picks_nothing_by_index_before_the_nms():
+    # the engagement check: in the lowered text no gather takes more
+    # indices than post_nms (the `top_boxes[idx]` after the NMS stays)
+    import jax
+
+    from replication_faster_rcnn_tpu.config import ProposalConfig
+    from replication_faster_rcnn_tpu.models.rpn import select_proposals
+
+    cfg = ProposalConfig(pre_nms_train=200, post_nms_train=40)
+    anchors, fg, deltas = _proposal_inputs()
+    text = jax.jit(
+        lambda a, s, d: select_proposals(a, s, d, 96.0, 96.0, cfg, True)
+    ).lower(anchors, fg, deltas).as_text()
+    assert oracles.largest_gather(text) <= cfg.post_nms(True)
+    # the oracle is what the check would catch
+    old = jax.jit(
+        lambda a, s, d: oracles.select_proposals_gather(a, s, d, 96.0, 96.0, cfg, True)
+    ).lower(anchors, fg, deltas).as_text()
+    assert oracles.largest_gather(old) == cfg.pre_nms(True)

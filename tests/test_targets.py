@@ -6,6 +6,8 @@ encoding) must match the numpy oracle exactly; the random subsampling is
 checked via its invariants (budgets, only-demotions, uniform coverage).
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,9 @@ from replication_faster_rcnn_tpu.targets import (
     random_subset_mask,
 )
 from tests import oracles
+
+# the module: the package re-exports the function under the same name
+_ANCHOR_TARGETS = importlib.import_module("replication_faster_rcnn_tpu.targets.anchor_targets")
 
 
 @pytest.fixture
@@ -176,6 +181,37 @@ class TestAnchorTargets:
         )
         assert not bool((labels == 1).any())
         np.testing.assert_array_equal(np.asarray(reg), 0.0)
+
+    @pytest.mark.parametrize("n_gt", [3, 0], ids=["padded_gt_rows", "no_gt"])
+    def test_equals_the_gather_writing_to_the_bit(self, anchors, n_gt, monkeypatch):
+        # `gt_boxes[argmax]`, the lookup before PR 28, put back as the oracle
+        rng = np.random.RandomState(4)
+        gt_pad = rng.uniform(1, 9, (8, 4)).astype(np.float32)  # padding is not zeros
+        gt_pad[:n_gt] = _random_gt(rng, n_gt)
+        args = (
+            jax.random.PRNGKey(5), jnp.asarray(gt_pad), jnp.asarray(np.arange(8) < n_gt),
+            jnp.asarray(anchors), self.cfg,
+        )
+        reg, labels = anchor_targets(*args)
+        monkeypatch.setattr(_ANCHOR_TARGETS, "matched_boxes", oracles.matched_boxes_gather)
+        want_reg, want_labels = anchor_targets(*args)
+        assert bool((np.asarray(want_labels) == 1).any()) == (n_gt > 0)
+        np.testing.assert_array_equal(np.asarray(labels), np.asarray(want_labels))
+        np.testing.assert_array_equal(np.asarray(reg), np.asarray(want_reg))
+
+    def test_looks_up_no_box_per_anchor_by_index(self, anchors, monkeypatch):
+        # the engagement check: no gather of the lowered text takes more
+        # indices than there are gt rows (data.max_boxes)
+        def lowered():
+            return jax.jit(
+                lambda k, b, m, a: anchor_targets(k, b, m, a, self.cfg)
+            ).lower(
+                jax.random.PRNGKey(0), jnp.zeros((8, 4)), jnp.arange(8) < 3, jnp.asarray(anchors)
+            ).as_text()
+
+        assert oracles.largest_gather(lowered()) <= 8
+        monkeypatch.setattr(_ANCHOR_TARGETS, "matched_boxes", oracles.matched_boxes_gather)
+        assert oracles.largest_gather(lowered()) == anchors.shape[0]  # what it catches
 
     def test_batched_shapes_and_jit(self, anchors):
         rng = np.random.RandomState(3)

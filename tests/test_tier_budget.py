@@ -1,10 +1,10 @@
 """The fast tier polices its own wall-time budget (ISSUE PR-2 satellite).
 
-The tier-1 verify command hard-kills the suite at 870 s (ROADMAP.md); a
-PR that adds one more compiling test too many makes the WHOLE tier read
-as broken. `benchmarks/tier_budget_audit.py` banks measured per-test
-durations; the audit test here projects the cost of the live fast-tier
-collection against that bank and fails while the offending PR is still
+The driver hard-kills the tier-1 run (6 xdist workers, a file whole on one
+worker) at 1470 s; a PR that adds one more compiling test too many makes
+the WHOLE tier read as broken. `benchmarks/tier_budget_audit.py` banks
+measured per-test durations; the audit test here projects the cost of the
+live fast-tier collection against that bank and fails while the offending PR is still
 open — rebalance markers (or shrink configs) and re-bank instead of
 silently timing out later.
 """
@@ -58,6 +58,14 @@ class TestParsing:
         assert rep["n_known"] == 2
         assert rep["n_unknown"] == 1
         assert rep["unknown_ids"] == ["t::new"]
+
+    def test_project_wall_gives_a_file_whole_to_the_least_loaded_worker(self):
+        # --dist loadfile: a.py (10 s) on one worker, b.py then c.py on the other
+        banked = {"a.py::x": 6.0, "a.py::y": 4.0, "b.py::x": 3.0, "c.py::x": 5.0}
+        rep = audit.project_wall(list(banked) + ["c.py::new"], banked, default_s=2.0, workers=2)
+        assert rep["projected_s"] == 10.0
+        assert rep["serial_s"] == 20.0
+        assert audit.project_wall(list(banked), banked, workers=1)["projected_s"] == 18.0
 
     def test_audit_report_verdicts(self):
         record = {"durations": {"t::a": 800.0}, "measured": "2026-01-01"}

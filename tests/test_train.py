@@ -73,6 +73,41 @@ class TestLosses:
         )
         assert np.isfinite(float(out)) and float(out) == 0.0
 
+    # value AND gradient against optax's integer-label CE, the writing before
+    # PR 28 (tests/oracles.py): op by op, where XLA:CPU contracts nothing, the
+    # two agree to the bit (inside one jitted fusion the select's backward
+    # gets a fused multiply-add, a last-place difference)
+    @pytest.mark.parametrize(
+        "classes,ignored", [(2, 0.6), (21, 0.3), (2, 1.0)], ids=["rpn_c2", "head_c21", "all_ignored"]
+    )
+    def test_ignore_cross_entropy_equals_optax_value_and_gradient(self, classes, ignored):
+        from tests import oracles
+
+        rng = np.random.default_rng(classes)
+        logits = rng.normal(0, 3, (3, 40, classes)).astype(np.float32)
+        logits[0, 0, 0] = -np.inf  # another class's infinite logit makes no NaN (0 * inf would)
+        labels = rng.integers(0, classes, (3, 40))
+        labels[rng.uniform(size=labels.shape) < ignored] = -1
+        if ignored < 1.0:
+            labels[0, 0] = classes - 1  # that row counts, under another label
+        logits, labels = jnp.asarray(logits), jnp.asarray(labels.astype(np.int32))
+        value, grad = jax.value_and_grad(losses.ignore_cross_entropy)(logits, labels)
+        want, want_grad = jax.value_and_grad(oracles.ignore_cross_entropy_optax)(logits, labels)
+        np.testing.assert_array_equal(np.asarray(value), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(grad), np.asarray(want_grad))
+
+    def test_ignore_cross_entropy_lowers_without_gather_or_scatter(self):
+        # the engagement check: the label pick is a select both ways
+        from tests import oracles
+
+        logits, labels = jnp.zeros((2, 30, 2)), jnp.zeros((2, 30), jnp.int32)
+        new, old = (
+            jax.jit(jax.value_and_grad(f)).lower(logits, labels).as_text()
+            for f in (losses.ignore_cross_entropy, oracles.ignore_cross_entropy_optax)
+        )
+        assert oracles.largest_gather(new) == 0 and "stablehlo.scatter" not in new
+        assert oracles.largest_gather(old) == 60 and "stablehlo.scatter" in old
+
 
 class TestSchedule:
     def test_epoch_granular_cosine(self):
