@@ -2,8 +2,8 @@
 
 ResNet-config MFU should either reach >=0.25 or be bounded by an
 analysis naming the irreducible costs. This is the static half of that
-analysis (the dynamic half is `benchmarks/grad_breakdown.py`, and a
-decoded profiler trace is ROADMAP S3): enumerate every
+analysis (the measured half is a traced run of the benchmark of record
+cut by `perf/stagecut.py`): enumerate every
 `conv_general_dilated` in the model's own jaxpr (exact traced shapes —
 no hand-maintained table) and bound each pass's achievable MXU
 utilization from the systolic array's tiling:
@@ -22,8 +22,8 @@ utilization from the systolic array's tiling:
   A 64-channel layer therefore cannot exceed 50% MXU utilization on its
   forward/wgrad output lanes no matter what the compiler does — that is
   the "irreducible" part; the rest of the gap between the ceiling floor
-  and a measured step is XLA scheduling/fusion/HBM, attributable on
-  chip by grad_breakdown.
+  and a measured step is XLA scheduling/fusion/HBM, which the traced
+  run's per-op table attributes.
 
 Writes ``benchmarks/layer_cost_table.json``:
   per-conv rows (shapes, per-pass GFLOPs and efficiency ceilings) and
@@ -51,9 +51,12 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 OUT = os.path.join(REPO, "benchmarks", "layer_cost_table.json")
 
-# single source for the v5e roofline constant (namespace-package import;
-# benchmark.py's _peak_flops_per_sec uses the same figure per device)
-from benchmarks.backward_analysis import V5E_PEAK_BF16_FLOPS as PEAK_BF16  # noqa: E402
+# the v5e's datasheet bf16 peak, from the one table that holds it
+from replication_faster_rcnn_tpu.telemetry.mfu import (  # noqa: E402
+    tpu_peak_flops_per_sec,
+)
+
+PEAK_BF16 = tpu_peak_flops_per_sec("TPU v5 lite", 1)
 
 TILE = 128
 
@@ -71,12 +74,12 @@ def collect_convs(config_name: str, batch_size: int, image_size=None):
 
     jax.config.update("jax_platforms", "cpu")  # pure trace; never touch a chip
 
-    from replication_faster_rcnn_tpu.benchmark import abstract_step_inputs
     from replication_faster_rcnn_tpu.config import get_config
     from replication_faster_rcnn_tpu.train.train_step import (
         compute_losses,
         make_optimizer,
     )
+    from replication_faster_rcnn_tpu.train.warmup import abstract_step_inputs
 
     import dataclasses
 
@@ -90,9 +93,9 @@ def collect_convs(config_name: str, batch_size: int, image_size=None):
         train=dataclasses.replace(cfg.train, batch_size=batch_size),
     )
     tx, _ = make_optimizer(cfg, 100)
-    # the bench's shared abstract fixture: shapes only, no arrays, no
-    # param-init program — this table can never trace different shapes
-    # than the flops_per_step it is reconciled against
+    # the warm-up's abstract fixture: shapes only, no arrays, no
+    # param-init program — this table traces the shapes the trainer's
+    # own programs are compiled for
     model, state_abs, batch_abs = abstract_step_inputs(cfg, tx)
 
     def loss(params, batch_stats, rng, step, batch):
@@ -219,18 +222,14 @@ def main() -> None:
             "bound per pass — what no compiler schedule can exceed, not a "
             "prediction of what XLA achieves. dgrad of the image-input "
             "stem is skipped (its dx is unused). Non-conv FLOPs (head "
-            "matmuls, NMS, targets) are excluded here; bench.py's "
-            "flops_per_step covers the whole program. CONVENTION: this "
+            "matmuls, NMS, targets) are excluded here. CONVENTION: this "
             "table counts the full kh*kw taps per output position (the "
             "work the MXU actually performs on the padded im2col, and the "
             "fvcore/industry convention behind quoted MFU numbers); "
-            "XLA's HloCostAnalysis — the basis of bench.py's "
-            "flops_per_step — excludes border padding taps (measured: "
-            "-30.5% on the ROI head's 4x4x3x3 SAME convs, (10/12)^2 "
-            "exactly; -1.4% on the 300x300 stem), so bench.py's mfu is "
-            "systematically CONSERVATIVE: flagship b16 forward convs are "
-            "902 GFLOP full-tap vs ~791 border-exact for forward+loss, "
-            "and the 0.153 record corresponds to ~0.186 full-tap."
+            "XLA's HloCostAnalysis excludes border padding taps (-30.5% "
+            "on the ROI head's 4x4x3x3 SAME convs, (10/12)^2 exactly; "
+            "-1.4% on the 300x300 stem), so an MFU priced by it reads "
+            "lower than one priced from shapes (perf/flops.py)."
         ),
     }
     with open(out_path, "w") as f:

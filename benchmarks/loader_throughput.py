@@ -202,12 +202,13 @@ def main() -> None:
 
     # trainer-loop throughput: real Trainer epochs through the
     # loader + shard_batch/device_put path (NOT pre-staged tensors like
-    # bench.py) on the synthetic dataset at the full 600x600 b16.
+    # the benchmark's resident cells) on the synthetic dataset at the full
+    # 600x600 b16.
     trainer_rec = None
     if os.environ.get("LOADER_BENCH_TRAINER", "1") == "1":
         import jax
 
-        from replication_faster_rcnn_tpu.benchmark import require_accelerator
+        from replication_faster_rcnn_tpu.telemetry.mfu import require_accelerator
         from replication_faster_rcnn_tpu.config import (
             MeshConfig,
             TrainConfig,

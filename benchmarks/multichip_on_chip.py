@@ -103,7 +103,7 @@ def main() -> int:
     import jax
 
     import __graft_entry__ as graft
-    from replication_faster_rcnn_tpu.benchmark import require_accelerator
+    from replication_faster_rcnn_tpu.telemetry.mfu import require_accelerator
     from replication_faster_rcnn_tpu.config import get_config
 
     device = require_accelerator("multichip_on_chip")

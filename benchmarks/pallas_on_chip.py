@@ -196,7 +196,7 @@ def _run_case(name, pallas_fn, xla_fn, args, atol):
 
 
 def main() -> int:
-    from replication_faster_rcnn_tpu.benchmark import require_accelerator
+    from replication_faster_rcnn_tpu.telemetry.mfu import require_accelerator
 
     device = require_accelerator("pallas_on_chip")
     only = set(sys.argv[1:])

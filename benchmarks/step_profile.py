@@ -4,8 +4,8 @@ One command measures a config's train step, attributes wall time to the
 pipeline phases (``dispatch`` floor, ``fwd``, ``bwd``, ``update``) via
 the telemetry span tracer (`telemetry/spans.py`), attaches the analytic
 per-phase FLOPs/bytes from XLA's HloCostAnalysis of the same lowered
-programs (`benchmark.lowered_cost`), computes MFU against the measured
-host peak (`telemetry/mfu.py`), and checks the result against the
+programs (`analysis.fingerprint.lowered_cost`), computes MFU against the
+measured host peak (`telemetry/mfu.py`), and checks the result against the
 committed record for the same (config, backend, platform) under
 ``benchmarks/records/``:
 
@@ -234,9 +234,9 @@ def tiny_config(batch_size: int = 2, image_size: int = 64, backend: str = "auto"
 
 
 def _phase_fns(model, cfg, tx):
-    """The four jitted phase programs. fwd/grad mirror the bench's stage
-    prefixes (`benchmark._stage_breakdown`) so the two harnesses can never
-    attribute different pipelines; update/null run on materialized grads."""
+    """The four jitted phase programs: fwd and grad are the step's own
+    `compute_losses`, without and under `value_and_grad`; update/null run
+    on materialized grads."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -548,12 +548,9 @@ def _measure_async_save(step, state, batch_staged, n_saves: int = 3):
 def profile(cfg, config_token: str, n_steps: int = 5):
     """Measure one config's step profile; returns the record dict."""
     import jax
-    import numpy as np  # noqa: F401 — keeps parity with bench imports
+    import numpy as np
 
-    from replication_faster_rcnn_tpu.benchmark import (
-        abstract_step_inputs,
-        lowered_cost,
-    )
+    from replication_faster_rcnn_tpu.analysis.fingerprint import lowered_cost
     from replication_faster_rcnn_tpu.data import SyntheticDataset
     from replication_faster_rcnn_tpu.data.loader import collate
     from replication_faster_rcnn_tpu.telemetry.mfu import (
@@ -567,6 +564,7 @@ def profile(cfg, config_token: str, n_steps: int = 5):
         make_optimizer,
         make_train_step,
     )
+    from replication_faster_rcnn_tpu.train.warmup import abstract_step_inputs
 
     batch_size = cfg.train.batch_size
     k = max(1, cfg.train.steps_per_dispatch)
@@ -623,30 +621,22 @@ def profile(cfg, config_token: str, n_steps: int = 5):
     images_per_sec = batch_size / (step_ms / 1e3)
 
     # analytic per-phase cost: HloCostAnalysis of the SAME programs,
-    # lowered on abstract inputs (the per-phase split is banked from the
-    # CPU records only; elsewhere the whole-step count is enough).
-    analytic = None
-    flops_per_step = None
-    if jax.default_backend() == "cpu":
-        _, state_abs, batch_abs = abstract_step_inputs(cfg, tx)
-        grads_abs = jax.tree_util.tree_map(
-            lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), state_abs.params
-        )
-        fwd_cost = lowered_cost(fwd_fn, state_abs, batch_abs)
-        grad_cost = lowered_cost(grad_fn, state_abs, batch_abs)
-        update_cost = lowered_cost(update_fn, state_abs, grads_abs)
-        analytic = {
-            "fwd": fwd_cost,
-            "bwd": {
-                key: max(0.0, grad_cost[key] - fwd_cost[key]) for key in fwd_cost
-            },
-            "update": update_cost,
-        }
-        flops_per_step = grad_cost["flops"] + update_cost["flops"]
-    else:
-        from replication_faster_rcnn_tpu.benchmark import _step_flops
-
-        flops_per_step = _step_flops(cfg, batch_size)
+    # lowered on abstract inputs
+    _, state_abs, batch_abs = abstract_step_inputs(cfg, tx)
+    grads_abs = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), state_abs.params
+    )
+    fwd_cost = lowered_cost(fwd_fn, state_abs, batch_abs)
+    grad_cost = lowered_cost(grad_fn, state_abs, batch_abs)
+    update_cost = lowered_cost(update_fn, state_abs, grads_abs)
+    analytic = {
+        "fwd": fwd_cost,
+        "bwd": {
+            key: max(0.0, grad_cost[key] - fwd_cost[key]) for key in fwd_cost
+        },
+        "update": update_cost,
+    }
+    flops_per_step = grad_cost["flops"] + update_cost["flops"]
 
     # critical-path overlap: feed-blocked + checkpoint-blocked host time
     # through the PR 4 machinery (data/prefetch_device.py,

@@ -11,8 +11,8 @@ StableHLO and the COMPILED executable,
 * the collective inventory (which psums, at which element types — read
   from the lowered IR, because XLA:CPU legalizes bf16 all-reduces to f32
   in the compiled module and would mask the contract),
-* HloCostAnalysis flops/bytes (via `benchmark.lowered_cost_analysis`,
-  the same pricing the step-profile harness banks), and
+* HloCostAnalysis flops/bytes (`lowered_cost_analysis`, the same
+  pricing the step-profile harness banks), and
 * the executable's memory analysis with a peak-HBM estimate
   (arguments + outputs − aliased + temporaries).
 
@@ -23,18 +23,20 @@ a banked one. The contract rules over these records live in
 analysis/hlolint.py (HLO contracts + drift) and analysis/shardlint.py
 (sharding & collective-cost, over the committed bank only).
 
-jax is imported lazily: everything except `summarize_abstract` /
-`fingerprint_program` is pure text/JSON work, and the static consumers
-(shardlint, commcost) reuse the parsers here without touching a backend.
+jax is imported lazily: everything except `summarize_abstract`,
+`lowered_cost` and `fingerprint_program` is pure text/JSON work, and the
+static consumers (shardlint, commcost) reuse the parsers here without
+touching a backend.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 import os
 import re
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 SCHEMA = "hlo_fingerprint/v1"
 
@@ -364,6 +366,124 @@ def summarize_abstract(tree) -> List[Dict[str, Any]]:
     return out
 
 
+def lowered_cost_analysis(lowered):
+    """{flops, bytes_accessed} of an already-lowered program, from XLA's
+    HloCostAnalysis. Shared by the step-profile harness and the HLO
+    auditor (:func:`fingerprint_program`) so both price programs
+    identically.
+
+    The CPU client analyses the lowered module without compiling it. The
+    TPU client has no pre-compile analysis (`Lowered.cost_analysis()` is
+    None there — seen on the v5e, libtpu 0.0.34), so there the program is
+    compiled and the executable's own analysis is read."""
+    ca = lowered.cost_analysis()
+    if ca is None:
+        ca = lowered.compile().cost_analysis()
+    return {
+        "flops": float(ca.get("flops", 0.0)),
+        "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
+    }
+
+
+def lowered_cost(fn, *abstract_args):
+    """{flops, bytes_accessed} of ``fn`` from HloCostAnalysis of its
+    abstract lowering (see :func:`lowered_cost_analysis`)."""
+    import jax
+
+    return lowered_cost_analysis(jax.jit(fn).lower(*abstract_args))
+
+
+# ------------------------------------------------- sharding repr parsing
+
+# `NamedSharding(mesh=Mesh('data': 2, 'model': 1),
+#  spec=PartitionSpec(None, 'data'), memory_kind=unpinned_host)` — the
+# repr summarize_abstract banks. PartitionSpec entries may be None, a
+# quoted axis name, or a tuple of names (one nesting level).
+_MESH_RE = re.compile(r"mesh=Mesh\(([^)]*)\)")
+_MESH_AXIS_RE = re.compile(r"'(\w+)':\s*(\d+)")
+_SPEC_RE = re.compile(r"spec=PartitionSpec\(((?:[^()]|\([^()]*\))*)\)")
+
+
+@dataclasses.dataclass(frozen=True)
+class ShardingView:
+    """A parsed NamedSharding repr: mesh axis sizes + normalized per-dim
+    spec (each entry None or a tuple of axis names, trailing Nones
+    trimmed)."""
+
+    mesh: Tuple[Tuple[str, int], ...]
+    spec: Tuple[Optional[Tuple[str, ...]], ...]
+
+    @property
+    def axes_used(self) -> frozenset:
+        names: set = set()
+        for entry in self.spec:
+            if entry:
+                names.update(entry)
+        return frozenset(names)
+
+    def spec_str(self) -> str:
+        if not self.spec:
+            return "P()"
+        toks = []
+        for entry in self.spec:
+            if entry is None:
+                toks.append("None")
+            elif len(entry) == 1:
+                toks.append(f"'{entry[0]}'")
+            else:
+                toks.append("(" + ", ".join(f"'{a}'" for a in entry) + ")")
+        return f"P({', '.join(toks)})"
+
+
+def _parse_spec_body(body: str) -> Tuple[Optional[Tuple[str, ...]], ...]:
+    # split on top-level commas only: tuple entries `('a', 'b')` nest one
+    # paren level
+    parts: List[str] = []
+    depth = 0
+    token = ""
+    for ch in body:
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        if ch == "," and depth == 0:
+            parts.append(token)
+            token = ""
+        else:
+            token += ch
+    parts.append(token)
+    entries: List[Optional[Tuple[str, ...]]] = []
+    for part in parts:
+        part = part.strip()
+        if not part:
+            continue
+        if part == "None":
+            entries.append(None)
+            continue
+        names = re.findall(r"'(\w+)'", part)
+        if names:
+            entries.append(tuple(names))
+    while entries and entries[-1] is None:
+        entries.pop()
+    return tuple(entries)
+
+
+def parse_sharding(repr_str: Optional[str]) -> Optional[ShardingView]:
+    """ShardingView for a banked NamedSharding repr; None for anything
+    else (null, SingleDeviceSharding, unparseable) — callers skip those
+    leaves rather than guess."""
+    if not repr_str or "NamedSharding" not in repr_str:
+        return None
+    mm = _MESH_RE.search(repr_str)
+    sm = _SPEC_RE.search(repr_str)
+    if not mm or not sm:
+        return None
+    mesh = tuple(
+        (name, int(size)) for name, size in _MESH_AXIS_RE.findall(mm.group(1))
+    )
+    return ShardingView(mesh=mesh, spec=_parse_spec_body(sm.group(1)))
+
+
 def fingerprint_program(spec) -> Dict[str, Any]:
     """AOT-lower and compile one ProgramSpec; return its fingerprint.
 
@@ -375,7 +495,6 @@ def fingerprint_program(spec) -> Dict[str, Any]:
     import jax
 
     from replication_faster_rcnn_tpu.analysis import commcost
-    from replication_faster_rcnn_tpu.benchmark import lowered_cost_analysis
 
     jitted, args = spec.build()
     lowered = jitted.lower(*args)
@@ -506,7 +625,9 @@ MEMORY_REL_TOL = 0.25
 # the SL005 comm-budget arm compares live-vs-banked wire bytes with its
 # own tolerance (the partitioned half wobbles with the SPMD pipeline),
 # and out_shardings reprs wobble with the jax version — shardlint parses
-# the banked values structurally instead of comparing text.
+# the banked values structurally instead of comparing text. `args` holds
+# the same reprs leaf by leaf, so `diff_programs` compares each leaf's
+# sharding by what it parses to (`_args_as_banked_facts`).
 _EXACT_FIELDS = ("args", "params", "outputs", "aliasing", "collectives", "has_f64")
 
 
@@ -514,6 +635,21 @@ def _rel_delta(cur: float, banked: float) -> float:
     if banked == 0.0:
         return 0.0 if cur == 0.0 else float("inf")
     return abs(cur - banked) / abs(banked)
+
+
+def _args_as_banked_facts(args):
+    """``args`` with each leaf's sharding reduced to (mesh axis sizes,
+    spec): how jax prints a mesh's axis types or a memory kind changes
+    with its version, what the sharding is does not. A repr that does not
+    parse (null, a SingleDeviceSharding) is compared as the text it is."""
+    if not isinstance(args, dict):
+        return args
+
+    def fact(leaf):
+        text = leaf.get("sharding")
+        return {**leaf, "sharding": parse_sharding(text) or text}
+
+    return {role: [fact(leaf) for leaf in leaves] for role, leaves in args.items()}
 
 
 def diff_programs(
@@ -526,7 +662,10 @@ def diff_programs(
     banked record: [] when they agree, else human-readable mismatches."""
     out: List[str] = []
     for field in _EXACT_FIELDS:
-        if current.get(field) != banked.get(field):
+        cur, bank = current.get(field), banked.get(field)
+        if field == "args":
+            cur, bank = _args_as_banked_facts(cur), _args_as_banked_facts(bank)
+        if cur != bank:
             out.append(f"{field} changed vs bank")
     for key in ("flops", "bytes_accessed"):
         cur = float(current.get("cost", {}).get(key, 0.0))

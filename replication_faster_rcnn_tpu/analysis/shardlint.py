@@ -58,6 +58,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from replication_faster_rcnn_tpu.analysis import commcost
 from replication_faster_rcnn_tpu.analysis import fingerprint as _fp
+from replication_faster_rcnn_tpu.analysis.fingerprint import parse_sharding
 from replication_faster_rcnn_tpu.analysis.jaxlint import (
     Baseline,
     Finding,
@@ -146,97 +147,6 @@ def compose_spec_dims(
     while spec and spec[-1] is None:
         spec.pop()
     return tuple(spec)
-
-
-# ------------------------------------------------- sharding repr parsing
-
-# `NamedSharding(mesh=Mesh('data': 2, 'model': 1),
-#  spec=PartitionSpec(None, 'data'), memory_kind=unpinned_host)` — the
-# repr summarize_abstract banks. PartitionSpec entries may be None, a
-# quoted axis name, or a tuple of names (one nesting level).
-_MESH_RE = re.compile(r"mesh=Mesh\(([^)]*)\)")
-_MESH_AXIS_RE = re.compile(r"'(\w+)':\s*(\d+)")
-_SPEC_RE = re.compile(r"spec=PartitionSpec\(((?:[^()]|\([^()]*\))*)\)")
-
-
-@dataclasses.dataclass(frozen=True)
-class ShardingView:
-    """A parsed NamedSharding repr: mesh axis sizes + normalized per-dim
-    spec (each entry None or a tuple of axis names, trailing Nones
-    trimmed)."""
-
-    mesh: Tuple[Tuple[str, int], ...]
-    spec: Tuple[Optional[Tuple[str, ...]], ...]
-
-    @property
-    def axes_used(self) -> frozenset:
-        names: set = set()
-        for entry in self.spec:
-            if entry:
-                names.update(entry)
-        return frozenset(names)
-
-    def spec_str(self) -> str:
-        if not self.spec:
-            return "P()"
-        toks = []
-        for entry in self.spec:
-            if entry is None:
-                toks.append("None")
-            elif len(entry) == 1:
-                toks.append(f"'{entry[0]}'")
-            else:
-                toks.append("(" + ", ".join(f"'{a}'" for a in entry) + ")")
-        return f"P({', '.join(toks)})"
-
-
-def _parse_spec_body(body: str) -> Tuple[Optional[Tuple[str, ...]], ...]:
-    # split on top-level commas only: tuple entries `('a', 'b')` nest one
-    # paren level
-    parts: List[str] = []
-    depth = 0
-    token = ""
-    for ch in body:
-        if ch == "(":
-            depth += 1
-        elif ch == ")":
-            depth -= 1
-        if ch == "," and depth == 0:
-            parts.append(token)
-            token = ""
-        else:
-            token += ch
-    parts.append(token)
-    entries: List[Optional[Tuple[str, ...]]] = []
-    for part in parts:
-        part = part.strip()
-        if not part:
-            continue
-        if part == "None":
-            entries.append(None)
-            continue
-        names = re.findall(r"'(\w+)'", part)
-        if names:
-            entries.append(tuple(names))
-    while entries and entries[-1] is None:
-        entries.pop()
-    return tuple(entries)
-
-
-def parse_sharding(repr_str: Optional[str]) -> Optional[ShardingView]:
-    """ShardingView for a banked NamedSharding repr; None for anything
-    else (null, SingleDeviceSharding, unparseable) — callers skip those
-    leaves rather than guess."""
-    if not repr_str or "NamedSharding" not in repr_str:
-        return None
-    mm = _MESH_RE.search(repr_str)
-    sm = _SPEC_RE.search(repr_str)
-    if not mm or not sm:
-        return None
-    mesh = tuple(
-        (name, int(size)) for name, size in _MESH_AXIS_RE.findall(mm.group(1))
-    )
-    return ShardingView(mesh=mesh, spec=_parse_spec_body(sm.group(1)))
 
 
 # --------------------------------------------------------- program views

@@ -7,7 +7,6 @@ Subcommands:
   train      — run the jitted SPMD trainer (--telemetry enables the
                span-trace/health/watchdog observability layer)
   eval       — run inference + VOC mAP over a dataset split
-  bench      — train-step throughput (same measurement as bench.py)
   telemetry  — summarize a --telemetry run dir (phase times + health)
 
 ``--config`` selects one of the five BASELINE presets (config.CONFIGS);
@@ -845,37 +844,6 @@ def cmd_quantize(args) -> int:
             indent=2,
         )
     )
-    return 0
-
-
-def cmd_bench(args) -> int:
-    _apply_device(args.device)
-    from replication_faster_rcnn_tpu.benchmark import main as bench_main
-
-    # pass flag overrides through; None keeps the flagship default setup
-    flagged = any(
-        v is not None
-        for v in (
-            args.dataset, args.data_root, args.image_size, args.backbone,
-            args.roi_op, args.batch_size, args.lr, args.epochs, args.seed,
-            args.num_model, args.mesh_shape, args.backend, args.mu_dtype,
-            args.loader_workers,
-            args.loader_mode, args.augment_scale, args.norm,
-            args.steps_per_dispatch, args.grad_allreduce_dtype,
-            args.nonfinite_policy, args.max_consecutive_skips,
-            args.prefetch_device, args.compile_cache,
-        )
-    ) or (
-        args.spatial or args.remat or args.shard_opt or args.augment_hflip
-        or args.frozen_bn or args.augment_scale_device
-        or getattr(args, "augment_device", False)
-        or getattr(args, "augment_translate", None) is not None
-        or args.no_augment_hflip or args.cache_ram or args.device_normalize
-        or getattr(args, "cache_device", False)
-        or args.async_checkpoint
-        or args.config != "voc_resnet18"
-    )
-    bench_main(_build_config(args) if flagged else None, profile_dir=args.profile)
     return 0
 
 
@@ -1752,13 +1720,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                              "forward, candidates merged before NMS "
                              "(~2x eval compute for a small mAP gain)")
     p_eval.set_defaults(fn=cmd_eval)
-
-    p_bench = sub.add_parser("bench", help="train-step throughput")
-    _add_common(p_bench)
-    p_bench.add_argument("--profile", default=None, metavar="DIR",
-                         help="write a jax.profiler trace of the timed "
-                              "loop (TensorBoard/Perfetto)")
-    p_bench.set_defaults(fn=cmd_bench)
 
     p_warm = sub.add_parser(
         "warmup",

@@ -1364,8 +1364,7 @@ def get_config(name: str = "voc_resnet18", **overrides) -> FasterRCNNConfig:
 
 def config_from_dict(d: dict) -> FasterRCNNConfig:
     """Rebuild a :class:`FasterRCNNConfig` from ``dataclasses.asdict``
-    output, e.g. after a JSON round-trip (lists re-become tuples). Used to
-    ship a config to a subprocess (benchmark FLOPs analysis)."""
+    output, e.g. after a JSON round-trip (lists re-become tuples)."""
     import typing
 
     def deep_tuple(v):

@@ -47,12 +47,12 @@ def _norm(
     pmean over that mesh axis, matching what jit auto-partitioning
     computes on a globally-sharded batch.
 
-    ``kind='group'``: GroupNorm(32) — the BN-free structural lever from
-    the MFU attribution (STAGE_BREAKDOWN.md: the measured-vs-ceiling gap
-    ranking tracks BatchNorm density; train-mode BN's batch-stats
-    reductions are fusion breaks + HBM round-trips XLA cannot elide,
-    while GN normalizes within each sample — no mutable state, no
-    cross-batch coupling, shard-invariant by construction). Parameter
+    ``kind='group'``: GroupNorm(32) — the BN-free structural lever:
+    train-mode BN's batch-stats reductions are fusion breaks + HBM
+    round-trips XLA cannot elide (bn1's backward alone is 1.95 ms of the
+    75.75 ms step: PERF.md section 5), while GN normalizes within each
+    sample — no mutable state, no cross-batch coupling, shard-invariant
+    by construction. Parameter
     names stay at the BN sites' names (scale/bias under e.g. 'bn1') so
     the tree layout is stable; there are no running statistics, so
     torch-pretrained BN checkpoints do NOT convert onto a GN model."""

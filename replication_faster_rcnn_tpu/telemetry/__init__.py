@@ -8,9 +8,9 @@ Four pillars, each usable on its own:
 - :mod:`.health` — on-device train-health metrics (grad/param/update
   norms, update ratio, non-finite counts) folded into the jitted step so
   they ride the existing metrics sync instead of adding one.
-- :mod:`.mfu` — model FLOPs utilisation from the step FLOPs the bench
-  already derives, with a measured-matmul CPU peak so MFU is non-null
-  even off-TPU.
+- :mod:`.mfu` — model FLOPs utilisation from a step's analytical
+  FLOPs, with a measured-matmul CPU peak so MFU is non-null even
+  off-TPU; the device record every reported number carries.
 - :mod:`.watchdog` — heartbeat daemon that detects a stalled run and
   dumps a diagnostic snapshot (last span, queue depth,
   elapsed-since-progress) instead of leaving a hung process to guess at.

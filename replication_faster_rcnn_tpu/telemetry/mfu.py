@@ -1,9 +1,9 @@
 """Model FLOPs utilisation (MFU).
 
 MFU = achieved FLOP/s / peak FLOP/s, the canonical "is the chip or the
-feed the bottleneck" number. Achieved FLOP/s comes from the per-step
-analytical FLOPs the bench already derives (XLA HloCostAnalysis of the
-lowered step) times steps/sec; peak comes from one of two bases:
+feed the bottleneck" number. Achieved FLOP/s comes from the caller's
+per-step analytical FLOPs times steps/sec; peak comes from one of two
+bases:
 
 - ``tpu_datasheet`` — published per-chip bf16 peaks times device count,
   keyed off the runtime's own ``device_kind`` string. A TPU whose kind
@@ -44,6 +44,18 @@ def device_record() -> dict:
         "kind": devs[0].device_kind,
         "count": len(devs),
     }
+
+
+def require_accelerator(who: str) -> dict:
+    """The :func:`device_record`, or exit non-zero when JAX found no
+    accelerator: a measurement entry point never carries on on the CPU."""
+    device = device_record()
+    if device["platform"] == "cpu":
+        raise SystemExit(
+            f"{who}: no accelerator — jax.devices()[0].platform is 'cpu' "
+            f"({device['count']} device(s)); nothing was measured"
+        )
+    return device
 
 
 def tpu_peak_flops_per_sec(device_kind: str, n_dev: int) -> float:

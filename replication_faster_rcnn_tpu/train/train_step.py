@@ -126,8 +126,6 @@ def compute_losses(
     train: bool = True,
     axis_name: str = None,
     positions: Array = None,
-    features_wall: bool = False,
-    targets_only: bool = False,
     train_resolution=None,
 ) -> Tuple[Array, Tuple[Dict[str, Array], Any]]:
     """Forward + 4 losses. Returns (total, (metrics, new_batch_stats)).
@@ -136,18 +134,6 @@ def compute_losses(
     (`parallel/spmd.py`): loss normalizers psum over the axis, per-image
     sampling keys fold in the global batch position so the objective and
     randomness match the jit auto-partitioned path exactly.
-
-    ``features_wall`` stops gradients at the trunk/neck features, so a
-    grad of this loss excludes the whole trunk backward. Diagnostics
-    only (`benchmarks/grad_breakdown.py` uses the full-vs-walled time
-    difference to attribute backward cost on hardware); never set in
-    training.
-
-    ``targets_only`` returns right after the second-stage target
-    creators with a scalar probe consuming their outputs (empty
-    metrics) — the bench's `targets_ms` stage prefix, kept inside this
-    function so the timed prefix can't drift from the real step.
-    Diagnostics only.
 
     ``train_resolution`` (STATIC ``(h, w)`` or None) is one multi-scale
     training bucket (data.train_resolutions): the batch arrives at the
@@ -183,8 +169,6 @@ def compute_losses(
             variables, images, train, method="extract_features",
             mutable=["batch_stats"],
         )
-    if features_wall:
-        feat = jax.tree_util.tree_map(jax.lax.stop_gradient, feat)
     with jax.named_scope(stages.RPN):
         logits, deltas, anchors = model.apply(
             variables, feat, method="rpn_forward"
@@ -211,12 +195,6 @@ def compute_losses(
             config.roi_targets, positions,
             strategy=config.train.sampling_strategy,
         )
-    if targets_only:
-        probe = (
-            reg_t.sum() + lab_t.sum() + sample_rois.sum()
-            + reg_t2.sum() + lab_t2.sum()
-        ).astype(jnp.float32)
-        return probe, ({}, mut.get("batch_stats", {}))
 
     # head on the sampled rois (BN in the tail also updates; the VGG16
     # tail's dropout draws from the 'dropout' rng in train mode)

@@ -559,6 +559,39 @@ def build_int8_program_specs(
     return specs
 
 
+def abstract_step_inputs(cfg, tx):
+    """(model, state_abs, batch_abs): abstract fixtures of one train step
+    — shapes/dtypes only, no arrays allocated, no param-init programs run
+    (a pure trace). Shared by the program registry below and the static
+    cost scripts (`benchmarks/step_profile.py`, `layer_cost_table.py`) so
+    they can never analyze different shapes."""
+    from replication_faster_rcnn_tpu.data import SyntheticDataset
+    from replication_faster_rcnn_tpu.data.loader import collate
+    from replication_faster_rcnn_tpu.models.faster_rcnn import FasterRCNN
+    from replication_faster_rcnn_tpu.train import create_train_state
+
+    model = FasterRCNN(cfg)
+    state_abs = jax.eval_shape(
+        lambda rng: create_train_state(cfg, rng, tx)[1], jax.random.PRNGKey(0)
+    )
+    sample = collate([SyntheticDataset(cfg.data, length=1)[0]])
+    b = cfg.train.batch_size
+    batch_abs = {
+        k: jax.ShapeDtypeStruct((b,) + v.shape[1:], v.dtype)
+        for k, v in sample.items()
+    }
+    if cfg.data.augment_device and (
+        cfg.data.augment_hflip
+        or cfg.data.augment_scale
+        or cfg.data.augment_translate
+    ):
+        # device-mode augmentation ships an int32 (idx, epoch) row per
+        # sample (data/augment.py::AugmentTagView) — the fixture must
+        # carry it so warmup/audit lower the runtime trace, not a twin
+        batch_abs["aug"] = jax.ShapeDtypeStruct((b, 2), np.int32)
+    return model, state_abs, batch_abs
+
+
 def build_program_specs(
     config: FasterRCNNConfig,
     feeds: Sequence[str] = ("loader",),
@@ -580,7 +613,6 @@ def build_program_specs(
     for cached-feed programs (default: two batches — the cache length is
     a free shape parameter, and fingerprints pin it).
     """
-    from replication_faster_rcnn_tpu.benchmark import abstract_step_inputs
     from replication_faster_rcnn_tpu.parallel import (
         batch_sharding,
         image_sharding,

@@ -1,13 +1,12 @@
 """Minimal XSpace/XPlane trace reader — op-level time attribution from
 ``jax.profiler.trace`` output with zero external tooling.
 
-SURVEY.md §5 "tracing/profiling": the bench already records per-stage
-wall times (`benchmark.py::_stage_breakdown`); this module turns a
-captured trace (``<dir>/plugins/profile/*/\\*.xplane.pb``) into a per-op
-table so the backward/update stages can be attributed at the XLA-op
-level (VERDICT r3 #2). The image's tensorboard profile plugin cannot do
-this (its generated protos predate the installed protobuf and fail to
-import), so the stable xplane wire format is decoded directly: a
+SURVEY.md §5 "tracing/profiling": this module turns a captured trace
+(``<dir>/plugins/profile/*/\\*.xplane.pb``) into a per-op table, so a
+``train --profile`` run can be attributed at the XLA-op level from the
+command line (``cli trace-summary``, `telemetry/report.py`). The
+image's tensorboard profile plugin cannot do this (its generated protos
+predate the installed protobuf and fail to import), so the stable xplane wire format is decoded directly: a
 ~60-line protobuf wire reader plus a walker for the four message types
 the table needs. Schema (field numbers are stable across TF/TSL/JAX):
 

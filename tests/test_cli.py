@@ -76,118 +76,6 @@ class TestEvalSmoke:
         assert "aeroplane" in out  # per-class table rendered with VOC names
 
 
-def _bench_cfg(argv):
-    return cli._build_config(_args(argv))
-
-
-class TestBenchSuccess:
-    """`cli bench` itself needs a chip (TestBenchNeedsAChip); the record
-    the measurement functions build is checked here at a tiny size."""
-
-    @pytest.mark.slow
-    def test_measure_train_record(self):
-        from replication_faster_rcnn_tpu import benchmark
-
-        line = benchmark.measure_train(
-            _bench_cfg(["--image-size", "64", "--batch-size", "8"])
-        )
-        assert line["metric"] == "train_images_per_sec_64x64"
-        assert line["value"] > 0
-        assert "error" not in line
-        # the record carries the step's FLOPs and a per-stage wall-time
-        # attribution; off-TPU the peak comes from the measured-matmul
-        # basis and is labelled as such
-        assert line["flops_per_step"] > 0
-        assert line["mfu"] is not None and line["mfu"] > 0
-        assert line["mfu_basis"] == "cpu_measured_matmul"
-        bd = line["breakdown"]
-        assert bd["trunk_ms"] > 0 and bd["step_ms"] > 0
-        required = {
-            "trunk_ms", "rpn_heads_ms", "proposal_nms_ms",
-            "targets_ms", "head_loss_ms",
-            "targets_head_loss_ms", "backward_ms", "opt_update_ms",
-            "backward_update_ms", "step_ms",
-        }
-        # the direct optimizer-update row and its dispatch-floor
-        # companions accompany the core keys (a failed row fails the run)
-        assert set(bd) - required == {
-            "opt_update_direct_ms", "dispatch_floor_ms",
-            "opt_update_direct_adj_ms",
-        }
-        # the split must account for the lump it replaces
-        assert bd["backward_update_ms"] == pytest.approx(
-            bd["backward_ms"] + bd["opt_update_ms"], abs=0.05
-        )
-
-    @pytest.mark.slow
-    def test_measure_eval_record(self):
-        """The eval measurement covers the inference path (forward +
-        decode + per-class NMS) and reports no baseline ratio (the
-        reference has no eval path to race — SURVEY.md §2.1 #15)."""
-        from replication_faster_rcnn_tpu import benchmark
-
-        # no BENCH_EVAL_BATCH: exercise the second precedence tier (the
-        # CLI config's train.batch_size feeds the eval batch)
-        line = benchmark.measure_eval(
-            _bench_cfg(["--image-size", "64", "--batch-size", "2"])
-        )
-        assert line["metric"] == "eval_images_per_sec_64x64"
-        assert line["value"] > 0
-        assert line["vs_baseline"] is None
-        assert "error" not in line
-
-
-class TestBenchMeshValidation:
-    """ADVICE r1 #3: bad --num-model must fail fast with a descriptive
-    error, not an opaque mesh reshape failure (or silent device drop)."""
-
-    def test_num_model_exceeding_devices(self):
-        from replication_faster_rcnn_tpu import benchmark
-
-        with pytest.raises(ValueError, match="exceeds the 8 available"):
-            benchmark.measure_train(_bench_cfg(
-                ["--num-model", "16", "--image-size", "64", "--batch-size", "8"]
-            ))
-
-    def test_num_model_not_dividing_devices(self):
-        from replication_faster_rcnn_tpu import benchmark
-
-        with pytest.raises(ValueError, match="split evenly"):
-            benchmark.measure_train(_bench_cfg(
-                ["--num-model", "3", "--image-size", "64", "--batch-size", "8"]
-            ))
-
-
-class TestBenchNeedsAChip:
-    """The bench has no path that measures on the CPU instead: with no
-    accelerator it exits non-zero and prints no metric line."""
-
-    def test_cli_bench_without_a_chip_exits_nonzero_and_prints_no_metric(
-        self, capsys
-    ):
-        with pytest.raises(SystemExit) as exc:
-            cli.main(["bench", "--image-size", "64", "--batch-size", "8"])
-        assert exc.value.code not in (0, None)
-        assert "no accelerator" in str(exc.value.code)
-        assert "cpu" in str(exc.value.code)  # says what it found
-        assert "metric" not in capsys.readouterr().out
-
-    def test_bench_py_without_a_chip_exits_nonzero_and_prints_no_metric(self):
-        import os
-        import subprocess
-        import sys
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        r = subprocess.run(
-            [sys.executable, "bench.py"], cwd=repo, capture_output=True,
-            text=True, timeout=300,
-            env={**os.environ, "JAX_PLATFORMS": "cpu"},
-        )
-        assert r.returncode != 0
-        assert r.stdout.strip() == ""  # no JSON line, no number
-        assert "no accelerator" in r.stderr
-
-
 class TestTrainSmoke:
     @pytest.mark.slow
     def test_bounded_steps(self, tmp_path, capsys):

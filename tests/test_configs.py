@@ -60,7 +60,7 @@ def test_preset_one_train_step(name):
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_config_json_round_trip(name):
     """config_from_dict rebuilds every preset exactly after a JSON round
-    trip (the path bench.py uses to ship a config to its FLOPs subprocess)."""
+    trip."""
     import json
 
     from replication_faster_rcnn_tpu.config import config_from_dict

@@ -328,24 +328,6 @@ class TestCLISurfaces:
         )
         assert rc == 0
 
-    @pytest.mark.slow
-    def test_bench_cache_device_measures_cached_step(self, capsys):
-        """bench --cache-device must time the cached step (and say so by
-        skipping the fed-graph stage breakdown), not silently bench the
-        fed path under a cache_device label."""
-        import json
-
-        from replication_faster_rcnn_tpu import cli
-
-        rc = cli.main(
-            ["bench", "--cache-device", "--image-size", "64",
-             "--batch-size", "4"]
-        )
-        assert rc == 0
-        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert line["value"] > 0
-        assert "cache-device" in line["breakdown"]["note"]
-
 
 @pytest.mark.slow
 class TestCachedStepDP8:

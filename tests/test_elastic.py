@@ -106,7 +106,7 @@ class TestElasticAgent:
         assert seen == [([1], [0])]
         assert a0.check() == [1]
 
-    def test_drop_failpoint_targets_only_its_rank(self, tmp_path):
+    def test_drop_failpoint_hits_only_its_rank(self, tmp_path):
         from replication_faster_rcnn_tpu.faultlib import failpoints
 
         now = [0.0]

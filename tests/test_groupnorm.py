@@ -1,7 +1,6 @@
 """GroupNorm backbone option (`ModelConfig.norm="group"`): the BN-free
-structural lever from the MFU attribution (STAGE_BREAKDOWN.md — the
-measured-vs-ceiling gap ranking tracks BatchNorm density; GN removes the
-batch-stats reductions entirely). Reference parity note: the reference is
+structural lever (GN removes train-mode BN's batch-stats reductions
+entirely). Reference parity note: the reference is
 BN-only (`nets/resnet_torch.py`); GN is a deliberate TPU-side extension.
 """
 

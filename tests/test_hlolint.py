@@ -542,6 +542,57 @@ class TestDriftRules:
         assert [v.rule for v in viols] == ["HX005"]
         assert viols[0].program == "p"
 
+    # one leaf's sharding as the bank's jax printed it and as jax 0.9.0 does
+    BANKED_REPR = (
+        "NamedSharding(mesh=Mesh('data': 2, 'model': 1), "
+        "spec=PartitionSpec('data',), memory_kind=unpinned_host)"
+    )
+    LIVE_REPR = (
+        "NamedSharding(mesh=Mesh('data': 2, 'model': 1, "
+        "axis_types=(Auto, Auto)), spec=PartitionSpec('data',), "
+        "memory_kind=device)"
+    )
+
+    @staticmethod
+    def _with_leaf(sharding, shape=(2, 8, 4)):
+        leaf = {
+            "path": "['boxes']", "shape": list(shape), "dtype": "float32",
+            "sharding": sharding,
+        }
+        return _fp(args={"batch": [leaf]})
+
+    def _drift(self, cur):
+        bank = fp_mod.make_bank(
+            {"p": self._with_leaf(self.BANKED_REPR)}, "cpu", 8, {}
+        )
+        return hlolint.check_drift(
+            {"p": cur}, bank, "f", self.EXPECTED, "cpu", 8
+        )
+
+    def test_how_jax_prints_a_sharding_is_no_drift(self):
+        assert self.BANKED_REPR != self.LIVE_REPR
+        assert self._drift(self._with_leaf(self.LIVE_REPR)) == []
+
+    @pytest.mark.parametrize(
+        "sharding, shape",
+        [
+            pytest.param(
+                LIVE_REPR.replace("PartitionSpec('data',)", "PartitionSpec()"),
+                (2, 8, 4), id="spec",
+            ),
+            pytest.param(
+                LIVE_REPR.replace("'data': 2", "'data': 4"), (2, 8, 4),
+                id="axis_size",
+            ),
+            pytest.param(LIVE_REPR, (4, 8, 4), id="leaf_shape"),
+            pytest.param(None, (2, 8, 4), id="sharding_gone"),
+        ],
+    )
+    def test_what_a_sharding_is_still_drifts(self, sharding, shape):
+        [v] = self._drift(self._with_leaf(sharding, shape))
+        assert v.rule == "HX005" and v.program == "p"
+        assert "args changed vs bank" in v.message
+
 
 # ----------------------------------------------------------- the package gate
 

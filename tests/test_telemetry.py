@@ -31,6 +31,7 @@ from replication_faster_rcnn_tpu.telemetry.mfu import (
     compute_mfu,
     measured_cpu_peak_flops_per_sec,
     peak_flops_per_sec,
+    require_accelerator,
     tpu_peak_flops_per_sec,
 )
 from replication_faster_rcnn_tpu.telemetry.report import (
@@ -232,7 +233,7 @@ class TestMFU:
 
     def test_cpu_backend_peak_is_measured_and_nonnull(self):
         """On the CPU test backend the peak must come from the measured
-        matmul basis — this is what makes bench mfu non-null off-TPU."""
+        matmul basis — this is what makes step_profile's mfu non-null off-TPU."""
         peak, basis = peak_flops_per_sec()
         assert basis == "cpu_measured_matmul"
         assert peak is not None and peak > 0
@@ -240,6 +241,14 @@ class TestMFU:
     def test_env_override(self, monkeypatch):
         monkeypatch.setenv("FRCNN_CPU_PEAK_FLOPS", "123e9")
         assert measured_cpu_peak_flops_per_sec() == pytest.approx(123e9)
+
+    def test_a_measurement_entry_point_never_carries_on_on_the_cpu(self, capsys):
+        with pytest.raises(SystemExit) as exc:
+            require_accelerator("some_on_chip_script")
+        assert exc.value.code not in (0, None)
+        assert "no accelerator" in str(exc.value.code)
+        assert "cpu" in str(exc.value.code)  # says what it found
+        assert capsys.readouterr().out == ""
 
 
 class TestHealthMetrics:

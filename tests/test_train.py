@@ -318,78 +318,6 @@ class TestTrainStep:
         assert loss < 0.7 * first, (first, loss)
 
 
-class TestFeaturesWall:
-    """compute_losses(features_wall=True) — the grad_breakdown diagnostic."""
-
-    def test_wall_zeroes_trunk_grads_only(self):
-        from replication_faster_rcnn_tpu.train.train_step import compute_losses
-
-        cfg = _tiny_cfg()
-        tx, _ = make_optimizer(cfg, steps_per_epoch=10)
-        model, state = create_train_state(cfg, jax.random.PRNGKey(0), tx)
-        ds = SyntheticDataset(cfg.data, length=2)
-        batch = collate([ds[i] for i in range(2)])
-        rng = jax.random.PRNGKey(1)
-
-        def grads(wall):
-            def loss_fn(params):
-                return compute_losses(
-                    model, cfg, params, state.batch_stats, batch, rng, True,
-                    features_wall=wall,
-                )
-
-            return jax.grad(lambda p: loss_fn(p)[0])(state.params)
-
-        g_wall = grads(True)
-        g_full = grads(False)
-        trunk_norm_wall = float(
-            sum(jnp.abs(x).sum() for x in jax.tree_util.tree_leaves(g_wall["trunk"]))
-        )
-        trunk_norm_full = float(
-            sum(jnp.abs(x).sum() for x in jax.tree_util.tree_leaves(g_full["trunk"]))
-        )
-        head_norm_wall = float(
-            sum(jnp.abs(x).sum() for x in jax.tree_util.tree_leaves(g_wall["head"]))
-        )
-        assert trunk_norm_wall == 0.0  # the wall cuts the trunk backward
-        assert trunk_norm_full > 0.0
-        assert head_norm_wall > 0.0  # head/rpn backward still runs
-
-    @pytest.mark.slow  # compiles six full/partial train graphs (~5 min on
-    # one CPU core — a third of the fast tier's whole wall-clock budget);
-    # the fast tier keeps the in-process wall semantics test above
-    def test_grad_breakdown_script_cpu(self, tmp_path, monkeypatch):
-        # end-to-end at tiny shape on CPU (GRAD_BREAKDOWN_CPU gate)
-        import importlib.util
-        import pathlib
-
-        monkeypatch.setenv("GRAD_BREAKDOWN_CPU", "1")
-        script = (
-            pathlib.Path(__file__).resolve().parents[1]
-            / "benchmarks"
-            / "grad_breakdown.py"
-        )
-        spec = importlib.util.spec_from_file_location("grad_breakdown", script)
-        gb = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(gb)
-        monkeypatch.setattr(gb, "OUT", str(tmp_path / "gb.json"))
-        monkeypatch.setattr(
-            "sys.argv",
-            ["grad_breakdown.py", "--config", "voc_resnet18",
-             "--batch-size", "2", "--image-size", "64", "64"],
-        )
-        gb.main()
-        import json as _json
-
-        out = _json.load(open(tmp_path / "gb.json"))
-        rows = out["rows"]
-        for k in ("trunk_train_ms", "trunk_eval_ms",
-                  "fwd_ms", "grad_wall_ms", "grad_imgs_ms", "grad_full_ms",
-                  "attrib_trunk_backward_ms", "attrib_all_wgrads_ms"):
-            assert k in rows
-        assert rows["grad_full_ms"] > 0
-
-
 class TestLayerCostTable:
     def _load(self):
         import importlib.util
