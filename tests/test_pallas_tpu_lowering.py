@@ -1,5 +1,6 @@
-"""Every `ops/pallas/` kernel must lower for the TPU at the shapes the
-`voc_resnet18` step and the 600x600 serve program use.
+"""Every `ops/pallas/` kernel, and the stem's two of `ops/pool_ops.py`, must
+lower for the TPU at the shapes the `voc_resnet18` step and the 600x600 serve
+program use.
 
 The Pallas -> Mosaic lowering is Python and runs on any host
 (``lowering_platforms=("tpu",)``), so whatever it refuses — an
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from replication_faster_rcnn_tpu.ops import pool_ops
 from replication_faster_rcnn_tpu.ops.pallas import (
     dequantize_pallas,
     iou_matrix_pallas,
@@ -49,7 +51,24 @@ def _roi(f, r):
     return roi_align_pallas(f, r, 7, 2, 1 / 16.0, interpret=False)
 
 
+def _stem(train):
+    """conv1's map at 600x600 through the stem's norm + ReLU + max-pool:
+    the forward alone (serving), and with its hand-written backward."""
+    pool = lambda y, *terms: pool_ops.norm_relu_max_pool(y, *terms, jnp.bfloat16)
+    if not train:
+        return pool
+    return jax.grad(lambda *a: jnp.sum(pool(*a).astype(F32)), argnums=(0, 1, 2, 3))
+
+
+def _stem_args(batch, per_sample=False):
+    term = S((batch if per_sample else 1, 1, 1, 64), F32)
+    return (S((batch, 300, 300, 64), jnp.bfloat16), term, term, term)
+
+
 CASES = {
+    "stem_pool_train": (_stem(True), _stem_args(32)),
+    "stem_pool_train_group_norm": (_stem(True), _stem_args(B, per_sample=True)),
+    "stem_pool_serve": (_stem(False), _stem_args(8)),
     # proposal NMS, train (12000 -> 600, sorted) alone and under the vmap
     "nms_train": (_nms(600, False), (S((12000, 4), F32), S((12000,), F32))),
     "nms_train_vmap": (
@@ -99,7 +118,9 @@ CASES = {
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_kernel_lowers_for_tpu_at_step_shapes(name):
+def test_kernel_lowers_for_tpu_at_step_shapes(name, monkeypatch):
+    # the stem's kernels ask the backend, which is the CPU here
+    monkeypatch.setattr(pool_ops, "_interpret", lambda: False)
     fn, args = CASES[name]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
     # a Mosaic kernel, not an interpreted loop nest

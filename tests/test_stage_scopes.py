@@ -134,6 +134,20 @@ class TestStageScopes:
         strays = [n for n in ops if "jit(roi_pool)" in n and stages.ROI_POOL not in n]
         assert not strays, strays[:3]
 
+    def test_stem_pool_custom_vjp_keeps_the_scope_both_ways(self, jit_step):
+        """The stem's norm + ReLU + max-pool is two kernels with a
+        hand-written backward (`ops/pool_ops.py`): the forward must read
+        `jvp(frcnn.trunk)` and the backward `transpose(jvp(frcnn.trunk))`,
+        or `stage_trunk_ms` would lose them to the unscoped time."""
+        names = _op_names(jit_step)
+        forward = [n for n in names if "/stem_pool/" in n]
+        backward = [n for n in names if "/stem_pool_backward/" in n]
+        assert forward and backward
+        for n in forward:
+            assert f"jvp({stages.TRUNK})" in n and "transpose(" not in n, n
+        for n in backward:
+            assert f"transpose(jvp({stages.TRUNK}))" in n, n
+
     def test_scopes_change_no_instruction(self, jit_step, monkeypatch):
         """The step lowered with `jax.named_scope` patched to a null context
         is the same program, locations (the later `metadata={...}`) apart."""
