@@ -209,6 +209,7 @@ def expected_program_names(
     TRAIN bucket programs (data.train_resolutions × every feed × ks)
     and the ops.backend=pallas twin programs are included."""
     from replication_faster_rcnn_tpu.train.warmup import (
+        LM_PROGRAM,
         bucket_train_program_names,
         int8_program_names,
         pallas_program_name,
@@ -227,6 +228,7 @@ def expected_program_names(
             pallas_program_name(b) for b in pallas_twin_base_names(config)
         )
         names.extend(int8_program_names(config))
+        names.append(LM_PROGRAM)
     return names
 
 
@@ -241,6 +243,7 @@ def collect_fingerprints(
     over the returned dicts."""
     from replication_faster_rcnn_tpu.train.warmup import (
         build_int8_program_specs,
+        build_lm_program_specs,
         build_pallas_program_specs,
         build_program_specs,
         build_serving_specs,
@@ -254,6 +257,7 @@ def collect_fingerprints(
         **build_serving_specs(config),
         **build_pallas_program_specs(config),
         **build_int8_program_specs(config),
+        **build_lm_program_specs(),
     }
     if programs is None:
         wanted = list(specs)

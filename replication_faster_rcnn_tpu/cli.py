@@ -233,7 +233,7 @@ def _build_config(args):
 
 
 def _add_common(p: argparse.ArgumentParser) -> None:
-    p.add_argument("--config", default="voc_resnet18",
+    p.add_argument("--config", "--preset", default="voc_resnet18",
                    help="preset name (see replication_faster_rcnn_tpu.config.CONFIGS)")
     p.add_argument("--device", default="auto", choices=["auto", "tpu", "cpu"],
                    help="JAX backend (BASELINE --device flag)")
@@ -717,6 +717,7 @@ def cmd_eval(args) -> int:
     from replication_faster_rcnn_tpu.train.trainer import load_eval_variables
 
     cfg = _build_config(args)
+    cfg.require_detector("eval")
     from replication_faster_rcnn_tpu.train.warmup import place_compile_cache
 
     place_compile_cache(cfg.compile.cache_dir)
@@ -787,6 +788,7 @@ def cmd_quantize(args) -> int:
     from replication_faster_rcnn_tpu.train.trainer import load_eval_variables
 
     cfg = _build_config(args)
+    cfg.require_detector("quantize")
     q = cfg.quant
     if args.calib_batches is not None:
         q = _dc.replace(q, calib_batches=args.calib_batches)
@@ -862,6 +864,7 @@ def cmd_warmup(args) -> int:
     )
 
     cfg = _build_config(args)
+    cfg.require_detector("warmup")
     cache_path = place_compile_cache(cfg.compile.cache_dir)
     tracer = None
     if args.telemetry:
@@ -902,6 +905,7 @@ def cmd_predict(args) -> int:
     from replication_faster_rcnn_tpu.train.trainer import load_eval_variables
 
     cfg = _build_config(args)
+    cfg.require_detector("predict")
     model, variables = load_eval_variables(cfg, args.workdir, args.checkpoint_step)
     paths = list(args.image)
     # all paths go through the serving engine as one submission wave, so
@@ -960,6 +964,7 @@ def _cmd_serve_impl(args) -> int:
     from replication_faster_rcnn_tpu.train.warmup import place_compile_cache
 
     cfg = _build_config(args)
+    cfg.require_detector("serve")
     serving = cfg.serving
     if args.max_delay_ms is not None:
         serving = _dc.replace(serving, max_delay_ms=args.max_delay_ms)
@@ -1399,6 +1404,7 @@ def cmd_viz(args) -> int:
     plot and `utils/data_loader.py:119-134` gt overlay, as a real command)."""
     _apply_device(args.device)
     cfg = _build_config(args)
+    cfg.require_detector("viz")
     from replication_faster_rcnn_tpu.utils import viz
 
     if args.what == "anchors":

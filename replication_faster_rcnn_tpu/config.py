@@ -209,7 +209,10 @@ class DataConfig:
     """Data pipeline (reference `utils/data_loader.py:17-117`)."""
 
     root_dir: str = "data/voc/VOCdevkit/VOC2012"
-    dataset: str = "voc"  # voc | coco | synthetic
+    dataset: str = "voc"  # voc | coco | synthetic | tokens (a sequence model's rows)
+    # tokens a packed row holds (data/tokens.py); read by the sequence model
+    # alone, as `image_size` is by the detectors
+    seq_len: int = 8192
     image_size: Tuple[int, int] = (600, 600)
     max_boxes: int = 32
     use_difficult: bool = False
@@ -1233,6 +1236,64 @@ class RolloutConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LMConfig:
+    """The sequence model's sizes (`models/lm.py`) and the share of them
+    this chip holds.
+
+    A decoder of pre-norm layers: rotary grouped-query attention, windowed
+    or full by `layer_types`, then a dense SwiGLU in the leading
+    `num_dense_layers` and a routed expert layer after them (sigmoid router
+    over `num_experts`, the top `experts_per_token` by score + balance bias,
+    their scores normalised and scaled by `route_scale`, one shared expert).
+    Empty `layer_types` means the config is no sequence model's.
+
+    The share. A layer is divided over `num_experts / experts_held` chips by
+    expert parallelism: this chip holds experts `first_expert ..
+    first_expert + experts_held - 1` and `vocab_rows` rows of the embedding
+    and of the output head. The router still scores all `num_experts`; the
+    weighted sum runs over the chosen experts that are held, and that partial
+    result goes on. Token ids are drawn from the rows held. With
+    `experts_held == num_experts` the model is whole.
+    """
+
+    vocab_rows: int = 25_024  # rows of the tables held here
+    hidden_size: int = 2048
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_size: int = 128
+    sliding_window: int = 2048
+    layer_types: Tuple[str, ...] = ()  # "sliding_attention" | "full_attention"
+    num_dense_layers: int = 1
+    dense_width: int = 6144
+    expert_width: int = 1024
+    num_experts: int = 128  # the router's width
+    experts_per_token: int = 8
+    route_scale: float = 2.826
+    load_balance_coeff: float = 0.001
+    rope_theta: float = 10_000.0
+    rms_norm_eps: float = 1e-5
+    experts_held: int = 16
+    first_expert: int = 0
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        kinds = ("sliding_attention", "full_attention")
+        if any(t not in kinds for t in self.layer_types):
+            raise ValueError(f"lm.layer_types entries must be of {kinds}, got {self.layer_types!r}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"lm.num_heads={self.num_heads} must be a multiple of lm.num_kv_heads={self.num_kv_heads}"
+            )
+        if not 0 <= self.first_expert <= self.first_expert + self.experts_held <= self.num_experts:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the router's {self.num_experts}"
+            )
+        if not 1 <= self.experts_per_token <= self.num_experts:
+            raise ValueError(f"lm.experts_per_token={self.experts_per_token} of {self.num_experts} experts")
+
+
+@dataclasses.dataclass(frozen=True)
 class FasterRCNNConfig:
     anchors: AnchorConfig = dataclasses.field(default_factory=AnchorConfig)
     proposals: ProposalConfig = dataclasses.field(default_factory=ProposalConfig)
@@ -1255,6 +1316,33 @@ class FasterRCNNConfig:
         default_factory=TelemetryConfig
     )
     rollout: RolloutConfig = dataclasses.field(default_factory=RolloutConfig)
+    lm: LMConfig = dataclasses.field(default_factory=LMConfig)
+
+    def __post_init__(self):
+        if self.is_sequence_model:
+            if not self.lm.layer_types:
+                raise ValueError("data.dataset='tokens' needs lm.layer_types: the sequence model's layers")
+            if self.train.backend != "auto" or self.data.cache_device or self.mesh.param_sharding:
+                raise ValueError(
+                    "a sequence model trains under train.backend='auto' from the loader, its parameters "
+                    "whole on every chip: the spmd backend, the device cache and mesh.param_sharding "
+                    "are the detectors'"
+                )
+
+    @property
+    def is_sequence_model(self) -> bool:
+        """Which kind of model the config trains: the decoder of
+        `models/lm.py` over rows of tokens, else a detector over images."""
+        return self.data.dataset == "tokens"
+
+    def require_detector(self, what: str) -> None:
+        """Raise where `what` (eval, predict, serve, ...) is asked of a
+        sequence model: only training is written for that kind."""
+        if self.is_sequence_model:
+            raise ValueError(
+                f"{what} is for detectors; this config is a sequence model (data.dataset='tokens'), "
+                "which `cli train` trains and nothing else runs yet"
+            )
 
     def feature_size(self, image_size: Optional[Tuple[int, int]] = None) -> Tuple[int, int]:
         """Spatial size of the stride-16 feature map for a given image size.
@@ -1350,6 +1438,36 @@ CONFIGS = {
             augment_hflip=True,
         ),
         eval=EvalConfig(metric="coco"),
+    ),
+    # 7. One chip's share of Trinity-Mini (arcee-ai, `model_type` afmoe; 26 B
+    #    parameters, 3 B active) trained over eight chips by expert
+    #    parallelism: 16 of 128 experts and 25,024 of 200,192 vocabulary rows
+    #    held here, the router whole; one dense layer and one period of
+    #    expert layers (three windowed, one full), the rest on further
+    #    pipeline stages. Widths as published (perf/configs/trinity_mini_ep8.json
+    #    has the source and every cut). 663.5 M parameters, 10.6 GB trained.
+    "trinity_mini_ep8": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=8192, root_dir=""),
+        # a fine-tuning rate and no L2 term: the catalog row gives no training
+        # recipe, and the detectors' 1e-4 / 5e-6 are the reference detector's.
+        # At 1e-4 Adam moves every router weight by half a percent a step, all
+        # one way: within some forty steps on cycled batches the routers send
+        # most tokens to a few experts (PERF.md section 6, PR 31)
+        train=TrainConfig(batch_size=2, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(layer_types=("sliding_attention",) * 4 + ("full_attention",)),
+    ),
+    # 8. The same layer pattern at sizes a CPU test runs: hidden 64, 4 / 2
+    #    heads of 16, 16 experts of which 4 are held, top-2, window 8, rows
+    #    of 64 tokens over 64 of 256 vocabulary rows.
+    "trinity_tiny": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=64, root_dir=""),
+        train=TrainConfig(batch_size=2, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(
+            vocab_rows=64, hidden_size=64, num_heads=4, num_kv_heads=2, head_size=16,
+            sliding_window=8, layer_types=("sliding_attention",) * 4 + ("full_attention",),
+            dense_width=192, expert_width=32, num_experts=16, experts_per_token=2,
+            experts_held=4,
+        ),
     ),
 }
 

@@ -568,4 +568,8 @@ def make_dataset(cfg, split: str = "train", **kwargs):
         from replication_faster_rcnn_tpu.data.synthetic import SyntheticDataset
 
         return SyntheticDataset(cfg, split, **kwargs)
+    if kind == "tokens":
+        from replication_faster_rcnn_tpu.data.tokens import TokenDataset
+
+        return TokenDataset(cfg, split, **kwargs)
     raise ValueError(f"unknown dataset kind {kind!r}")

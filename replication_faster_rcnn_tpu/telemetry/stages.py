@@ -23,6 +23,29 @@ ROI_POOL = "frcnn.roi_pool"  # ROIPool / ROIAlign / multilevel align inside the 
 BOX_HEAD = "frcnn.box_head"  # the tail, the two heads, select_class_deltas, the head losses
 UPDATE = "frcnn.update"  # gradient exchange and rounding, the guard, the optimizer, health norms
 
+# the sequence model's stages (models/lm.py), under the same prefix so that one
+# trace reduction reads both programs; `frcnn.update` is a stage of both
+LM_EMBED = "frcnn.lm_embed"  # the embedding rows and their multiplier
+LM_ATTENTION = "frcnn.lm_attention"  # norm, q/k/v, rotary embedding, the output projection
+LM_ATTN_CORE = "frcnn.lm_attn_core"  # the attention function alone, inside lm_attention
+LM_FFN = "frcnn.lm_ffn"  # norm, the dense SwiGLU or the shared expert
+LM_ROUTER = "frcnn.lm_router"  # scores, top-k, the sort by expert, counts, the balance bias
+LM_EXPERTS = "frcnn.lm_experts"  # rows gathered by expert, the weighted combine by token
+LM_EXPERT_MM = "frcnn.lm_expert_mm"  # the grouped products alone, inside lm_experts
+LM_HEAD = "frcnn.lm_head"  # last norm, output head, cross-entropy
+
+LM_STAGES = (
+    LM_EMBED,
+    LM_ATTENTION,
+    LM_ATTN_CORE,
+    LM_FFN,
+    LM_ROUTER,
+    LM_EXPERTS,
+    LM_EXPERT_MM,
+    LM_HEAD,
+    UPDATE,
+)
+
 STAGES = (
     INPUT,
     TRUNK,

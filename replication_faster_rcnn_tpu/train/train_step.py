@@ -19,7 +19,7 @@ unweighted, `train.py:123`).
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,11 +49,53 @@ class TrainState(struct.PyTreeNode):
     rng: Array
 
 
+class ModelKind(NamedTuple):
+    """What a kind of model gives the train step; everything around it
+    (`value_and_grad`, `quantize_grads`, `guarded_update`, `TrainState`,
+    donation, the K-step scan) is one writing for every kind.
+
+    ``init(model, config, rng) -> (params, batch_stats)``: the gradient
+    leaves, and the state no gradient reaches (BatchNorm statistics, a
+    router's balance bias). ``losses(model, config, params, batch_stats,
+    batch, rng, train, train_resolution=) -> (total, (metrics,
+    new_batch_stats))``. ``batch_keys``: what a batch holds, each array's
+    leading axis the batch's."""
+
+    build: Callable[[FasterRCNNConfig], Any]
+    init: Callable[..., Tuple[Any, Any]]
+    losses: Callable[..., Tuple[Array, Tuple[Dict[str, Array], Any]]]
+    batch_keys: Tuple[str, ...]
+
+
+def _detector_init(model: FasterRCNN, config: FasterRCNNConfig, rng: Array):
+    h, w = config.data.image_size
+    variables = model.init(
+        {"params": rng}, jnp.zeros((1, h, w, 3), jnp.float32), train=False
+    )
+    return variables["params"], variables.get("batch_stats", {})
+
+
+def model_kind(config: FasterRCNNConfig) -> ModelKind:
+    """The detector's four-loss step, or the sequence model's
+    (`models/lm.py`, imported only where a config asks for it)."""
+    if config.is_sequence_model:
+        from replication_faster_rcnn_tpu.models import lm
+
+        # the model is plain functions of the parameter tree: nothing to build
+        return ModelKind(
+            build=lambda config: None,
+            init=lambda model, config, rng: lm.init(config, rng),
+            losses=lm.losses,
+            batch_keys=lm.BATCH_KEYS,
+        )
+    return ModelKind(FasterRCNN, _detector_init, compute_losses, ("image", "boxes", "labels", "mask"))
+
+
 def create_train_state(
     config: FasterRCNNConfig, rng: Array, tx: optax.GradientTransformation
-) -> Tuple[FasterRCNN, TrainState]:
-    model = FasterRCNN(config)
-    h, w = config.data.image_size
+) -> Tuple[Any, TrainState]:
+    kind = model_kind(config)
+    model = kind.build(config)
     init_rng, state_rng = jax.random.split(rng)
 
     @jax.jit
@@ -61,11 +103,8 @@ def create_train_state(
         # one program, not an eager pass over the model (some 220 small
         # programs and the forward itself, which only the parameters' shapes
         # need): 3 s of every start from the compile cache, 70 s without
-        variables = model.init(
-            {"params": init_rng}, jnp.zeros((1, h, w, 3), jnp.float32), train=False
-        )
-        params = variables["params"]
-        return params, variables.get("batch_stats", {}), tx.init(params)
+        params, batch_stats = kind.init(model, config, init_rng)
+        return params, batch_stats, tx.init(params)
 
     params, batch_stats, opt_state = init(init_rng)
     return model, TrainState(
@@ -267,7 +306,7 @@ def quantize_grads(grads: Any, dtype_str: str) -> Any:
 
 
 def make_train_step(
-    model: FasterRCNN,
+    model: Any,
     config: FasterRCNNConfig,
     tx: optax.GradientTransformation,
     train_resolution=None,
@@ -282,11 +321,13 @@ def make_train_step(
     program, byte-identical to the pre-bucket build.
     """
 
+    losses_of = model_kind(config).losses
+
     def train_step(state: TrainState, batch: Dict[str, Array]):
         step_rng = jax.random.fold_in(state.rng, state.step)
 
         def loss_fn(params):
-            return compute_losses(
+            return losses_of(
                 model, config, params, state.batch_stats, batch, step_rng,
                 True, train_resolution=train_resolution,
             )

@@ -57,10 +57,18 @@ from replication_faster_rcnn_tpu.train.train_step import (
     make_cached_multi_step,
     make_optimizer,
     make_train_step,
+    model_kind,
 )
 from replication_faster_rcnn_tpu.telemetry import spans as tspans
 from replication_faster_rcnn_tpu.telemetry.watchdog import StallWatchdog
 from replication_faster_rcnn_tpu.utils.logging import MetricLogger
+
+COUNTER_EVERY = 10  # steps between the counters a sequence model's trainer traces
+
+
+def _rows(batch) -> int:
+    """Samples in a host batch (or a --cache-device selection)."""
+    return next(iter(batch.values())).shape[0]
 
 
 def load_eval_variables(
@@ -75,6 +83,7 @@ def load_eval_variables(
 
     from replication_faster_rcnn_tpu.models.faster_rcnn import FasterRCNN  # noqa: F401
 
+    config.require_detector("eval, predict and serve")
     tx, _ = make_optimizer(config, steps_per_epoch=1)
     model, state = create_train_state(
         config, jax.random.PRNGKey(config.train.seed), tx
@@ -194,6 +203,16 @@ class Trainer:
         )
         self._host_step = 0  # host mirror of state.step: no sync to read
         self._shutdown: Optional[fault.GracefulShutdown] = None
+        # the step's counters that go to the tracer (a sequence model's
+        # routing counts; a detector has none), and the steps' metrics that
+        # wait, still on the device, for a moment the host may read them
+        self._counters: tuple = ()
+        if config.is_sequence_model and telemetry_dir:
+            from replication_faster_rcnn_tpu.models.lm import COUNTERS
+
+            self._counters = COUNTERS
+        self._counters_pending: list = []
+        self._batch_keys = model_kind(config).batch_keys
 
         # chaos runs: every injected fault lands in the metric stream and
         # the watchdog incident log, so a post-mortem can line up observed
@@ -201,8 +220,11 @@ class Trainer:
         if failpoints.armed():
             failpoints.set_sink(self._chaos_sink)
 
+        # a sequence model's seeded documents draw their ids from the
+        # vocabulary rows held here
+        ids = {"id_rows": config.lm.vocab_rows} if config.is_sequence_model else {}
         self.dataset = dataset if dataset is not None else make_dataset(
-            config.data, "train"
+            config.data, "train", **ids
         )
         self.device_cache = None
         self.sampler = None
@@ -878,6 +900,13 @@ class Trainer:
         knows it: the span carries it, as that step's ``step/dispatch``
         does."""
         feed = "device_cache" if self.device_cache is not None else "loader"
+        if self.device_cache is None:
+            lacks = [k for k in self._batch_keys if k not in batch]
+            if lacks:
+                raise ValueError(
+                    f"the batch lacks {lacks}: this config's step takes "
+                    f"{self._batch_keys} (is the data set of another kind of model?)"
+                )
         ids = {} if step is None else {"step": step}
         with self.tracer.span("data/device_put", cat="data", feed=feed, **ids):
             return stage_to_devices(
@@ -948,7 +977,22 @@ class Trainer:
         # hand the monitor this step's `skipped` flag as a DEVICE scalar —
         # it syncs only at drain points, preserving dispatch overlap
         self.skip_monitor.observe(self._host_step, metrics)
+        if self._counters and self._host_step % COUNTER_EVERY == 0:
+            self._counters_pending.append(
+                (self._host_step, {k: metrics[k] for k in self._counters})
+            )
+            if len(self._counters_pending) > 1:
+                # the step before the newest finished COUNTER_EVERY steps ago
+                self._write_counters(*self._counters_pending.pop(0))
         return metrics
+
+    def _write_counters(self, step: int, values) -> None:
+        """One counter event a name (`lm/<name>`), from a step's metrics the
+        host may read without waiting."""
+        with self.tracer.span("step/counters", cat="sync", step=step):
+            host = jax.device_get(values)
+        for name, value in host.items():
+            self.tracer.counter(f"lm/{name}", float(value))
 
     def train_chunk(
         self,
@@ -1021,7 +1065,12 @@ class Trainer:
         :meth:`train_one_batch` directly without :meth:`telemetry_session`."""
         if self.watchdog is not None:
             self.watchdog.stop()
+        self._flush_counters()
         self.tracer.flush()
+
+    def _flush_counters(self) -> None:
+        while self._counters_pending:
+            self._write_counters(*self._counters_pending.pop(0))
 
     @contextlib.contextmanager
     def telemetry_session(self):
@@ -1046,6 +1095,7 @@ class Trainer:
         finally:
             if self.watchdog is not None:
                 self.watchdog.stop()
+            self._flush_counters()
             self.tracer.flush()
 
     def _check_preemption(self, step: int) -> None:
@@ -1106,6 +1156,7 @@ class Trainer:
 
         The val dataset and the Evaluator (whose inference fn is jitted)
         are built once and cached, so per-epoch eval pays no recompile."""
+        self.config.require_detector("evaluate")
         if getattr(self, "_evaluator", None) is None:
             from replication_faster_rcnn_tpu.eval import Evaluator
 
@@ -1292,9 +1343,7 @@ class Trainer:
                                         batch, bucket=cur_bucket()
                                     )
                                     step += 1
-                                    n_images += batch[
-                                        "idx" if "idx" in batch else "image"
-                                    ].shape[0]
+                                    n_images += _rows(batch)
                                     if self.watchdog is not None:
                                         self.watchdog.beat(
                                             step=step, phase="train"
@@ -1332,10 +1381,7 @@ class Trainer:
                                 )
                                 first = step + 1
                                 step += k
-                                n_images += sum(
-                                    b["idx" if "idx" in b else "image"].shape[0]
-                                    for b in chunk
-                                )
+                                n_images += sum(_rows(b) for b in chunk)
                                 chunk = []
                                 if self.watchdog is not None:
                                     self.watchdog.beat(step=step, phase="train")
@@ -1351,9 +1397,7 @@ class Trainer:
                             metrics = self.train_one_batch(
                                 batch, bucket=cur_bucket()
                             )
-                            n_images += batch[
-                                "idx" if "idx" in batch else "image"
-                            ].shape[0]
+                            n_images += _rows(batch)
                             step += 1
                             if self.watchdog is not None:
                                 self.watchdog.beat(step=step, phase="train")
@@ -1371,9 +1415,7 @@ class Trainer:
                             metrics = self.train_one_batch(
                                 batch, bucket=cur_bucket()
                             )
-                            n_images += batch[
-                                "idx" if "idx" in batch else "image"
-                            ].shape[0]
+                            n_images += _rows(batch)
                             step += 1
                             if self.watchdog is not None:
                                 self.watchdog.beat(step=step, phase="train")

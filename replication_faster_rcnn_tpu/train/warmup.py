@@ -1030,6 +1030,62 @@ def build_pallas_program_specs(
     return specs
 
 
+LM_PROGRAM = "train_lm_k1"
+
+
+def build_lm_program_specs() -> Dict[str, ProgramSpec]:
+    """{LM_PROGRAM: ProgramSpec}: the sequence model's train step
+    (`models/lm.py`) at the tiny preset's sizes on one device, jitted as the
+    Trainer jits it (donated state, `out_shardings` pinning its layout). It
+    hangs on no audited detector config: the kind of model is the preset's.
+    Off the TPU its two kernels lower in interpret mode (`pallas_interpret`
+    in the meta), as the ops.backend=pallas twins do."""
+    import dataclasses as _dc
+
+    from replication_faster_rcnn_tpu import ops as ops_pkg
+    from replication_faster_rcnn_tpu.config import get_config
+    from replication_faster_rcnn_tpu.parallel import batch_sharding
+    from replication_faster_rcnn_tpu.parallel.plan import Plan, compile_step_with_plan
+    from replication_faster_rcnn_tpu.parallel.zero import train_state_shardings
+    from replication_faster_rcnn_tpu.train.train_step import (
+        create_train_state,
+        make_optimizer,
+        make_train_step,
+    )
+
+    config = get_config("trinity_tiny")
+    config = config.replace(mesh=_dc.replace(config.mesh, num_data=1))
+
+    def _build():
+        mesh, mesh_cfg = _mesh_for(config)
+        tx, _ = make_optimizer(config, steps_per_epoch=100)
+        state_raw = jax.eval_shape(
+            lambda rng: create_train_state(config, rng, tx)[1], jax.random.PRNGKey(0)
+        )
+        shardings = train_state_shardings(state_raw, mesh, mesh_cfg, False)
+        state_abs = jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), state_raw, shardings
+        )
+        batch_abs = {
+            "tokens": jax.ShapeDtypeStruct(
+                (config.train.batch_size, config.data.seq_len), np.int32,
+                sharding=batch_sharding(mesh, mesh_cfg),
+            )
+        }
+        plan = Plan(mesh=mesh, donate_argnums=(0,), out_shardings=(shardings, None))
+        return compile_step_with_plan(make_train_step(None, config, tx), plan), (state_abs, batch_abs)
+
+    meta = {
+        "preset": "trinity_tiny", "mesh_shape": {config.mesh.data_axis: 1, config.mesh.model_axis: 1},
+        "pallas_interpret": ops_pkg.interpret_mode(),
+    }
+    return {
+        LM_PROGRAM: ProgramSpec(
+            name=LM_PROGRAM, feed="loader", k=1, arg_roles=("state", "batch"), build=_build, meta=meta
+        )
+    }
+
+
 def warmup_compile(
     config: FasterRCNNConfig,
     include_eval: bool = True,

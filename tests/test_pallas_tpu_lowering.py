@@ -1,6 +1,7 @@
 """Every `ops/pallas/` kernel, and the stem's two of `ops/pool_ops.py`, must
 lower for the TPU at the shapes the `voc_resnet18` step and the 600x600 serve
-program use.
+program use; the sequence model's attention and grouped product
+(`ops/attention.py`, `ops/grouped_mm.py`) at the shapes of `trinity_mini_ep8`.
 
 The Pallas -> Mosaic lowering is Python and runs on any host
 (``lowering_platforms=("tpu",)``), so whatever it refuses — an
@@ -14,7 +15,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from replication_faster_rcnn_tpu.ops import pool_ops
+from replication_faster_rcnn_tpu.ops import attention as attention_ops
+from replication_faster_rcnn_tpu.ops import grouped_mm, pool_ops
 from replication_faster_rcnn_tpu.ops.pallas import (
     dequantize_pallas,
     iou_matrix_pallas,
@@ -65,7 +67,28 @@ def _stem_args(batch, per_sample=False):
     return (S((batch, 300, 300, 64), jnp.bfloat16), term, term, term)
 
 
+def _attention(window):
+    """Two rows of 8,192 tokens, 32 query heads on 4 KV heads of 128, forward
+    and the hand-written backward's two kernels."""
+    loss = lambda q, k, v: jnp.sum(attention_ops.attention(q, k, v, window).astype(F32))
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+_ATTENTION_ARGS = (S((2, 8192, 32, 128), jnp.bfloat16),) + (S((2, 8192, 4, 128), jnp.bfloat16),) * 2
+
+
+def _experts(rows, weights, sizes):
+    """The held experts' first product over the usual buffer of 32,768 rows."""
+    loss = lambda r, w: jnp.sum(grouped_mm.grouped_matmul(r, w, sizes).astype(F32))
+    return jax.grad(loss, argnums=(0, 1))(rows, weights)
+
+
 CASES = {
+    "attention_windowed_train": (_attention(2048), _ATTENTION_ARGS),
+    "attention_full_train": (_attention(None), _ATTENTION_ARGS),
+    "grouped_matmul_train": (
+        _experts, (S((32768, 2048), jnp.bfloat16), S((16, 2048, 1024), F32), S((16,), jnp.int32))
+    ),
     "stem_pool_train": (_stem(True), _stem_args(32)),
     "stem_pool_train_group_norm": (_stem(True), _stem_args(B, per_sample=True)),
     "stem_pool_serve": (_stem(False), _stem_args(8)),
@@ -121,6 +144,8 @@ CASES = {
 def test_kernel_lowers_for_tpu_at_step_shapes(name, monkeypatch):
     # the stem's kernels ask the backend, which is the CPU here
     monkeypatch.setattr(pool_ops, "_interpret", lambda: False)
+    monkeypatch.setattr(attention_ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(grouped_mm, "interpret_mode", lambda: False)
     fn, args = CASES[name]
     lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
     # a Mosaic kernel, not an interpreted loop nest

@@ -606,9 +606,11 @@ class TestServingSpecs:
             len(hlolint.AUDIT_FEEDS) * len(hlolint.AUDIT_KS) * 2
         )
         assert buckets <= extra and len(buckets) == expected_buckets
+        # ... and the sequence model's train program at its tiny preset (PR 31)
         assert extra - serving - buckets == {
             "train_loader_k1__pallas",
             "eval_infer__pallas",
+            "train_lm_k1",
         }
 
 

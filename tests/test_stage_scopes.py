@@ -159,6 +159,50 @@ class TestStageScopes:
         assert bare.as_text() == jit_step.as_text()
 
 
+class TestSequenceModelStageScopes:
+    """The sequence model's step (models/lm.py) names its stages under the
+    same prefix: perf/stagecut.py reads both programs, and `frcnn.update` is
+    one stage of both."""
+
+    @pytest.fixture(scope="class")
+    def lm_names(self):
+        from replication_faster_rcnn_tpu.config import get_config
+        from replication_faster_rcnn_tpu.train.train_step import create_train_state, make_optimizer, make_train_step
+
+        cfg = get_config("trinity_tiny")
+        tx, _ = make_optimizer(cfg, steps_per_epoch=1)
+        state = jax.eval_shape(lambda: create_train_state(cfg, jax.random.PRNGKey(0), tx)[1])
+        batch = {"tokens": jax.ShapeDtypeStruct((2, cfg.data.seq_len), jnp.int32)}
+        return _op_names(jax.jit(make_train_step(None, cfg, tx)).lower(state, batch))
+
+    def test_names_are_fixed_distinct_and_share_the_update_stage(self):
+        assert len(set(stages.LM_STAGES)) == len(stages.LM_STAGES) == 9
+        assert all(re.fullmatch(r"frcnn\.[a-z_]+", s) for s in stages.LM_STAGES)
+        assert set(stages.LM_STAGES) & set(stages.STAGES) == {stages.UPDATE}
+
+    @pytest.mark.parametrize("scope", [s for s in stages.LM_STAGES if s != stages.UPDATE])
+    def test_every_stage_is_in_the_lowered_step_forward_and_backward(self, lm_names, scope):
+        """Each layer is recomputed in the backward pass (`jax.checkpoint`):
+        a stage reads `jvp(...)` forward and under `transpose(` backward."""
+        held = [n for n in lm_names if scope in n]
+        assert [n for n in held if "transpose(" not in n], scope
+        assert [n for n in held if "transpose(" in n], scope
+
+    def test_nested_scopes_keep_their_order_and_the_update_is_outside_the_gradient(self, lm_names):
+        assert any(n.rfind(stages.LM_ATTN_CORE) > n.find(stages.LM_ATTENTION) >= 0 for n in lm_names)
+        assert any(n.rfind(stages.LM_EXPERT_MM) > n.find(stages.LM_EXPERTS) >= 0 for n in lm_names)
+        assert any(f"{stages.UPDATE}/" in n and "jvp(" not in n for n in lm_names)
+        assert not [n for n in lm_names if any(s in n for s in set(stages.STAGES) - {stages.UPDATE})]
+
+    def test_few_operations_of_the_step_lie_in_no_stage(self, lm_names):
+        """Of the step's own operations (an absolute name: inside a kernel's
+        interpreter or a scan body names are relative), under a twentieth is
+        in no stage: the step's rng fold and reshapes between stages."""
+        own = [n for n in lm_names if n.startswith("jit(train_step)/")]
+        loose = [n for n in own if "frcnn." not in n]
+        assert len(own) > 200 and len(loose) <= 0.05 * len(own), sorted(loose)
+
+
 # ------------------------------------------------- spans on the profiler
 
 
