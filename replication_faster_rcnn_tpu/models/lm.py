@@ -6,9 +6,15 @@ The model is plain functions of a parameter tree (`init`, `losses`): what
 `train/train_step.py` asks of a kind of model. Parameters are float32 and
 are cast to `model.compute_dtype` where a layer reads them; the router's
 scores, every softmax, the norms' statistics, the combine and the loss are
-float32. Every layer is recomputed in the backward pass (`jax.checkpoint`),
-the head and the loss run over blocks of tokens, so that no `[tokens,
-vocabulary]` array outlives its block.
+float32. Every layer is under a `jax.checkpoint` that keeps, beside the
+layer's inputs, the attention function's own residuals
+(`ops.attention.RESIDUAL_NAMES`: the tiles of q, k and v, the output and the
+log-sum-exp): the backward pass recomputes the rest of the layer, but runs no
+attention forward a second time and builds none of its operands again. That
+costs `4 * (num_heads + num_kv_heads) * head_size + 4 * num_heads` bytes a
+token and layer whatever the preset (18,560 at the published widths: 1.52 GB
+for five layers of 16,384 tokens). The head and the loss run over blocks of
+tokens, so that no `[tokens, vocabulary]` array outlives its block.
 
 The expert layer (`expert_layer`). The router scores ALL `lm.num_experts`
 in float32; the chosen are the top `experts_per_token` of score + balance
@@ -40,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from replication_faster_rcnn_tpu.config import FasterRCNNConfig, LMConfig
-from replication_faster_rcnn_tpu.ops.attention import attention
+from replication_faster_rcnn_tpu.ops.attention import RESIDUAL_NAMES, attention
 from replication_faster_rcnn_tpu.ops.grouped_mm import grouped_matmul
 from replication_faster_rcnn_tpu.telemetry import stages
 
@@ -52,6 +58,8 @@ COUNTERS = ("expert_assignments", "expert_load_max_over_mean", "tokens_dropped",
 HEAD_BLOCK = 2048  # tokens whose logits are alive at once
 USUAL_ROWS = 2  # the experts' usual buffer, in pairs that even routing sends here
 INIT_STD = 0.02
+# what a layer's `jax.checkpoint` keeps for the backward pass, beside the layer's inputs
+KEPT = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
 
 
 def layer_name(i: int) -> str:
@@ -318,7 +326,7 @@ def losses(
     new_bias, per_layer = {}, []
     for i in range(len(lm.layer_types)):
         name = layer_name(i)
-        x, stats = jax.checkpoint(lambda p, b, x, i=i: layer(lm, i, p, b, x))(
+        x, stats = jax.checkpoint(lambda p, b, x, i=i: layer(lm, i, p, b, x), policy=KEPT)(
             params[name], bias.get(name), x
         )
         if stats is not None:

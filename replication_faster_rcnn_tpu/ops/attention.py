@@ -30,6 +30,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,6 +43,12 @@ BLOCK_KV = 512  # key positions a block
 _LANES = 128
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)  # a hidden score: finite, so no inf - inf
 _VMEM_BYTES = 64 * 1024 * 1024
+# what the forward rule keeps for the backward kernels (the tiles of q, k and v as the
+# kernels take them, the output tiles, the log-sum-exp), by the names a caller's
+# `jax.checkpoint` may keep them under (`save_only_these_names`): its backward pass then
+# neither runs the forward kernel again nor builds its operands. Without such a policy
+# the names change nothing
+RESIDUAL_NAMES = ("attention_q", "attention_k", "attention_v", "attention_out", "attention_lse")
 
 
 def visible(t: int, window: Optional[int]) -> Array:
@@ -272,7 +279,7 @@ def _tiles_attention(walk: _Walk, q: Array, k: Array, v: Array) -> Array:
 
 
 def _tiles_attention_fwd(walk, q, k, v):
-    o, lse = _forward(walk, q, k, v)
+    q, k, v, o, lse = map(checkpoint_name, (q, k, v, *_forward(walk, q, k, v)), RESIDUAL_NAMES)
     return o, (q, k, v, o, lse)
 
 

@@ -6,6 +6,7 @@ the expert layer's share of the model; no pair dropped whatever the routing."""
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -124,6 +125,51 @@ def test_the_bfloat16_loss_and_gradient_are_the_references_to_rounding(seeded, r
     for name, g in _flat(grads).items():
         gap = abs(float(jnp.linalg.norm(g)) - norms[name]) / max(norms[name], median)
         assert gap < (0.3 if "router" in name else 0.1), (name, gap)
+
+
+@pytest.fixture(scope="module")
+def tiny_loss(seeded):
+    """`lm.losses` at `trinity_tiny` as the preset stands (bfloat16) on the
+    seeded weights' first batch, as a function of the parameters."""
+    _, flat, batches = seeded
+    cfg = get_config("trinity_tiny")
+    _, stats = lm.init(cfg, jax.random.PRNGKey(0))
+    return cfg, _tree(flat), lambda p: lm.losses(None, cfg, p, stats, batches[0], None)[0]
+
+
+@pytest.fixture(scope="module")
+def gradient_jaxpr(tiny_loss):
+    _, params, loss_of = tiny_loss
+    return str(jax.make_jaxpr(jax.grad(loss_of))(params))
+
+
+@pytest.mark.parametrize("kernel", ["attention_forward", "attention_backward_dq", "attention_backward_dkv"])
+def test_the_gradient_runs_each_attention_kernel_once_a_layer(tiny_loss, gradient_jaxpr, kernel):
+    """The layer's checkpoint keeps the attention function's own residuals
+    (`lm.KEPT`), so the backward pass does not run the forward kernel again:
+    one call of each of the three kernels a layer, where a checkpoint that
+    kept nothing held two of the forward."""
+    cfg, _, _ = tiny_loss
+    assert len(re.findall(rf"name={kernel}\b", gradient_jaxpr)) == len(cfg.lm.layer_types)
+
+
+def test_what_the_checkpoint_keeps_changes_no_gradient(tiny_loss, monkeypatch):
+    """The oracle is the parent's writing, built here: the same `losses` whose
+    layers are checkpointed with no policy. Both gradients are taken op by op
+    (no jit around them): each operation is then its own XLA:CPU program, the
+    kept arrays are the very ones the second run of the forward kernel and of
+    its operands made, and every leaf agrees TO THE BIT. Under one `jax.jit`
+    that is not to be had on the CPU: with the recomputation gone XLA:CPU
+    groups the layer's bfloat16 fusions otherwise and keeps excess precision
+    through another set of roundings (PERF.md section 6, PR 30 and PR 32), so
+    most leaves agree to bfloat16 rounding of their norm only."""
+    _, params, loss_of = tiny_loss
+    got = jax.grad(loss_of)(params)
+    monkeypatch.setattr(lm, "KEPT", None)
+    want = jax.grad(loss_of)(params)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        assert float(jnp.linalg.norm(w)) > 0, path
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
 
 
 def _expert_layer_inputs(sz, flat, seed=3):

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from replication_faster_rcnn_tpu.ops.attention import attention, visible
+from replication_faster_rcnn_tpu.ops.attention import RESIDUAL_NAMES, attention, visible
 from replication_faster_rcnn_tpu.ops.grouped_mm import grouped_matmul
 
 pytestmark = pytest.mark.pallas_interpret
@@ -52,6 +52,33 @@ def test_attention_never_looks_ahead_or_past_its_window():
     moved = attention(q, k.at[:, -1].add(3.0).at[:, 0].add(3.0), v.at[:, -1].add(3.0).at[:, 0].add(3.0), 4)
     np.testing.assert_allclose(moved[:, 4:-1], base[:, 4:-1], atol=1e-6)
     assert not np.allclose(moved[:, :4], base[:, :4], atol=1e-3)
+
+
+# a row of 700 positions is padded to two key blocks of 512: four query tiles of 256
+@pytest.mark.parametrize("window", [300, None])
+def test_a_checkpoint_keeps_the_named_residuals_of_attention_and_no_other(window, capsys):
+    """Under `jax.checkpoint` with `save_only_these_names(*RESIDUAL_NAMES)` the
+    backward pass is handed what the forward rule keeps (the tiles of q, the
+    padded rows of k and v, the output tiles and the log-sum-exp) and nothing
+    else, not even the arguments, which nothing is built from again; under a
+    plain `jax.checkpoint` the arguments and nothing else: the names change
+    nothing for a caller without the policy."""
+    b, t, h, kv, d = 1, 700, 4, 2, 16
+    q, k, v = jnp.zeros((b, t, h, d)), jnp.zeros((b, t, kv, d)), jnp.zeros((b, t, kv, d))
+    f = lambda q, k, v: attention(q, k, v, window)
+
+    def kept(g):
+        """(the arguments kept, the shapes of what else is kept)"""
+        jax.ad_checkpoint.print_saved_residuals(g, q, k, v)
+        lines = capsys.readouterr().out.splitlines()
+        arguments = [line for line in lines if "from the argument" in line]
+        return len(arguments), sorted(line.split()[0] for line in lines if line not in arguments)
+
+    named = jax.checkpoint(f, policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
+    n, padded, tiles, rows = b * kv, 1024, 1024 // 256, (h // kv) * 256
+    q_tiles, keys, stat = f"f32[{n},{tiles},{rows},{d}]", f"f32[{n},{padded},{d}]", f"f32[{n},{tiles},1,{rows}]"
+    assert kept(named) == (0, sorted([q_tiles, keys, keys, q_tiles, stat]))
+    assert kept(jax.checkpoint(f)) == (3, [])
 
 
 def _plain_grouped(rows, weights, sizes):
