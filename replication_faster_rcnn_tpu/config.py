@@ -1240,12 +1240,18 @@ class LMConfig:
     """The sequence model's sizes (`models/lm.py`) and the share of them
     this chip holds.
 
-    A decoder of pre-norm layers: rotary grouped-query attention, windowed
-    or full by `layer_types`, then a dense SwiGLU in the leading
-    `num_dense_layers` and a routed expert layer after them (sigmoid router
-    over `num_experts`, the top `experts_per_token` by score + balance bias,
-    their scores normalised and scaled by `route_scale`, one shared expert).
-    Empty `layer_types` means the config is no sequence model's.
+    A decoder of pre-norm layers. A layer's mixer by `layer_types`: rotary
+    grouped-query attention, windowed or full, or a gated delta-rule
+    recurrence over a `[linear_key_head_dim, linear_value_head_dim]` state a
+    value head (`linear_attention`: `ops/delta_rule.py`). Then a dense SwiGLU
+    in the leading `num_dense_layers` and a routed expert layer after them
+    (a router over `num_experts`, the top `experts_per_token`, one shared
+    expert). `router_score` "sigmoid": the top by score + balance bias, the
+    chosen scores normalised and scaled by `route_scale`; "softmax": the top
+    by probability, the chosen normalised, no bias and no scale. The other
+    switches say what a layer computes, each read at one site of
+    `models/lm.py`. Empty `layer_types` means the config is no sequence
+    model's.
 
     The share. A layer is divided over `num_experts / experts_held` chips by
     expert parallelism: this chip holds experts `first_expert ..
@@ -1262,7 +1268,7 @@ class LMConfig:
     num_kv_heads: int = 4
     head_size: int = 128
     sliding_window: int = 2048
-    layer_types: Tuple[str, ...] = ()  # "sliding_attention" | "full_attention"
+    layer_types: Tuple[str, ...] = ()  # "sliding_attention" | "full_attention" | "linear_attention"
     num_dense_layers: int = 1
     dense_width: int = 6144
     expert_width: int = 1024
@@ -1274,10 +1280,25 @@ class LMConfig:
     rms_norm_eps: float = 1e-5
     experts_held: int = 16
     first_expert: int = 0
+    # a `linear_attention` layer: key heads (q and k), value heads (v and the
+    # output gate; a multiple of the key heads, value head j reads key head
+    # j // (value / key)), their sizes, the taps of the causal convolution
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel: int = 4
+    rotary_fraction: float = 1.0  # the leading share of a head that the rotary embedding turns
+    qk_norm: bool = False  # an RMSNorm a head on q and on k, before the rotary embedding
+    attention_gate: bool = False  # sigmoid gate on the attention's output; wq is twice as wide
+    norm_zero_centred: bool = False  # a norm's weight is 1 + w, w made at zero
+    router_score: str = "sigmoid"  # | "softmax"
+    shared_expert_gate: bool = False  # the shared expert times sigmoid(h w_g)
+    embed_scale: bool = True  # x0 = E[t] * sqrt(hidden_size)
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        kinds = ("sliding_attention", "full_attention")
+        kinds = ("sliding_attention", "full_attention", "linear_attention")
         if any(t not in kinds for t in self.layer_types):
             raise ValueError(f"lm.layer_types entries must be of {kinds}, got {self.layer_types!r}")
         if self.num_heads % self.num_kv_heads:
@@ -1291,6 +1312,17 @@ class LMConfig:
             )
         if not 1 <= self.experts_per_token <= self.num_experts:
             raise ValueError(f"lm.experts_per_token={self.experts_per_token} of {self.num_experts} experts")
+        if self.linear_num_value_heads % self.linear_num_key_heads:
+            raise ValueError(
+                f"lm.linear_num_value_heads={self.linear_num_value_heads} must be a multiple of "
+                f"lm.linear_num_key_heads={self.linear_num_key_heads}"
+            )
+        if self.router_score not in ("sigmoid", "softmax"):
+            raise ValueError(f"lm.router_score must be 'sigmoid' or 'softmax', got {self.router_score!r}")
+        if not 0.0 < self.rotary_fraction <= 1.0 or int(self.head_size * self.rotary_fraction) % 2:
+            raise ValueError(
+                f"lm.rotary_fraction={self.rotary_fraction} of a head of {self.head_size}: want an even part of it"
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1467,6 +1499,42 @@ CONFIGS = {
             sliding_window=8, layer_types=("sliding_attention",) * 4 + ("full_attention",),
             dense_width=192, expert_width=32, num_experts=16, experts_per_token=2,
             experts_held=4,
+        ),
+    ),
+    # 9. One chip's share of Qwen3-Next-80B-A3B (`model_type` qwen3_next; 80 B
+    #    parameters, 3 B active) trained over sixteen chips by expert
+    #    parallelism: 32 of 512 experts and 18,992 of 151,936 vocabulary rows
+    #    held here, the router whole; one period of the layer pattern (three
+    #    gated delta-rule layers, one gated full-attention layer), the rest on
+    #    further pipeline stages. Widths as published
+    #    (perf/configs/qwen3_next_ep16.json has the source and every cut).
+    #    625.7 M parameters, 10.0 GB trained; one row of 16,384 tokens a step.
+    "qwen3_next_ep16": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=16384, root_dir=""),
+        train=TrainConfig(batch_size=1, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(
+            vocab_rows=18_992, num_heads=16, num_kv_heads=2, head_size=256,
+            layer_types=("linear_attention",) * 3 + ("full_attention",), num_dense_layers=0,
+            expert_width=512, num_experts=512, experts_per_token=10, experts_held=32,
+            rope_theta=1e7, rms_norm_eps=1e-6, rotary_fraction=0.25, qk_norm=True,
+            attention_gate=True, norm_zero_centred=True, router_score="softmax",
+            shared_expert_gate=True, embed_scale=False,
+        ),
+    ),
+    # 10. The same layer pattern at sizes a CPU test runs: hidden 64, 2 key /
+    #    4 value heads of 16 in the delta-rule layers, attention 4 / 2 heads of
+    #    32, 8 experts of which 4 are held, top-2, rows of 256 tokens.
+    "qwen3_next_tiny": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=256, root_dir=""),
+        train=TrainConfig(batch_size=2, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(
+            vocab_rows=64, hidden_size=64, num_heads=4, num_kv_heads=2, head_size=32,
+            layer_types=("linear_attention",) * 3 + ("full_attention",), num_dense_layers=0,
+            expert_width=32, num_experts=8, experts_per_token=2, experts_held=4,
+            linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=16,
+            linear_value_head_dim=16, rope_theta=1e7, rms_norm_eps=1e-6, rotary_fraction=0.25,
+            qk_norm=True, attention_gate=True, norm_zero_centred=True, router_score="softmax",
+            shared_expert_gate=True, embed_scale=False,
         ),
     ),
 }

@@ -28,6 +28,8 @@ UPDATE = "frcnn.update"  # gradient exchange and rounding, the guard, the optimi
 LM_EMBED = "frcnn.lm_embed"  # the embedding rows and their multiplier
 LM_ATTENTION = "frcnn.lm_attention"  # norm, q/k/v, rotary embedding, the output projection
 LM_ATTN_CORE = "frcnn.lm_attn_core"  # the attention function alone, inside lm_attention
+LM_LINEAR_ATTENTION = "frcnn.lm_linear_attention"  # a delta-rule layer's norm, projections, convolution, gates, gated norm
+LM_DELTA_CORE = "frcnn.lm_delta_core"  # the gated delta rule alone, inside lm_linear_attention
 LM_FFN = "frcnn.lm_ffn"  # norm, the dense SwiGLU or the shared expert
 LM_ROUTER = "frcnn.lm_router"  # scores, top-k, the sort by expert, counts, the balance bias
 LM_EXPERTS = "frcnn.lm_experts"  # rows gathered by expert, the weighted combine by token
@@ -38,6 +40,8 @@ LM_STAGES = (
     LM_EMBED,
     LM_ATTENTION,
     LM_ATTN_CORE,
+    LM_LINEAR_ATTENTION,
+    LM_DELTA_CORE,
     LM_FFN,
     LM_ROUTER,
     LM_EXPERTS,

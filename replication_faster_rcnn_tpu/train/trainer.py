@@ -979,7 +979,7 @@ class Trainer:
         self.skip_monitor.observe(self._host_step, metrics)
         if self._counters and self._host_step % COUNTER_EVERY == 0:
             self._counters_pending.append(
-                (self._host_step, {k: metrics[k] for k in self._counters})
+                (self._host_step, {k: metrics[k] for k in self._counters if k in metrics})
             )
             if len(self._counters_pending) > 1:
                 # the step before the newest finished COUNTER_EVERY steps ago
