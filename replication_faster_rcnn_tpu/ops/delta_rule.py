@@ -52,7 +52,7 @@ from jax.ad_checkpoint import checkpoint_name
 Array = jnp.ndarray
 
 CHUNK = 64  # tokens whose writes are solved together
-SEGMENT = 16  # chunks between two kept states
+SEGMENT = 4  # chunks between two kept states (PERF.md section 6, PR 34: 4, 8 and 16 read on the chip)
 HI = jax.lax.Precision.HIGHEST
 # q, k, v, g, beta as the function takes them, the state at each segment's start, the output
 RESIDUAL_NAMES = ("delta_q", "delta_k", "delta_v", "delta_g", "delta_beta", "delta_state", "delta_out")

@@ -115,3 +115,13 @@ def pytest_pyfunc_call(pyfuncitem):
             pytest.xfail("pins BENCHMARK.json to one cell; a benchmark PR's to update (PERF.md section 7)")
         raise
     pytest.fail(f"`{statement}` holds again: take this test off tests/conftest.py::_PINNED_TO_ONE_CELL")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_tracer_across_modules():
+    """A `Trainer` with a telemetry directory installs its tracer process-wide
+    and keeps it installed; each test module starts from no tracer."""
+    from replication_faster_rcnn_tpu.telemetry import spans
+
+    yield
+    spans.set_tracer(None)

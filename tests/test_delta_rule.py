@@ -35,8 +35,11 @@ def _recurrence(q, k, v, g, beta):
 
 
 # 200 tokens are no multiple of a chunk of 64 (padded to 4 chunks); 256 are; 2 chunks a
-# segment walks two segments, the committed length one; 64 tokens are one chunk
-@pytest.mark.parametrize("t,segment", [(200, 2), (256, 2), (256, delta_rule.SEGMENT), (64, delta_rule.SEGMENT)])
+# segment walks two segments, 1 chunk four, the committed length one; 64 tokens are one chunk
+@pytest.mark.parametrize(
+    "t,segment",
+    [(200, 2), (256, 2), (256, 1), (200, delta_rule.SEGMENT), (256, delta_rule.SEGMENT), (64, delta_rule.SEGMENT)],
+)
 def test_the_chunked_form_is_the_recurrence_outputs_and_all_five_gradients(monkeypatch, t, segment):
     """Two writings of one function in float32: the sums run in another order
     (64 tokens solved together, the state carried by chunk), so outputs and
