@@ -19,7 +19,7 @@ unweighted, `train.py:123`).
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict, NamedTuple, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,8 +92,13 @@ def model_kind(config: FasterRCNNConfig) -> ModelKind:
 
 
 def create_train_state(
-    config: FasterRCNNConfig, rng: Array, tx: optax.GradientTransformation
+    config: FasterRCNNConfig,
+    rng: Array,
+    tx: optax.GradientTransformation,
+    keep: Optional[Callable[[Any, str], Any]] = None,
 ) -> Tuple[Any, TrainState]:
+    """(model, first state). ``keep(jitted, label)`` wraps the init program
+    (the trainer's executable store, `train/warmup.py::keep_compiled`)."""
     kind = model_kind(config)
     model = kind.build(config)
     init_rng, state_rng = jax.random.split(rng)
@@ -106,6 +111,8 @@ def create_train_state(
         params, batch_stats = kind.init(model, config, init_rng)
         return params, batch_stats, tx.init(params)
 
+    if keep is not None:
+        init = keep(init, "init")
     params, batch_stats, opt_state = init(init_rng)
     return model, TrainState(
         step=jnp.zeros((), jnp.int32),

@@ -48,7 +48,11 @@ from replication_faster_rcnn_tpu.train import fault
 from replication_faster_rcnn_tpu.train.async_checkpoint import (
     AsyncCheckpointWriter,
 )
-from replication_faster_rcnn_tpu.train.warmup import place_compile_cache
+from replication_faster_rcnn_tpu.train.warmup import (
+    keep_compiled,
+    place_compile_cache,
+    program_context,
+)
 from replication_faster_rcnn_tpu.train.train_step import (
     TrainState,
     build_multi_step,
@@ -178,8 +182,9 @@ class Trainer:
         setup_t0 = self.tracer.now_us()
         # persistent XLA compilation cache: must be placed before the
         # first jitted call traces — jit is lazy, so doing it here covers
-        # every program this trainer compiles
-        place_compile_cache(config.compile.cache_dir)
+        # every program this trainer compiles. The train programs' and
+        # init's executables are kept beside it (`keep` below).
+        compile_cache = place_compile_cache(config.compile.cache_dir)
         validate_parallel(
             config, len(devices) if devices is not None else None
         )
@@ -293,17 +298,24 @@ class Trainer:
             steps_per_epoch = max(len(self.loader), 1)
         # n_shards sizes LAMB's psum'd trust-ratio norms to the data axis
         # the per-shard ZeRO update runs over; inert for adam/lars.
+        n_shards = self.mesh.shape[config.mesh.data_axis]
         self.tx, self.schedule = make_optimizer(
-            config,
-            steps_per_epoch,
-            n_shards=self.mesh.shape[config.mesh.data_axis],
+            config, steps_per_epoch, n_shards=n_shards
         )
         # host-math twin for log rows: evaluating the jnp schedule on the
         # host would build + sync a device scalar every logged step
         self.host_schedule = host_schedule(config, steps_per_epoch)
+        # every program below is kept across processes under a key of all
+        # its lowering reads; the same jit object where no cache is placed
+        context = program_context(config, steps_per_epoch, n_shards)
+
+        def keep(jitted, label: str, plan: Optional[Plan] = None):
+            parts = context if plan is None else context + (repr(plan),)
+            return keep_compiled(jitted, compile_cache, label, parts)
+
         with self.tracer.span("setup/init_state", cat="setup"):
             self.model, state = create_train_state(
-                config, jax.random.PRNGKey(config.train.seed), self.tx
+                config, jax.random.PRNGKey(config.train.seed), self.tx, keep=keep
             )
         from replication_faster_rcnn_tpu.parallel.zero import (
             place_train_state,
@@ -404,12 +416,25 @@ class Trainer:
         from replication_faster_rcnn_tpu import ops as ops_pkg
         from replication_faster_rcnn_tpu.train.warmup import scope_jitted
 
-        if ops_pkg.resolve_backend(config) == "pallas":
+        pallas = ops_pkg.resolve_backend(config) == "pallas"
+        if pallas:
             self.jitted_step = scope_jitted(self.jitted_step, config)
             if self.jitted_multi_step is not None:
                 self.jitted_multi_step = scope_jitted(
                     self.jitted_multi_step, config
                 )
+        # the shard_map programs build their plans inside
+        # make_shard_map_train_step, from what the key already holds
+        spmd = config.train.backend == "spmd"
+        self.jitted_step = keep(
+            self.jitted_step, "train_step", None if spmd else self._step_plan
+        )
+        if self.jitted_multi_step is not None:
+            self.jitted_multi_step = keep(
+                self.jitted_multi_step,
+                f"multi_step_k{self.steps_per_dispatch}",
+                None if spmd else multi_plan,
+            )
         # multi-scale resolution buckets (data.train_resolutions): one
         # compiled program per bucket, each baking the bucket's static
         # (h, w) on-device resample into the trace (compute_losses) under
@@ -428,9 +453,14 @@ class Trainer:
                 make_cached_train_step,
             )
 
-            pallas = ops_pkg.resolve_backend(config) == "pallas"
             k = self.steps_per_dispatch
             steps, multis = [], []
+
+            def kept(jitted, label: str, plan: Optional[Plan] = None):
+                return keep(
+                    scope_jitted(jitted, config) if pallas else jitted, label, plan
+                )
+
             for bh, bw in self._bucket_resolutions:
                 if config.train.backend == "spmd":
                     # per-bucket shard_map program: the in/out specs shard
@@ -447,9 +477,7 @@ class Trainer:
                         state_template=self.state,
                         train_resolution=(bh, bw),
                     )
-                    steps.append(
-                        scope_jitted(jitted, config) if pallas else jitted
-                    )
+                    steps.append(kept(jitted, f"train_step_{bh}x{bw}"))
                     if k > 1:
                         mj, _ = make_shard_map_train_step(
                             config, self.tx, self.mesh,
@@ -457,9 +485,7 @@ class Trainer:
                             state_template=self.state,
                             train_resolution=(bh, bw),
                         )
-                        multis.append(
-                            scope_jitted(mj, config) if pallas else mj
-                        )
+                        multis.append(kept(mj, f"multi_step_k{k}_{bh}x{bw}"))
                     continue
                 plan = dataclasses.replace(
                     self._step_plan, label=f"train_step_{bh}x{bw}"
@@ -472,8 +498,9 @@ class Trainer:
                     fn = make_train_step(
                         self.model, config, self.tx, train_resolution=(bh, bw)
                     )
-                jitted = compile_step_with_plan(fn, plan)
-                steps.append(scope_jitted(jitted, config) if pallas else jitted)
+                steps.append(
+                    kept(compile_step_with_plan(fn, plan), plan.label, plan)
+                )
                 if k > 1:
                     mplan = dataclasses.replace(
                         self._step_plan, label=f"multi_step_k{k}_{bh}x{bw}"
@@ -491,8 +518,9 @@ class Trainer:
                             ),
                             k,
                         )
-                    mj = compile_step_with_plan(mfn, mplan)
-                    multis.append(scope_jitted(mj, config) if pallas else mj)
+                    multis.append(
+                        kept(compile_step_with_plan(mfn, mplan), mplan.label, mplan)
+                    )
             self.jitted_bucket_steps = steps
             if multis:
                 self.jitted_bucket_multi_steps = multis

@@ -2,7 +2,7 @@
 
 Every process start re-pays full XLA compilation of the train step
 (minutes for the big presets on TPU) before the first batch dispatches.
-Two pieces take that off the startup critical path:
+Three pieces take that off the startup critical path:
 
 * :func:`place_compile_cache` — the one place that decides where JAX's
   persistent compilation cache lives (``JAX_COMPILATION_CACHE_DIR`` if
@@ -11,6 +11,11 @@ Two pieces take that off the startup critical path:
   compile options and written under the directory; a later process
   compiling the *same* program (same config, same mesh, same jaxlib)
   deserializes instead of re-running XLA.
+* :func:`keep_compiled` — the executable store beside that cache
+  (``<cache>/executables/``). The persistent cache is keyed by the lowered
+  module, so every start still traces and lowers each program before it
+  can ask; the store keys a program's executable by everything its
+  lowering reads, and a start that matches loads it without tracing.
 * :func:`warmup_compile` — AOT-lower and compile the training-step
   program(s) (and optionally the eval inference program) for a config
   WITHOUT building datasets, allocating parameters or running a step:
@@ -34,13 +39,20 @@ committed fingerprints.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import glob
+import hashlib
 import os
+import pickle
 import re
+import threading
 import time
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.experimental import serialize_executable
+from jax.sharding import NamedSharding
 
 from replication_faster_rcnn_tpu.config import FasterRCNNConfig
 from replication_faster_rcnn_tpu.telemetry import spans as tspans
@@ -72,15 +84,20 @@ def place_compile_cache(cache_dir: str = "") -> Optional[str]:
     even cheap programs persist — this cache exists to make *restarts*
     free, and a restart replays every program, not just the slow ones.
 
-    The key holds the program's metadata (source locations and name
-    scopes), which JAX leaves out by default: a cached executable carries
-    the ``op_name`` of whoever compiled it, so without this a run loads
-    another commit's executable and its profiler trace shows that commit's
-    names (found on the chip in PR 24: the step read 100 % outside the
-    stage scopes of `telemetry/stages.py`, from a step an earlier commit
-    had cached). A hit is now this source's own program: one with traced
-    lines moved compiles its own. Source files inside the checkout are
-    named relative to it, so where the checkout lies is not in the key."""
+    The key holds the program's metadata (its name scopes), which JAX
+    leaves out by default: a cached executable carries the ``op_name`` of
+    whoever compiled it, so without this a run loads another commit's
+    executable and its profiler trace shows that commit's names (found on
+    the chip: the step read 100 % outside the stage scopes of
+    `telemetry/stages.py`, from a step an earlier commit had cached). A hit
+    is now a program with this source's own names.
+
+    The metadata holds no source locations (a traceback limit of 0 frames):
+    a nested jit's jaxpr is traced once a process and keeps the call stack
+    of whichever program traced it first, so with locations a program's key
+    would depend on what the process compiled before it, and a start that
+    loads the train programs from the executable store (`keep_compiled`)
+    would miss every entry a start that compiled them had written."""
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         if jax.default_backend() == "cpu":
@@ -95,11 +112,323 @@ def place_compile_cache(cache_dir: str = "") -> Optional[str]:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    jax.config.update(
-        "jax_hlo_source_file_canonicalization_regex",
-        "^" + re.escape(CHECKOUT_ROOT + os.sep),
-    )
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     return path
+
+
+# ------------------------------------------------------ executable store
+
+EXECUTABLES_DIR = "executables"  # under the compile cache's directory
+PACKAGE_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# what changes a lowering besides the source, the configuration and the
+# arguments: jax settings, and the environment the compiler and the
+# package's own dispatch sites (`ops/`: FRCNN_OPS_BACKEND, FRCNN_NMS, ...) read
+LOWERING_SETTINGS = (
+    "jax_enable_x64",
+    "jax_default_matmul_precision",
+    "jax_default_prng_impl",
+    "jax_threefry_partitionable",
+    "jax_numpy_dtype_promotion",
+    "jax_use_shardy_partitioner",
+    "jax_traceback_in_locations_limit",
+    "jax_include_full_tracebacks_in_locations",
+)
+LOWERING_ENV = ("XLA_FLAGS", "LIBTPU_INIT_ARGS")
+LOWERING_ENV_PREFIX = "FRCNN_"
+
+
+@functools.lru_cache(maxsize=None)
+def source_digest(root: str) -> str:
+    """sha256 over every ``*.py`` under ``root``, each by its path relative
+    to it: the Pallas kernels and every function a program traces."""
+    h = hashlib.sha256()
+    for path in sorted(glob.glob(os.path.join(root, "**", "*.py"), recursive=True)):
+        h.update(os.path.relpath(path, root).encode() + b"\0")
+        with open(path, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    return h.hexdigest()
+
+
+@functools.lru_cache(maxsize=None)
+def _versions() -> str:
+    import flax
+    import jaxlib
+    import optax
+
+    return (
+        f"jax {jax.__version__} jaxlib {jaxlib.__version__} "
+        f"flax {flax.__version__} optax {optax.__version__}"
+    )
+
+
+def program_context(
+    config: FasterRCNNConfig, steps_per_epoch: int, n_shards: int
+) -> Tuple[str, ...]:
+    """What a train program's builder is handed, as the store's key holds
+    it: the resolved configuration, ``steps_per_epoch`` and ``n_shards``
+    (the schedule's constants) and the resolved ops backend.
+
+    ``train.seed`` is left out unless ``data.augment_device`` is on: that is
+    the one place a program reads it (`train/train_step.py::_device_input`);
+    elsewhere it seeds host-side draws and the init program's key, which is
+    an argument. A restart with another seed then finds its entry
+    (`tests/test_executable_store.py` lowers the step for two seeds)."""
+    from replication_faster_rcnn_tpu import ops as ops_pkg
+
+    keyed = config
+    if not config.data.augment_device:
+        keyed = config.replace(train=dataclasses.replace(config.train, seed=0))
+    return (
+        repr(keyed),
+        f"steps_per_epoch {steps_per_epoch}",
+        f"n_shards {n_shards}",
+        f"ops_backend {ops_pkg.resolve_backend(config)}",
+    )
+
+
+def _devices_of(leaves) -> List[Any]:
+    """The devices a program runs on, in its mesh's order: those of its
+    first argument placed by a sharding, else the default device."""
+    for leaf in leaves:
+        s = getattr(leaf, "sharding", None)
+        if isinstance(s, NamedSharding):
+            return list(s.mesh.devices.flat)
+        if s is not None:
+            return sorted(s.device_set, key=lambda d: d.id)
+    return [jax.devices()[0]]
+
+
+def _signature(leaves, treedef) -> Optional[str]:
+    """The arguments as a lowering sees them (treedef; each leaf's shape,
+    dtype, weak type, sharding with its devices), or None where any is
+    abstract: a transformation is tracing through the program."""
+    parts = [str(treedef)]
+    for x in leaves:
+        if isinstance(x, jax.core.Tracer):
+            return None
+        s = getattr(x, "sharding", None)
+        if s is None:
+            parts.append(repr(jax.typeof(x)))
+            continue
+        ids = (
+            [d.id for d in s.mesh.devices.flat]
+            if isinstance(s, NamedSharding)
+            else sorted(d.id for d in s.device_set)
+        )
+        parts.append(
+            f"{jax.typeof(x)!r} {s!r} devices={ids} "
+            f"committed={getattr(x, 'committed', True)}"
+        )
+    return "\n".join(parts)
+
+
+def _runtime(devices) -> List[str]:
+    d = devices[0]
+    env = sorted(
+        (k, v)
+        for k, v in os.environ.items()
+        if k in LOWERING_ENV or k.startswith(LOWERING_ENV_PREFIX)
+    )
+    return [
+        _versions(),
+        f"backend {d.platform} {d.client.platform_version}",
+        f"device {d.device_kind}",
+        f"process {jax.process_index()} of {jax.process_count()}",
+        *(f"{name} {getattr(jax.config, name)}" for name in LOWERING_SETTINGS),
+        *(f"env {k}={v}" for k, v in env),
+    ]
+
+
+class StoredProgram:
+    """A jitted program whose compiled executables outlive the process.
+
+    The first call with a signature looks its entry up under the key of
+    everything the lowering reads (:func:`source_digest` of the package,
+    the caller's ``context``, the label, the signature, versions, devices,
+    settings); a hit loads the executable onto the program's devices and
+    traces, lowers and compiles nothing. A miss lowers and compiles as the
+    jit would (so the persistent cache is asked and written as before),
+    then writes the entry atomically. An entry that cannot be read or
+    loaded is deleted and written anew; that is never fatal.
+
+    After the first call, dispatch goes straight to the held executable,
+    whose own argument check runs in C++; only where it refuses the
+    arguments is a signature computed (one executable each). ``.lower``
+    gives the held executable where there is one for its arguments, else
+    the jit's lowering; every other attribute passes through to the jit.
+    ``_cache_size`` counts the executables held, for the strict harness's
+    recompile gate. Each
+    lookup writes a ``jit/load`` span (``fun``, ``outcome`` hit | miss |
+    bad, ``bytes``), each write a ``jit/store`` span.
+    """
+
+    def __init__(self, jitted, directory: str, label: str, context: Sequence[str]):
+        self._jitted = jitted
+        self._dir = directory
+        self.label = label
+        self._context = tuple(context)
+        self._held: Dict[str, Any] = {}
+        self._last = None
+
+    def __call__(self, *args):
+        last = self._last
+        if last is not None:
+            try:
+                return last(*args)
+            except (TypeError, ValueError):
+                # its check refused the arguments: another signature (an
+                # error of the call itself is raised again below)
+                pass
+        leaves, treedef = jax.tree_util.tree_flatten(args)
+        sig = _signature(leaves, treedef)
+        if sig is None:
+            return self._jitted(*args)
+        compiled = self._held.get(sig)
+        if compiled is None:
+            compiled = self._held[sig] = self._obtain(sig, leaves, args)
+        self._last = compiled
+        return compiled(*args)
+
+    def lower(self, *args, **kwargs):
+        """Where an executable is held for these arguments, a stand-in whose
+        ``compile()`` is that executable: the program that ran, which a
+        loaded program has never traced (lowering it again here would cost
+        a start's seconds, and a persistent-cache key of its own, since the
+        key holds the callers' lines); else the jit's own lowering."""
+        if not kwargs:
+            leaves, treedef = jax.tree_util.tree_flatten(args)
+            held = self._held.get(_signature(leaves, treedef))
+            if held is not None:
+                return _HeldLowering(held)
+        return self._jitted.lower(*args, **kwargs)
+
+    def _cache_size(self) -> int:
+        return len(self._held)
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+    def _obtain(self, sig: str, leaves, args):
+        tracer = tspans.current_tracer()
+        devices = _devices_of(leaves)
+        key = hashlib.sha256(
+            "\n".join(
+                (source_digest(PACKAGE_ROOT), self.label, *self._context, *_runtime(devices), sig)
+            ).encode()
+        ).hexdigest()
+        path = os.path.join(self._dir, f"{re.sub(r'[^\w.-]', '_', self.label)}-{key}.exe")
+        t0 = tracer.now_us()
+        compiled, outcome, size, error = None, "miss", 0, {}
+        if os.path.exists(path):
+            try:
+                with open(path, "rb") as f:
+                    blob = f.read()
+                stored, payload, in_tree, out_tree = pickle.loads(blob)
+                if stored != key:
+                    raise ValueError("an entry of another key")
+                compiled = serialize_executable.deserialize_and_load(
+                    payload, in_tree, out_tree,
+                    backend=devices[0].client, execution_devices=devices,
+                )
+                outcome, size = "hit", len(blob)
+            except Exception as e:  # never fatal: compiled and written anew below
+                outcome, error = "bad", {"error": f"{type(e).__name__}: {e}"[:200]}
+                _remove(path)
+            else:
+                try:
+                    os.utime(path)  # last used: what the bound evicts by
+                except OSError:
+                    pass
+        tracer.complete(
+            "jit/load", t0, tracer.now_us() - t0, cat="compile",
+            fun=self.label, outcome=outcome, bytes=size, **error,
+        )
+        if compiled is None:
+            compiled = self._jitted.lower(*args).compile()
+            self._store(compiled, key, path, tracer)
+        return compiled
+
+    def _store(self, compiled, key: str, path: str, tracer) -> None:
+        t0 = tracer.now_us()
+        tmp, size, error = None, 0, {}
+        try:
+            payload, in_tree, out_tree = serialize_executable.serialize(compiled)
+            blob = pickle.dumps((key, payload, in_tree, out_tree), protocol=pickle.HIGHEST_PROTOCOL)
+            os.makedirs(self._dir, exist_ok=True)
+            tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
+            with open(tmp, "wb") as f:
+                f.write(blob)
+            os.replace(tmp, path)
+            tmp, size = None, len(blob)
+            _evict(self._dir)
+        except Exception as e:  # unserializable or unwritable: it runs as compiled
+            error = {"error": f"{type(e).__name__}: {e}"[:200]}
+            if tmp is not None:
+                _remove(tmp)
+        tracer.complete(
+            "jit/store", t0, tracer.now_us() - t0, cat="compile",
+            fun=self.label, bytes=size, **error,
+        )
+
+
+class _HeldLowering:
+    """`StoredProgram.lower`'s answer where the executable is held."""
+
+    def __init__(self, compiled):
+        self._compiled = compiled
+
+    def compile(self, *args, **kwargs):
+        return self._compiled
+
+
+def _remove(path: str) -> None:
+    try:
+        os.remove(path)
+    except OSError:
+        pass
+
+
+# entries used since this module was imported were loaded or written by
+# this process (or one beside it) and are never evicted by it: the programs
+# of one start must not turn each other out
+_IMPORTED_AT = time.time()
+
+
+def _evict(directory: str) -> None:
+    """Hold the store to the size the persistent cache is held to
+    (``jax_compilation_cache_max_size``; unbounded where it is not set):
+    the least recently used entries go first, never one in use."""
+    limit = jax.config.jax_compilation_cache_max_size
+    if limit is None or limit < 0:
+        return
+    entries = []
+    for path in glob.glob(os.path.join(directory, "*.exe")):
+        try:
+            st = os.stat(path)
+        except OSError:
+            continue
+        entries.append((st.st_mtime, st.st_size, path))
+    total = sum(size for _, size, _ in entries)
+    for mtime, size, path in sorted(entries):
+        if total <= limit:
+            break
+        if mtime < _IMPORTED_AT:
+            _remove(path)
+            total -= size
+
+
+def keep_compiled(
+    jitted, cache_dir: Optional[str], label: str, context: Sequence[str]
+):
+    """``jitted`` with its executables kept under ``<cache_dir>/executables/``
+    (:class:`StoredProgram`), or ``jitted`` itself, unchanged, where no
+    compile cache is placed (``cache_dir`` None: the CPU backend without
+    ``JAX_COMPILATION_CACHE_DIR``). Empty the store by deleting that
+    directory."""
+    if cache_dir is None:
+        return jitted
+    return StoredProgram(jitted, os.path.join(cache_dir, EXECUTABLES_DIR), label, context)
 
 
 def _mesh_for(config: FasterRCNNConfig):

@@ -266,9 +266,12 @@ class TestCompileCachePlacement:
         assert updates["jax_persistent_cache_min_entry_size_bytes"] == -1
 
     def test_a_hit_is_this_sources_own_program(self, updates, monkeypatch, tmp_path):
-        """Name scopes and locations are part of the key: an executable
-        another commit cached carries that commit's `op_name`s, and a trace
-        of it would be cut by stages this program does not have."""
+        """Name scopes are part of the key: an executable another commit
+        cached carries that commit's `op_name`s, and a trace of it would be
+        cut by stages this program does not have. Source locations are not:
+        a nested jit keeps the call stack of whichever program traced it
+        first, so a key with them would hang on what the process compiled
+        before (`tests/test_executable_store.py` shows both)."""
         import jax
 
         from replication_faster_rcnn_tpu.train import warmup
@@ -276,20 +279,12 @@ class TestCompileCachePlacement:
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         warmup.place_compile_cache(str(tmp_path / "flag"))
         assert updates["jax_compilation_cache_include_metadata_in_key"] is True
+        assert updates["jax_traceback_in_locations_limit"] == 0
         updates.clear()
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
         warmup.place_compile_cache()
         assert updates["jax_compilation_cache_include_metadata_in_key"] is True
-        # where the checkout lies is not part of it: its files are named
-        # relative to it
-        import os
-        import re
-
-        pattern = updates["jax_hlo_source_file_canonicalization_regex"]
-        inside = os.path.join(warmup.CHECKOUT_ROOT, "perf", "harness.py")
-        assert re.sub(pattern, "", inside) == os.path.join("perf", "harness.py")
-        assert re.sub(pattern, "", "/opt/venv/lib/x.py") == "/opt/venv/lib/x.py"
-        assert os.path.isdir(os.path.join(warmup.CHECKOUT_ROOT, "replication_faster_rcnn_tpu"))
+        assert updates["jax_traceback_in_locations_limit"] == 0
         # both options exist on the installed jax, under these names
         for name in updates:
             assert hasattr(jax.config, name), name
