@@ -1241,16 +1241,23 @@ class LMConfig:
     this chip holds.
 
     A decoder of pre-norm layers. A layer's mixer by `layer_types`: rotary
-    grouped-query attention, windowed or full, or a gated delta-rule
+    grouped-query attention, windowed or full, a gated delta-rule
     recurrence over a `[linear_key_head_dim, linear_value_head_dim]` state a
-    value head (`linear_attention`: `ops/delta_rule.py`). Then a dense SwiGLU
+    value head (`linear_attention`: `ops/delta_rule.py`), or multi-head latent
+    attention (`latent_attention`: q through a rank-`q_lora_rank` latent,
+    keys and values through a rank-`kv_lora_rank` one, `num_heads` heads of
+    `qk_nope_head_dim + qk_rope_head_dim` for q and k and `v_head_dim` for
+    v, one rotary part shared by every head's key). Then a dense SwiGLU
     in the leading `num_dense_layers` and a routed expert layer after them
     (a router over `num_experts`, the top `experts_per_token`, one shared
     expert). `router_score` "sigmoid": the top by score + balance bias, the
     chosen scores normalised and scaled by `route_scale`; "softmax": the top
     by probability, the chosen normalised, no bias and no scale. The other
     switches say what a layer computes, each read at one site of
-    `models/lm.py`. Empty `layer_types` means the config is no sequence
+    `models/lm.py`. With `mtp_layers` 1 a multi-token-prediction module
+    (a layer of the last layer's kind over the trunk's output and the next
+    token's embedding) predicts the token after next, its loss weighted by
+    `mtp_weight`. Empty `layer_types` means the config is no sequence
     model's.
 
     The share. A layer is divided over `num_experts / experts_held` chips by
@@ -1268,7 +1275,7 @@ class LMConfig:
     num_kv_heads: int = 4
     head_size: int = 128
     sliding_window: int = 2048
-    layer_types: Tuple[str, ...] = ()  # "sliding_attention" | "full_attention" | "linear_attention"
+    layer_types: Tuple[str, ...] = ()  # "sliding_attention" | "full_attention" | "linear_attention" | "latent_attention"
     num_dense_layers: int = 1
     dense_width: int = 6144
     expert_width: int = 1024
@@ -1280,6 +1287,10 @@ class LMConfig:
     rms_norm_eps: float = 1e-5
     experts_held: int = 16
     first_expert: int = 0
+    # > 0: the experts' buffer holds this many times the pairs that even routing
+    # sends here, and the grouped products visit every row of it, the rows past the
+    # routed pairs at weight 0: the same work a step whatever the routing
+    experts_fixed_rows: float = 0.0
     # a `linear_attention` layer: key heads (q and k), value heads (v and the
     # output gate; a multiple of the key heads, value head j reads key head
     # j // (value / key)), their sizes, the taps of the causal convolution
@@ -1288,7 +1299,17 @@ class LMConfig:
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel: int = 4
+    # a `latent_attention` layer: the ranks of the query's and of the keys' and values' latents,
+    # a head's q/k part without and with the rotary embedding, a head's v
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
     rotary_fraction: float = 1.0  # the leading share of a head that the rotary embedding turns
+    rope_interleave: bool = False  # the rotary embedding turns pairs (2i, 2i + 1), not (i, i + r/2)
+    mtp_layers: int = 0  # multi-token-prediction modules (0 or 1): each predicts one token further
+    mtp_weight: float = 0.1  # the weight of their loss beside the next token's
     qk_norm: bool = False  # an RMSNorm a head on q and on k, before the rotary embedding
     attention_gate: bool = False  # sigmoid gate on the attention's output; wq is twice as wide
     norm_zero_centred: bool = False  # a norm's weight is 1 + w, w made at zero
@@ -1298,7 +1319,7 @@ class LMConfig:
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
-        kinds = ("sliding_attention", "full_attention", "linear_attention")
+        kinds = ("sliding_attention", "full_attention", "linear_attention", "latent_attention")
         if any(t not in kinds for t in self.layer_types):
             raise ValueError(f"lm.layer_types entries must be of {kinds}, got {self.layer_types!r}")
         if self.num_heads % self.num_kv_heads:
@@ -1319,6 +1340,10 @@ class LMConfig:
             )
         if self.router_score not in ("sigmoid", "softmax"):
             raise ValueError(f"lm.router_score must be 'sigmoid' or 'softmax', got {self.router_score!r}")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(f"lm.mtp_layers={self.mtp_layers}: one module or none")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(f"lm.qk_rope_head_dim={self.qk_rope_head_dim}: the rotary embedding turns pairs")
         if not 0.0 < self.rotary_fraction <= 1.0 or int(self.head_size * self.rotary_fraction) % 2:
             raise ValueError(
                 f"lm.rotary_fraction={self.rotary_fraction} of a head of {self.head_size}: want an even part of it"
@@ -1535,6 +1560,45 @@ CONFIGS = {
             linear_value_head_dim=16, rope_theta=1e7, rms_norm_eps=1e-6, rotary_fraction=0.25,
             qk_norm=True, attention_gate=True, norm_zero_centred=True, router_score="softmax",
             shared_expert_gate=True, embed_scale=False,
+        ),
+    ),
+    # 11. One chip's share of JoyAI-LLM-Flash (`model_type` joyai_llm_flash;
+    #    48 B parameters, 2.7 B active) trained over sixteen chips by expert
+    #    parallelism: 16 of 256 experts and 16,160 of 129,280 vocabulary rows
+    #    held here, the router whole; the leading dense layer and four expert
+    #    layers, every mixer multi-head latent attention, and the
+    #    multi-token-prediction module, which sits with the head; the other 35
+    #    layers lie on further pipeline stages. Widths as published
+    #    (perf/configs/joyai_flash_ep16.json has the source and every cut).
+    #    680.4 M parameters, 10.9 GB trained; one row of 8,192 tokens a step.
+    #    At random weights the router sends the held experts a share that
+    #    differs from seed to seed, up to three times the even one in a layer:
+    #    the experts' buffer is fixed at 15,872 rows (3.875 x the even 4,096;
+    #    five buffers at most, so the overflow stays the device loop) and
+    #    visited whole, the same work a step for every seed (PERF.md section 6).
+    "joyai_flash_ep16": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=8192, root_dir=""),
+        train=TrainConfig(batch_size=1, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(
+            vocab_rows=16_160, num_heads=32, num_kv_heads=32, layer_types=("latent_attention",) * 5,
+            dense_width=7168, expert_width=768, num_experts=256, experts_per_token=8, experts_held=16,
+            route_scale=2.5, rope_theta=3.2e7, rms_norm_eps=1e-6, rope_interleave=True, mtp_layers=1,
+            embed_scale=False, experts_fixed_rows=3.875,
+        ),
+    ),
+    # 12. The same layer pattern at sizes a CPU test runs: hidden 64, 4 heads,
+    #    latent ranks 24 (q) and 16 (keys and values), heads of 16 + 8 for q
+    #    and k and 16 for v, 16 experts of which 4 are held, top-2, rows of
+    #    128 tokens, the multi-token-prediction module.
+    "joyai_tiny": _cfg(
+        data=DataConfig(dataset="tokens", seq_len=128, root_dir=""),
+        train=TrainConfig(batch_size=2, lr=1e-5, weight_decay=0.0),
+        lm=LMConfig(
+            vocab_rows=64, hidden_size=64, num_heads=4, num_kv_heads=4, layer_types=("latent_attention",) * 3,
+            dense_width=96, expert_width=32, num_experts=16, experts_per_token=2, experts_held=4,
+            q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            route_scale=2.5, rope_theta=3.2e7, rms_norm_eps=1e-6, rope_interleave=True, mtp_layers=1,
+            embed_scale=False, experts_fixed_rows=2.0,
         ),
     ),
 }

@@ -1,6 +1,7 @@
 """The sequence model: a decoder of pre-norm layers whose mixer is, layer by
-layer, rotary grouped-query attention (windowed or full) or a gated delta-rule
-recurrence over a matrix state (`linear_attention`), with a dense SwiGLU in
+layer, rotary grouped-query attention (windowed or full), a gated delta-rule
+recurrence over a matrix state (`linear_attention`) or multi-head latent
+attention (`latent_attention`), with a dense SwiGLU in
 the leading layers and a routed expert layer in the others, told which
 experts it holds. What else a layer computes (norms on q and k, a rotary
 embedding over part of a head, a sigmoid gate on the attention's output, a
@@ -18,10 +19,28 @@ log-sum-exp): the backward pass recomputes the rest of the layer, but runs no
 attention forward a second time and builds none of its operands again. That
 costs `4 * (num_heads + num_kv_heads) * head_size + 4 * num_heads` bytes a
 token and layer whatever the preset (18,560 at the published widths: 1.52 GB
-for five layers of 16,384 tokens). A delta-rule layer keeps the function's
+for five layers of 16,384 tokens; a latent layer's q and k are 192 wide and
+its v and output 128, 40,960 bytes a token). A delta-rule layer keeps the function's
 output and the state at each segment's start (`ops.delta_rule`): its backward
 pass runs the recurrence backward only. The head and the loss run over blocks
 of tokens, so that no `[tokens, vocabulary]` array outlives its block.
+
+A `latent_attention` layer (`latent_block`, DeepSeek-V2's attention): the
+query through a latent, ``c_q = N(h W_qa)``, ``q = c_q W_qb``, a head's q its
+part without rotary embedding (`qk_nope_head_dim`) then its part with it
+(`qk_rope_head_dim`); keys and values through another, ``[c_kv | k_pe] = h
+W_kva``, ``[k_nope | v] = N(c_kv) W_kvb`` a head, and one ``k_pe`` turned by the
+rotary embedding and shared by every head's key. The attention runs over the
+decompressed heads, scores ``q.k / sqrt(nope + rope)``; what the latents and
+their norms cost runs under `frcnn.lm_latent`, nested in `frcnn.lm_attention`.
+
+The multi-token-prediction module (`mtp_loss`, `lm.mtp_layers` 1: DeepSeek-V3's
+depth 1): ``h'_i = [N_e(E[t_{i+1}]) | N_h(h_i)] W_eh`` with ``h_i`` the trunk's
+last output before its final norm, one layer of the last layer's kind over
+``h'`` (its own router, balance bias and held experts), then its own final
+norm, the shared head and the cross-entropy of the token after next. The
+step's loss is the next token's plus `mtp_weight` times that one; the
+gradient flows into the trunk and the shared embedding and head.
 
 A `linear_attention` layer (`delta_block`): one projection gives q, k (key
 heads), v and an output gate z (value heads), laid out by key-head group; a
@@ -46,7 +65,10 @@ pairs that even routing sends here, a step whose pairs pass it goes on through
 further buffers of that size, up to `tokens * min(experts_per_token,
 experts_held)` rows in all, the most the shapes allow. The grouped products
 visit only the row tiles that the pairs fill, so the step's time follows the
-pairs really routed here.
+pairs really routed here. With `experts_fixed_rows` the buffer holds that
+many times what even routing sends here and the products visit every row tile
+of it (the last held expert's group runs on over the rows past the pairs,
+which weigh 0), so a step's work is the same whatever the routing.
 
 With `router_score="softmax"` the chosen are the top of the probabilities
 over all experts, their weights the chosen probabilities over their sum, and
@@ -76,11 +98,12 @@ Array = jnp.ndarray
 BATCH_KEYS = ("tokens",)
 # counters of the step's metrics that the trainer writes to its tracer
 # (those a configuration's step has: `router_bias_absmax` where there is a balance bias,
-# the two of the delta rule where a layer runs it)
+# the two of the delta rule where a layer runs it, the multi-token-prediction module's loss where there is one)
 COUNTERS = (
     "expert_assignments", "expert_load_max_over_mean", "tokens_dropped", "router_bias_absmax",
-    "delta_state_absmax", "delta_decay_mean",
+    "delta_state_absmax", "delta_decay_mean", "mtp_loss",
 )
+MTP = "mtp"  # the multi-token-prediction module's subtree, and its balance bias's key
 HEAD_BLOCK = 2048  # tokens whose logits are alive at once
 USUAL_ROWS = 2  # the experts' usual buffer, in pairs that even routing sends here
 # Up to as many buffers the overflow is one `lax.switch` over how many the pairs reach, beyond a loop on
@@ -118,6 +141,12 @@ def is_linear(lm: LMConfig, i: int) -> bool:
     return lm.layer_types[i] == "linear_attention"
 
 
+def mtp_like(lm: LMConfig) -> int:
+    """The layer whose kind the multi-token-prediction module's layer has:
+    the last (DeepSeek-V3's module is a layer as its last layer is)."""
+    return len(lm.layer_types) - 1
+
+
 def kept(lm: LMConfig):
     """The policy of a layer's `jax.checkpoint`: `KEPT`, and where a layer
     runs the delta rule, `DELTA_KEPT` of that function's residuals too."""
@@ -138,6 +167,16 @@ def _attention_shapes(lm: LMConfig) -> Dict[str, Tuple[int, ...]]:
     return shapes
 
 
+def _latent_shapes(lm: LMConfig) -> Dict[str, Tuple[int, ...]]:
+    d, h = lm.hidden_size, lm.num_heads
+    return {
+        "wq_a": (d, lm.q_lora_rank), "q_a_norm": (lm.q_lora_rank,),
+        "wq_b": (lm.q_lora_rank, h * (lm.qk_nope_head_dim + lm.qk_rope_head_dim)),
+        "wkv_a": (d, lm.kv_lora_rank + lm.qk_rope_head_dim), "kv_a_norm": (lm.kv_lora_rank,),
+        "wkv_b": (lm.kv_lora_rank, h * (lm.qk_nope_head_dim + lm.v_head_dim)), "wo": (h * lm.v_head_dim, d),
+    }
+
+
 def _linear_shapes(lm: LMConfig) -> Dict[str, Tuple[int, ...]]:
     d = lm.hidden_size
     keys = lm.linear_num_key_heads * lm.linear_key_head_dim
@@ -150,27 +189,39 @@ def _linear_shapes(lm: LMConfig) -> Dict[str, Tuple[int, ...]]:
     }
 
 
+def _layer_shapes(lm: LMConfig, i: int) -> Dict[str, Any]:
+    d = lm.hidden_size
+    layer: Dict[str, Any] = {"attn_norm": {"scale": (d,)}, "ffn_norm": {"scale": (d,)}}
+    if is_linear(lm, i):
+        layer["linear"] = _linear_shapes(lm)
+    elif lm.layer_types[i] == "latent_attention":
+        layer["attn"] = _latent_shapes(lm)
+    else:
+        layer["attn"] = _attention_shapes(lm)
+    if is_dense(lm, i):
+        layer["ffn"] = _swiglu_shapes(d, lm.dense_width)
+    else:
+        layer["router"] = {"kernel": (d, lm.num_experts)}
+        layer["experts"] = _swiglu_shapes(d, lm.expert_width, (lm.experts_held,))
+        layer["shared"] = _swiglu_shapes(d, lm.expert_width)
+        if lm.shared_expert_gate:
+            layer["shared_gate"] = {"kernel": (d, 1)}
+    return layer
+
+
 def param_shapes(lm: LMConfig) -> Dict[str, Any]:
     """The parameter tree as shapes: the gradient leaves, and no other."""
     d = lm.hidden_size
     tree: Dict[str, Any] = {"embed": {"table": (lm.vocab_rows, d)}}
     for i in range(len(lm.layer_types)):
-        layer: Dict[str, Any] = {"attn_norm": {"scale": (d,)}, "ffn_norm": {"scale": (d,)}}
-        if is_linear(lm, i):
-            layer["linear"] = _linear_shapes(lm)
-        else:
-            layer["attn"] = _attention_shapes(lm)
-        if is_dense(lm, i):
-            layer["ffn"] = _swiglu_shapes(d, lm.dense_width)
-        else:
-            layer["router"] = {"kernel": (d, lm.num_experts)}
-            layer["experts"] = _swiglu_shapes(d, lm.expert_width, (lm.experts_held,))
-            layer["shared"] = _swiglu_shapes(d, lm.expert_width)
-            if lm.shared_expert_gate:
-                layer["shared_gate"] = {"kernel": (d, 1)}
-        tree[layer_name(i)] = layer
+        tree[layer_name(i)] = _layer_shapes(lm, i)
     tree["final_norm"] = {"scale": (d,)}
     tree["head"] = {"kernel": (d, lm.vocab_rows)}
+    if lm.mtp_layers:
+        tree[MTP] = {
+            "enorm": {"scale": (d,)}, "hnorm": {"scale": (d,)}, "eh_proj": {"kernel": (2 * d, d)},
+            "block": _layer_shapes(lm, mtp_like(lm)), "final_norm": {"scale": (d,)},
+        }
     return tree
 
 
@@ -189,14 +240,15 @@ def _init_vector(lm: LMConfig, name: str, shape: Tuple[int, ...], key: Array) ->
     if name == "dt_bias":
         dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
         return dt + jnp.log(-jnp.expm1(-dt))
-    if lm.norm_zero_centred and name in ("scale", "q_norm", "k_norm"):
+    if lm.norm_zero_centred and name in ("scale", "q_norm", "k_norm", "q_a_norm", "kv_a_norm"):
         return jnp.zeros(shape, jnp.float32)
     return jnp.ones(shape, jnp.float32)
 
 
 def init(config: FasterRCNNConfig, rng: Array) -> Tuple[Any, Any]:
     """(params, batch_stats): matrices N(0, 0.02), vectors by `_init_vector`,
-    the balance bias of every expert layer at zero where there is one."""
+    the balance bias of every expert layer (the multi-token-prediction
+    module's among them) at zero where there is one."""
     lm = config.lm
     shapes = param_shapes(lm)
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes, is_leaf=lambda x: isinstance(x, tuple))
@@ -211,6 +263,8 @@ def init(config: FasterRCNNConfig, rng: Array) -> Tuple[Any, Any]:
         layer_name(i): jnp.zeros((lm.num_experts,), jnp.float32)
         for i in range(len(lm.layer_types)) if not is_dense(lm, i)
     }
+    if lm.mtp_layers and not is_dense(lm, mtp_like(lm)):
+        bias[MTP] = jnp.zeros((lm.num_experts,), jnp.float32)
     return jax.tree_util.tree_unflatten(treedef, made), {"router_bias": bias}
 
 
@@ -222,11 +276,12 @@ def rms_norm(x: Array, scale: Array, eps: float) -> Array:
     return (xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * scale).astype(x.dtype)
 
 
-def rotary(x: Array, theta: float, fraction: float = 1.0) -> Array:
+def rotary(x: Array, theta: float, fraction: float = 1.0, interleave: bool = False) -> Array:
     """Rotary embedding over the leading ``r = fraction * d`` of each head of
-    ``[B, T, heads, d]``, pairs ``(i, i + r/2)``, positions 0..T-1 along the
-    row; the rest of the head is left as it is (its angle is nought). ``x cos
-    + swap(x) sin`` with ``swap`` the turned part's two halves exchanged. The
+    ``[B, T, heads, d]``, pairs ``(i, i + r/2)`` (``interleave``: pairs ``(2i,
+    2i + 1)``, the partner of lane ``i`` lane ``i xor 1``), positions 0..T-1
+    along the row; the rest of the head is left as it is (its angle is
+    nought). ``x cos + swap(x) sin`` with ``swap`` each lane's partner. The
     exchange is a product with a 0/1 matrix (exact), so that every array
     keeps the head's whole width in its lanes; slices of half a head cost
     layout copies on the TPU."""
@@ -234,12 +289,17 @@ def rotary(x: Array, theta: float, fraction: float = 1.0) -> Array:
     r = int(d * fraction)
     freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     still = [jnp.zeros((d - r,), jnp.float32)] if r < d else []
-    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.concatenate([freq, freq] + still)[None, :]
-    first = jnp.arange(d) < r // 2
+    turned = [jnp.repeat(freq, 2)] if interleave else [freq, freq]
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * jnp.concatenate(turned + still)[None, :]
+    # the lane of a pair that takes minus the sine
+    first = jnp.arange(d) % 2 == 0 if interleave else jnp.arange(d) < r // 2
     cos = jnp.cos(angle)[None, :, None, :]
     sin = jnp.where(first, -jnp.sin(angle), jnp.sin(angle))[None, :, None, :]
     at = jnp.arange(d)[:, None]
-    partner = (jnp.arange(d)[None, :] + r // 2) % r
+    if interleave:
+        partner = jnp.bitwise_xor(jnp.arange(d)[None, :], 1)
+    else:
+        partner = (jnp.arange(d)[None, :] + r // 2) % r
     if r < d:
         partner = jnp.where(jnp.arange(d)[None, :] < r, partner, jnp.arange(d)[None, :])
     swap = (at == partner).astype(x.dtype)
@@ -281,6 +341,30 @@ def attention_block(lm: LMConfig, p: Dict[str, Any], x: Array, windowed: bool) -
     if lm.attention_gate:
         o = (o.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(o.dtype)
     return _mm(o.reshape(b, t, lm.num_heads * lm.head_size), p["attn"]["wo"])
+
+
+def latent_block(lm: LMConfig, p: Dict[str, Any], x: Array) -> Array:
+    """Multi-head latent attention over ``x`` ``[B, T, D]``: the latents,
+    their norms and the rotary parts under `frcnn.lm_latent`, the attention
+    over the decompressed heads under `frcnn.lm_attn_core`."""
+    b, t, _ = x.shape
+    w = p["attn"]
+    n, nope, rope, dv = lm.num_heads, lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim
+    h = rms_norm(x, norm_weight(lm, p["attn_norm"]["scale"]), lm.rms_norm_eps)
+    with jax.named_scope(stages.LM_LATENT):
+        c_q = rms_norm(_mm(h, w["wq_a"]), norm_weight(lm, w["q_a_norm"]), lm.rms_norm_eps)
+        q = _mm(c_q, w["wq_b"]).reshape(b, t, n, nope + rope)
+        c_kv, k_pe = jnp.split(_mm(h, w["wkv_a"]), [lm.kv_lora_rank], axis=-1)
+        kv = _mm(rms_norm(c_kv, norm_weight(lm, w["kv_a_norm"]), lm.rms_norm_eps), w["wkv_b"]).reshape(b, t, n, nope + dv)
+        turned = lambda y: rotary(y, lm.rope_theta, 1.0, lm.rope_interleave)
+        q = jnp.concatenate([q[..., :nope], turned(q[..., nope:])], axis=-1)
+        # one rotary part of the key, shared by every head
+        k_pe = jnp.broadcast_to(turned(k_pe.reshape(b, t, 1, rope)), (b, t, n, rope))
+        k = jnp.concatenate([kv[..., :nope], k_pe], axis=-1)
+        v = kv[..., nope:]
+    with jax.named_scope(stages.LM_ATTN_CORE):
+        o = attention(q, k, v, None, (nope + rope) ** -0.5)
+    return _mm(o.reshape(b, t, n * dv), w["wo"])
 
 
 def causal_conv(x: Array, taps: Array) -> Array:
@@ -350,13 +434,14 @@ def route(lm: LMConfig, kernel: Array, bias: Array, h: Array):
 
 def buffer_rows(lm: LMConfig, n_tokens: int) -> Tuple[int, int]:
     """(rows of the experts' buffer, buffers that hold every pair the shapes
-    allow to land here). A buffer is `USUAL_ROWS` times what even routing
-    sends here, up to the grouped product's row tile; a step whose pairs pass
-    the first buffer goes through as many more as its pairs fill."""
+    allow to land here). A buffer is `USUAL_ROWS` (or `lm.experts_fixed_rows`)
+    times what even routing sends here, up to the grouped product's row tile;
+    a step whose pairs pass the first buffer goes through as many more as its
+    pairs fill."""
     most = n_tokens * min(lm.experts_per_token, lm.experts_held)
     even = n_tokens * lm.experts_per_token * lm.experts_held / lm.num_experts
     up = lambda rows: -(-int(rows) // 128) * 128
-    rows = min(up(USUAL_ROWS * even), up(most))
+    rows = min(up((lm.experts_fixed_rows or USUAL_ROWS) * even), up(most))
     return rows, -(-most // rows)
 
 
@@ -392,6 +477,10 @@ def experts_on_buffer(lm: LMConfig, e: Dict[str, Array], h: Array, key: Array, p
     key, pair = fit(key, lm.experts_held), fit(pair, 0)
     ends = jnp.cumsum(sizes)
     here = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(ends - sizes, lo), 0, None)
+    if lm.experts_fixed_rows:
+        # the last held expert's group runs on to the buffer's end: its rows past the
+        # pairs weigh 0, so every row tile is visited and nothing they give is kept
+        here = here.at[-1].add(rows - jnp.sum(here))
     token = pair // k
     weight = jnp.where(key < lm.experts_held, weights.reshape(-1)[pair], 0.0)
     # gathered through float32, so that the rows' gradient adds up by token in float32
@@ -478,6 +567,9 @@ def layer(lm: LMConfig, i: int, p: Dict[str, Any], bias, x: Array):
         with jax.named_scope(stages.LM_LINEAR_ATTENTION):
             mixed, mixer = delta_block(lm, p, x)
             x = x + mixed
+    elif lm.layer_types[i] == "latent_attention":
+        with jax.named_scope(stages.LM_ATTENTION):
+            x = x + latent_block(lm, p, x)
     else:
         with jax.named_scope(stages.LM_ATTENTION):
             x = x + attention_block(lm, p, x, lm.layer_types[i] == "sliding_attention")
@@ -498,13 +590,16 @@ def next_bias(lm: LMConfig, bias: Array, counts: Array) -> Array:
     return moved - jnp.mean(moved)
 
 
-def head_loss(lm: LMConfig, params: Dict[str, Any], x: Array, tokens: Array) -> Array:
-    """Mean cross-entropy of the next token over every position of a row
-    but its last, the logits a block of tokens at a time."""
+def head_loss(lm: LMConfig, params: Dict[str, Any], x: Array, tokens: Array, norm=None, ahead: int = 1) -> Array:
+    """Mean cross-entropy of the token ``ahead`` positions on (the next one
+    unless told) over every position of a row that has one, the logits a
+    block of tokens at a time; ``norm`` the final norm's weight, the trunk's
+    unless told."""
     b, t, d = x.shape
-    x = rms_norm(x, norm_weight(lm, params["final_norm"]["scale"]), lm.rms_norm_eps)
-    target = jnp.concatenate([tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
-    counted = jnp.broadcast_to(jnp.arange(t) < t - 1, (b, t))
+    norm = params["final_norm"]["scale"] if norm is None else norm
+    x = rms_norm(x, norm_weight(lm, norm), lm.rms_norm_eps)
+    target = jnp.concatenate([tokens[:, ahead:], jnp.zeros((b, ahead), tokens.dtype)], axis=1)
+    counted = jnp.broadcast_to(jnp.arange(t) < t - ahead, (b, t))
     n = b * t
     block = HEAD_BLOCK if n % HEAD_BLOCK == 0 else n
     kernel = params["head"]["kernel"]
@@ -518,7 +613,34 @@ def head_loss(lm: LMConfig, params: Dict[str, Any], x: Array, tokens: Array) -> 
 
     blocks = lambda a: a.reshape((n // block, block) + a.shape[2:])
     sums = jax.lax.map(lambda xyw: nll(*xyw), (blocks(x), blocks(target), blocks(counted)))
-    return jnp.sum(sums) / (b * (t - 1))
+    return jnp.sum(sums) / (b * (t - ahead))
+
+
+def embed(lm: LMConfig, params: Dict[str, Any], tokens: Array, dtype) -> Array:
+    x = params["embed"]["table"][tokens]
+    return (x * (lm.hidden_size ** 0.5) if lm.embed_scale else x).astype(dtype)
+
+
+def checkpointed_layer(lm: LMConfig, i: int):
+    """Layer ``i`` under the `jax.checkpoint` that keeps what `kept` names."""
+    return jax.checkpoint(lambda p, b, x: layer(lm, i, p, b, x), policy=kept(lm))
+
+
+def mtp_loss(lm: LMConfig, params: Dict[str, Any], bias, x: Array, tokens: Array):
+    """The multi-token-prediction module's loss over the trunk's output ``x``
+    (before its final norm), and its layer's counters: ``h'_i = [N_e(E[t_{i +
+    1}]) | N_h(x_i)] W_eh``, a layer of the last layer's kind over ``h'``, then
+    its final norm, the shared head and the cross-entropy of ``t_{i + 2}``. The
+    row's last position is given id 0 for its next token: its outputs enter
+    no loss (the attention is causal)."""
+    p = params[MTP]
+    b = tokens.shape[0]
+    following = jnp.concatenate([tokens[:, 1:], jnp.zeros((b, 1), tokens.dtype)], axis=1)
+    e = rms_norm(embed(lm, params, following, x.dtype), norm_weight(lm, p["enorm"]["scale"]), lm.rms_norm_eps)
+    h = rms_norm(x, norm_weight(lm, p["hnorm"]["scale"]), lm.rms_norm_eps)
+    h = _mm(jnp.concatenate([e, h], axis=-1), p["eh_proj"]["kernel"])
+    y, stats = checkpointed_layer(lm, mtp_like(lm))(p["block"], bias.get(MTP), h)
+    return head_loss(lm, params, y, tokens, p["final_norm"]["scale"], ahead=2), stats
 
 
 def losses(
@@ -533,21 +655,19 @@ def losses(
 ) -> Tuple[Array, Tuple[Dict[str, Array], Any]]:
     """Forward + loss. Returns (total, (metrics, new_batch_stats)), as the
     detector's `compute_losses` does; `rng` is not used: the model samples
-    nothing. Each stage runs under its scope of `telemetry/stages.py`."""
+    nothing. Each stage runs under its scope of `telemetry/stages.py`; the
+    multi-token-prediction module's own work under `frcnn.lm_mtp`, its layer
+    under the layers' scopes."""
     del model, rng, train, train_resolution
     lm = config.lm
     dtype = jnp.dtype(config.model.compute_dtype)
     tokens = batch["tokens"]
     with jax.named_scope(stages.LM_EMBED):
-        x = params["embed"]["table"][tokens]
-        x = (x * (lm.hidden_size ** 0.5) if lm.embed_scale else x).astype(dtype)
+        x = embed(lm, params, tokens, dtype)
     bias = batch_stats.get("router_bias", {})
     new_bias, per_layer, mixers = {}, [], []
-    for i in range(len(lm.layer_types)):
-        name = layer_name(i)
-        x, stats = jax.checkpoint(lambda p, b, x, i=i: layer(lm, i, p, b, x), policy=kept(lm))(
-            params[name], bias.get(name), x
-        )
+
+    def counted(name, stats):
         if stats is not None:
             stats = jax.lax.stop_gradient(stats)
             if "decay_mean" in stats:
@@ -558,9 +678,19 @@ def losses(
                 with jax.named_scope(stages.LM_ROUTER):
                     new_bias[name] = next_bias(lm, bias[name], counts)
             per_layer.append(stats)
+
+    for i in range(len(lm.layer_types)):
+        name = layer_name(i)
+        x, stats = checkpointed_layer(lm, i)(params[name], bias.get(name), x)
+        counted(name, stats)
     with jax.named_scope(stages.LM_HEAD):
         loss = head_loss(lm, params, x, tokens)
     metrics = {"loss": loss, "nll_loss": loss}
+    if lm.mtp_layers:
+        with jax.named_scope(stages.LM_MTP):
+            further, stats = mtp_loss(lm, params, bias, x, tokens)
+        counted(MTP, stats)
+        metrics.update(loss=loss + lm.mtp_weight * further, mtp_loss=further)
     if per_layer:
         with jax.named_scope(stages.LM_ROUTER):
             stacked = {k: jnp.stack([s[k] for s in per_layer]) for k in per_layer[0]}
@@ -577,4 +707,4 @@ def losses(
                 delta_state_absmax=jnp.max(jnp.stack([m["state_absmax"] for m in mixers])),
                 delta_decay_mean=jnp.mean(jnp.stack([m["decay_mean"] for m in mixers])),
             )
-    return loss, (metrics, {"router_bias": new_bias} if has_balance_bias(lm) else {})
+    return metrics["loss"], (metrics, {"router_bias": new_bias} if has_balance_bias(lm) else {})

@@ -21,6 +21,10 @@ builds the mask; the others are plain products.
 A row whose length is no multiple of the key block is padded at its end: a
 padded key is later than every real query, and a padded query's output is
 cut off again.
+
+A value (and so the output) may be narrower or wider than a query and a key
+(latent attention: 192 against 128); the kernels take each width as the
+operand has it.
 """
 
 from __future__ import annotations
@@ -208,36 +212,41 @@ def _params():
     )
 
 
-def _specs(walk: _Walk, rows: int, d: int):
-    """Block specs of a query tile, a key block and a tile's statistics, for
-    a grid (n, query tile, key step) and for a grid (n, key block, query step)."""
+def _specs(walk: _Walk, rows: int, d: int, dv: int):
+    """Block specs of a query tile (``d`` wide; ``out`` one of the output's,
+    ``dv`` wide), a key block (``values`` one of v's) and a tile's
+    statistics, for a grid (n, query tile, key step) and for a grid (n, key
+    block, query step)."""
     kv_of = lambda i, j: jnp.minimum(walk.first_kv(i) + j, walk.last_kv(i))
     q_of = lambda c, j: jnp.minimum(walk.first_q(c) + j, walk.last_q(c))
-    by_q = {
-        "tile": pl.BlockSpec((None, None, rows, d), lambda n, i, j: (n, i, 0, 0)),
-        "keys": pl.BlockSpec((None, walk.bkv, d), lambda n, i, j: (n, kv_of(i, j), 0)),
-        "stat": pl.BlockSpec((None, None, 1, rows), lambda n, i, j: (n, i, 0, 0)),
-    }
-    by_kv = {
-        "tile": pl.BlockSpec((None, None, rows, d), lambda n, c, j: (n, q_of(c, j), 0, 0)),
-        "keys": pl.BlockSpec((None, walk.bkv, d), lambda n, c, j: (n, c, 0)),
-        "stat": pl.BlockSpec((None, None, 1, rows), lambda n, c, j: (n, q_of(c, j), 0, 0)),
-    }
+    by_q, by_kv = {}, {}
+    for name, width in (("tile", d), ("out", dv)):
+        by_q[name] = pl.BlockSpec((None, None, rows, width), lambda n, i, j: (n, i, 0, 0))
+        by_kv[name] = pl.BlockSpec((None, None, rows, width), lambda n, c, j: (n, q_of(c, j), 0, 0))
+    for name, width in (("keys", d), ("values", dv)):
+        by_q[name] = pl.BlockSpec((None, walk.bkv, width), lambda n, i, j: (n, kv_of(i, j), 0))
+        by_kv[name] = pl.BlockSpec((None, walk.bkv, width), lambda n, c, j: (n, c, 0))
+    by_q["stat"] = pl.BlockSpec((None, None, 1, rows), lambda n, i, j: (n, i, 0, 0))
+    by_kv["stat"] = pl.BlockSpec((None, None, 1, rows), lambda n, c, j: (n, q_of(c, j), 0, 0))
     return by_q, by_kv
 
 
 def _forward(walk: _Walk, q: Array, k: Array, v: Array):
-    """``q``: ``[n, nq, rows, d]``; ``k``, ``v``: ``[n, t, d]``. Returns the
-    output as ``q`` is laid out and the log-sum-exp ``[n, nq, 1, rows]``."""
+    """``q``: ``[n, nq, rows, d]``; ``k``: ``[n, t, d]``; ``v``: ``[n, t,
+    dv]``. Returns the output ``[n, nq, rows, dv]`` and the log-sum-exp ``[n,
+    nq, 1, rows]``."""
     n, nq, rows, d = q.shape
-    by_q, _ = _specs(walk, rows, d)
+    dv = v.shape[-1]
+    by_q, _ = _specs(walk, rows, d, dv)
     return pl.pallas_call(
         functools.partial(_forward_kernel, walk),
         grid=(n, nq, walk.kv_steps),
-        in_specs=[by_q["tile"], by_q["keys"], by_q["keys"]],
-        out_specs=[by_q["tile"], by_q["stat"]],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype), jax.ShapeDtypeStruct((n, nq, 1, rows), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
+        in_specs=[by_q["tile"], by_q["keys"], by_q["values"]],
+        out_specs=[by_q["out"], by_q["stat"]],
+        out_shape=[
+            jax.ShapeDtypeStruct((n, nq, rows, dv), q.dtype), jax.ShapeDtypeStruct((n, nq, 1, rows), jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32)] * 2 + [pltpu.VMEM((rows, dv), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret_mode(),
         name="attention_forward",
@@ -246,12 +255,13 @@ def _forward(walk: _Walk, q: Array, k: Array, v: Array):
 
 def _backward(walk: _Walk, q: Array, k: Array, v: Array, o: Array, lse: Array, do: Array):
     n, nq, rows, d = q.shape
-    by_q, by_kv = _specs(walk, rows, d)
+    dv = v.shape[-1]
+    by_q, by_kv = _specs(walk, rows, d, dv)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, walk),
         grid=(n, nq, walk.kv_steps),
-        in_specs=[by_q["tile"], by_q["keys"], by_q["keys"], by_q["tile"], by_q["stat"], by_q["stat"]],
+        in_specs=[by_q["tile"], by_q["keys"], by_q["values"], by_q["out"], by_q["stat"], by_q["stat"]],
         out_specs=by_q["tile"],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((rows, 1), jnp.float32)] * 2 + [pltpu.VMEM((rows, d), jnp.float32)],
@@ -262,10 +272,10 @@ def _backward(walk: _Walk, q: Array, k: Array, v: Array, o: Array, lse: Array, d
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, walk),
         grid=(n, walk.nkv, walk.q_steps),
-        in_specs=[by_kv["tile"], by_kv["keys"], by_kv["keys"], by_kv["tile"], by_kv["stat"], by_kv["stat"]],
-        out_specs=[by_kv["keys"], by_kv["keys"]],
+        in_specs=[by_kv["tile"], by_kv["keys"], by_kv["values"], by_kv["out"], by_kv["stat"], by_kv["stat"]],
+        out_specs=[by_kv["keys"], by_kv["values"]],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((walk.bkv, d), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((walk.bkv, d), jnp.float32), pltpu.VMEM((walk.bkv, dv), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret_mode(),
         name="attention_backward_dkv",
@@ -290,24 +300,29 @@ def _tiles_attention_bwd(walk, res, do):
 _tiles_attention.defvjp(_tiles_attention_fwd, _tiles_attention_bwd)
 
 
-def attention(q: Array, k: Array, v: Array, window: Optional[int] = None) -> Array:
-    """``softmax(q k^T / sqrt(d) + mask) v`` a row at a time.
+def attention(
+    q: Array, k: Array, v: Array, window: Optional[int] = None, scale: Optional[float] = None
+) -> Array:
+    """``softmax(scale q k^T + mask) v`` a row at a time, ``scale`` ``1 /
+    sqrt(d)`` unless the caller gives it (a caller that pads q and k with
+    noughts keeps the scale of their width before).
 
-    ``q``: ``[B, T, H, d]``; ``k``, ``v``: ``[B, T, KV, d]`` with ``H`` a
-    multiple of ``KV`` (query head ``h`` reads KV head ``h // (H / KV)``).
-    Returns ``[B, T, H, d]`` in ``q``'s dtype; the softmax is in float32.
+    ``q``, ``k``: ``[B, T, H, d]``, ``[B, T, KV, d]``; ``v``: ``[B, T, KV,
+    dv]``, with ``H`` a multiple of ``KV`` (query head ``h`` reads KV head
+    ``h // (H / KV)``). Returns ``[B, T, H, dv]`` in ``q``'s dtype; the
+    softmax is in float32.
     """
     b, t, h, d = q.shape
-    kv = k.shape[2]
+    kv, dv = k.shape[2], v.shape[-1]
     group = h // kv
     walk = _Walk(t, window)
     pad = [(0, 0), (0, walk.t - t), (0, 0), (0, 0)]
-    q = jnp.pad((q * (d ** -0.5)).astype(q.dtype), pad)
+    q = jnp.pad((q * (d ** -0.5 if scale is None else scale)).astype(q.dtype), pad)
     k, v = jnp.pad(k, pad), jnp.pad(v, pad)
     # a tile: the group's heads for BLOCK_Q positions, a head's positions together
     q = q.reshape(b, walk.nq, walk.bq, kv, group, d)
     q = jnp.transpose(q, (0, 3, 1, 4, 2, 5)).reshape(b * kv, walk.nq, group * walk.bq, d)
-    k, v = (jnp.transpose(x, (0, 2, 1, 3)).reshape(b * kv, walk.t, d) for x in (k, v))
+    k, v = (jnp.transpose(x, (0, 2, 1, 3)).reshape(b * kv, walk.t, x.shape[-1]) for x in (k, v))
     o = _tiles_attention(walk, q, k, v)
-    o = jnp.transpose(o.reshape(b, kv, walk.nq, group, walk.bq, d), (0, 2, 4, 1, 3, 5))
-    return o.reshape(b, walk.t, h, d)[:, :t]
+    o = jnp.transpose(o.reshape(b, kv, walk.nq, group, walk.bq, dv), (0, 2, 4, 1, 3, 5))
+    return o.reshape(b, walk.t, h, dv)[:, :t]

@@ -28,6 +28,7 @@ UPDATE = "frcnn.update"  # gradient exchange and rounding, the guard, the optimi
 LM_EMBED = "frcnn.lm_embed"  # the embedding rows and their multiplier
 LM_ATTENTION = "frcnn.lm_attention"  # norm, q/k/v, rotary embedding, the output projection
 LM_ATTN_CORE = "frcnn.lm_attn_core"  # the attention function alone, inside lm_attention
+LM_LATENT = "frcnn.lm_latent"  # a latent layer's latents, their norms, the rotary parts, inside lm_attention
 LM_LINEAR_ATTENTION = "frcnn.lm_linear_attention"  # a delta-rule layer's norm, projections, convolution, gates, gated norm
 LM_DELTA_CORE = "frcnn.lm_delta_core"  # the gated delta rule alone, inside lm_linear_attention
 LM_FFN = "frcnn.lm_ffn"  # norm, the dense SwiGLU or the shared expert
@@ -35,11 +36,13 @@ LM_ROUTER = "frcnn.lm_router"  # scores, top-k, the sort by expert, counts, the 
 LM_EXPERTS = "frcnn.lm_experts"  # rows gathered by expert, the weighted combine by token
 LM_EXPERT_MM = "frcnn.lm_expert_mm"  # the grouped products alone, inside lm_experts
 LM_HEAD = "frcnn.lm_head"  # last norm, output head, cross-entropy
+LM_MTP = "frcnn.lm_mtp"  # the multi-token-prediction module's norms, projection, head pass and loss (its layer apart)
 
 LM_STAGES = (
     LM_EMBED,
     LM_ATTENTION,
     LM_ATTN_CORE,
+    LM_LATENT,
     LM_LINEAR_ATTENTION,
     LM_DELTA_CORE,
     LM_FFN,
@@ -47,6 +50,7 @@ LM_STAGES = (
     LM_EXPERTS,
     LM_EXPERT_MM,
     LM_HEAD,
+    LM_MTP,
     UPDATE,
 )
 

@@ -60,35 +60,41 @@ def pytest_sessionstart(session):
 # statement is said again in tests/perf_yardstick/test_cells_of_record.py.
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PINNED_TO_ONE_CELL = {
-    # (file, test) -> (its parameters, the statement, what the failing frame's locals hold, the error it raises)
-    ("test_stage_metrics.py", "test_the_stages_manifest_is_sound_and_adds_only_the_ten"): (
+    # (file, test) -> one entry for each of its parameter sets that is pinned: (its parameters, the
+    # statement, what the failing frame's locals hold, the error it raises)
+    ("test_stage_metrics.py", "test_the_stages_manifest_is_sound_and_adds_only_the_ten"): [(
         {}, 'assert [p["name"] for p in record["per_layer"]][-10:] == list(NEW_METRICS)', {}, AssertionError,
-    ),
-    ("test_perf_benchmark.py", "test_manifests_are_sound_and_their_files_exist"): (
+    )],
+    ("test_perf_benchmark.py", "test_manifests_are_sound_and_their_files_exist"): [(
         {"path": os.path.join(_ROOT, "BENCHMARK.json")},
         'assert set(cell.config["limits"]) >= limits', {"name": "trinity_ep8.packed8k"}, AssertionError,
-    ),
+    )],
     # PR 33: `test_cells_of_record.py::LIMITS` is keyed by reference module and knows two; the third
     # cell's case stops where it looks its module up. What the case says beside that is said for the
     # third cell in tests/perf_yardstick/hybrid/test_hybrid_cell.py, the limit names read from the
-    # reference module itself (PERF.md section 7, "benchmark files", item 6)
-    ("test_cells_of_record.py", "test_a_cell_of_record_has_its_files_its_limits_and_the_benchmarks_own_modules"): (
-        {"name": "qwen3next_ep16.packed16k"},
-        'assert set(cell.config["limits"]) >= LIMITS[cell.config["reference"]]', {"name": "qwen3next_ep16.packed16k"}, KeyError,
-    ),
-    # PR 35: the five set-up metrics, which every cell reports, the detector's among them, stand at the
-    # end of the list, behind the ten and behind the hybrid cell's two: a new entry goes at the end of
-    # its list, since one inserted among the entries there reads as an edit of them (PERF.md section 7,
-    # "benchmark files", item 7). What the two tests say beside the pinned statement is said again by
-    # name in tests/test_setup_spans.py (TestTheBenchmarksEntries)
-    ("test_cells_of_record.py", "test_what_was_added_after_the_ten_is_read_by_no_detector_cell"): (
+    # reference module itself (PERF.md section 7, "benchmark files", item 6). The fourth cell's case
+    # (the latent-attention model's) stops at the same lookup; tests/perf_yardstick/latent/test_latent_cell.py
+    # says the rest of it
+    ("test_cells_of_record.py", "test_a_cell_of_record_has_its_files_its_limits_and_the_benchmarks_own_modules"): [
+        (
+            {"name": cell},
+            'assert set(cell.config["limits"]) >= LIMITS[cell.config["reference"]]', {"name": cell}, KeyError,
+        )
+        for cell in ("qwen3next_ep16.packed16k", "joyai_ep16.packed8k")
+    ],
+    # the five set-up metrics, which every cell reports, the detector's among them, stand
+    # behind the ten and behind the hybrid cell's two: a new entry goes at the end of its list, since
+    # one inserted among the entries there reads as an edit of them (PERF.md section 7, "benchmark
+    # files", item 7). What the two tests say beside the pinned statement is said again by name in
+    # tests/test_setup_spans.py (TestTheBenchmarksEntries)
+    ("test_cells_of_record.py", "test_what_was_added_after_the_ten_is_read_by_no_detector_cell"): [(
         {}, 'assert all("r18c4.resident" not in p["workloads"] for p in behind), [p["name"] for p in behind]', {},
         AssertionError,
-    ),
-    ("test_hybrid_cell.py", "test_the_manifests_are_sound_and_the_cell_of_record_is_as_issue_33_names_it"): (
+    )],
+    ("test_hybrid_cell.py", "test_the_manifests_are_sound_and_the_cell_of_record_is_as_issue_33_names_it"): [(
         {}, 'assert [p["name"] for p in record["per_layer"]][-2:] == ["stage_linear_attention_ms", "delta_rule_roofline"]',
         {}, AssertionError,
-    ),
+    )],
 }
 
 
@@ -106,10 +112,11 @@ def _failed_at(error, statement, local_values):
 
 
 def _pinned(item):
-    entry = _PINNED_TO_ONE_CELL.get((os.path.basename(str(item.path)), item.originalname))
-    if entry is None or entry[0] != (item.callspec.params if hasattr(item, "callspec") else {}):
-        return None
-    return entry[1:]
+    params = item.callspec.params if hasattr(item, "callspec") else {}
+    for entry in _PINNED_TO_ONE_CELL.get((os.path.basename(str(item.path)), item.originalname), []):
+        if entry[0] == params:
+            return entry[1:]
+    return None
 
 
 import pytest  # noqa: E402

@@ -284,7 +284,8 @@ class TestHybridStageScopes:
         text = jax.jit(make_train_step(None, cfg, tx)).lower(state, batch).as_text(debug_info=True)
         return set(re.findall(r'loc\("([^"]*/[^"]*)"\(', text))  # the operations' names, as tests/test_stage_scopes.py reads them
 
-    @pytest.mark.parametrize("scope", [s for s in stages.LM_STAGES if s != stages.UPDATE])
+    # the latent layer's and the multi-token-prediction module's are in the latent preset's: tests/test_lm_latent.py
+    @pytest.mark.parametrize("scope", [s for s in stages.LM_STAGES if s not in (stages.UPDATE, stages.LM_LATENT, stages.LM_MTP)])
     def test_every_stage_is_in_the_lowered_step_forward_and_backward(self, names, scope):
         held = [n for n in names if scope in n]
         assert [n for n in held if "transpose(" not in n], scope
@@ -309,7 +310,7 @@ def test_trainer_trains_the_hybrid_preset_strictly_and_writes_the_counters_its_s
     assert all(np.isfinite(losses)) and trainer.strict.report()["programs"]["train_step"]["recompiles_after_warmup"] == 0
     with open(tmp_path / "tel" / "trace.json") as f:
         counters = {e["name"] for e in json.load(f)["traceEvents"] if e.get("ph") == "C" and e["name"].startswith("lm/")}
-    assert counters == {f"lm/{k}" for k in lm.COUNTERS if k != "router_bias_absmax"}
+    assert counters == {f"lm/{k}" for k in lm.COUNTERS if k not in ("router_bias_absmax", "mtp_loss")}
 
 
 def test_cli_train_runs_the_hybrid_preset(tmp_path):

@@ -26,7 +26,7 @@ if ROOT not in sys.path:
 from perf import harness, manifest  # noqa: E402
 
 READERS = ("setup_before_trainer_s", "setup_trainer_s", "setup_jit_s", "setup_cache_misses", "setup_unattributed_s")
-CELLS = ["r18c4.resident", "trinity_ep8.packed8k", "qwen3next_ep16.packed16k"]
+CELLS = [w["name"] for w in manifest.load(os.path.join(ROOT, "BENCHMARK.json"))["workloads"]]  # every cell reports the five
 
 
 def _reader(name):
@@ -349,20 +349,32 @@ class TestATinyTrainer:
 class TestTheBenchmarksEntries:
     """What the two benchmark tests that pin the end of `BENCHMARK.json`'s
     per-layer list (and stop there: tests/conftest.py) say beside that,
-    said again by name, and the five entries as they stand."""
+    said again by name, and the five entries as they stand. Entries a later
+    cell appends stand behind the five, so nothing here reads a position
+    from the end of a list."""
 
     def test_the_five_stand_at_the_end_and_every_cell_reports_them(self):
+        """The five stand together, behind the ten, every cell of the
+        record reports them, and what stands behind them is no cell's but
+        the cells' that the record added after them."""
         record = manifest.load(os.path.join(ROOT, "BENCHMARK.json"))
         assert manifest.validate(record) == []
-        entries = record["per_layer"][-5:]
+        names = [p["name"] for p in record["per_layer"]]
+        first = names.index(READERS[0])
+        entries = record["per_layer"][first : first + len(READERS)]
         assert [p["name"] for p in entries] == list(READERS)
+        assert first > names.index("idle_unattributed_pct.resident")
         for p in entries:
             assert (p["layer"], p["moves"], p["better"], p["workloads"]) == ("set-up", "setup_s", "lower", CELLS)
             assert p["unit"] == ("count" if p["name"] == "setup_cache_misses" else "s")
             assert os.path.exists(os.path.join(ROOT, "perf", "metrics", f"{p['name']}.py"))
+        older = {"r18c4.resident", "trinity_ep8.packed8k", "qwen3next_ep16.packed16k"}
+        for p in record["per_layer"][first + len(READERS) :]:
+            assert not older & set(p["workloads"]), p["name"]
         for cell in CELLS:
             reported = [m["name"] for m in manifest.Cell(ROOT, os.path.join(ROOT, "BENCHMARK.json"), cell).per_layer]
-            assert reported[-5:] == list(READERS)
+            at = reported.index(READERS[0])
+            assert reported[at : at + len(READERS)] == list(READERS)
 
     def test_what_the_pinned_statements_stood_beside(self):
         record = manifest.load(os.path.join(ROOT, "BENCHMARK.json"))
@@ -374,13 +386,14 @@ class TestTheBenchmarksEntries:
         detector = manifest.Cell(ROOT, os.path.join(ROOT, "BENCHMARK.json"), "r18c4.resident")
         upto = names[: names.index("idle_unattributed_pct.resident") + 1]
         assert [m["name"] for m in detector.per_layer] == upto + list(READERS)
-        # hybrid/test_hybrid_cell.py: the hybrid cell's two list that cell alone; it and its
-        # configuration are the last of theirs; the program's preset is what the file states
+        # hybrid/test_hybrid_cell.py: the hybrid cell's two list that cell alone and stand
+        # right before the five; the record holds it and its configuration; the program's
+        # preset is what the file states
         for name in ("stage_linear_attention_ms", "delta_rule_roofline"):
             assert by_name[name]["workloads"] == ["qwen3next_ep16.packed16k"]
-        assert names.index("delta_rule_roofline") == len(names) - 6
-        assert [w["name"] for w in record["workloads"]][-1] == "qwen3next_ep16.packed16k"
-        assert record["configs"][-1]["name"] == "qwen3_next_ep16"
+        assert names.index("delta_rule_roofline") == names.index("stage_linear_attention_ms") + 1 == names.index(READERS[0]) - 1
+        assert "qwen3next_ep16.packed16k" in [w["name"] for w in record["workloads"]]
+        assert "qwen3_next_ep16" in [c["name"] for c in record["configs"]]
         from replication_faster_rcnn_tpu.config import get_config
 
         cell = manifest.Cell(ROOT, os.path.join(ROOT, "BENCHMARK.json"), "qwen3next_ep16.packed16k")

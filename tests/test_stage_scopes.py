@@ -176,13 +176,18 @@ class TestSequenceModelStageScopes:
         return _op_names(jax.jit(make_train_step(None, cfg, tx)).lower(state, batch))
 
     def test_names_are_fixed_distinct_and_share_the_update_stage(self):
-        assert len(set(stages.LM_STAGES)) == len(stages.LM_STAGES) == 11
+        assert len(set(stages.LM_STAGES)) == len(stages.LM_STAGES) == 13
         assert all(re.fullmatch(r"frcnn\.[a-z_]+", s) for s in stages.LM_STAGES)
         assert set(stages.LM_STAGES) & set(stages.STAGES) == {stages.UPDATE}
 
-    # the delta-rule layer's two scopes are in the hybrid preset's step: tests/test_lm_hybrid.py
+    # the delta-rule layer's two scopes are in the hybrid preset's step: tests/test_lm_hybrid.py; the
+    # latent layer's and the multi-token-prediction module's in the latent preset's: tests/test_lm_latent.py
     @pytest.mark.parametrize(
-        "scope", [s for s in stages.LM_STAGES if s not in (stages.UPDATE, stages.LM_LINEAR_ATTENTION, stages.LM_DELTA_CORE)]
+        "scope",
+        [
+            s for s in stages.LM_STAGES
+            if s not in (stages.UPDATE, stages.LM_LINEAR_ATTENTION, stages.LM_DELTA_CORE, stages.LM_LATENT, stages.LM_MTP)
+        ],
     )
     def test_every_stage_is_in_the_lowered_step_forward_and_backward(self, lm_names, scope):
         """Each layer is recomputed in the backward pass (`jax.checkpoint`):
